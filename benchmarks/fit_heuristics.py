@@ -4,7 +4,7 @@
 The reference trains its select_k algorithm dispatch offline from GPU
 sweeps (cpp/include/raft/matrix/detail/select_k-inl.cuh:47-75, notebooks
 cpp/scripts/heuristics/select_k/).  This is the TPU analog: consume
-``benchmarks/prims_tpu.json`` (written on-chip by onchip_autorun.sh) and
+``benchmarks/prims_tpu.json`` (written on-chip by ``raft_tpu.bench.prims``) and
 report, per primitive, the measured decision boundary next to the
 constant the dispatch currently hard-codes:
 

@@ -33,14 +33,12 @@ def main() -> None:
     # opt-in CPU simulation, matching ann_quickstart's --platform pattern:
     # an explicit --platform wins; otherwise accelerators autodetect and
     # only a CPU-only environment gets the N-virtual-device mesh
-    from raft_tpu.core.compat import set_host_device_count
-
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
         if args.platform == "cpu":
-            set_host_device_count(args.devices)
+            jax.config.update("jax_num_cpu_devices", args.devices)
     elif not os.environ.get("JAX_PLATFORMS"):
-        set_host_device_count(args.devices)
+        jax.config.update("jax_num_cpu_devices", args.devices)
 
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P

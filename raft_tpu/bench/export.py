@@ -70,7 +70,7 @@ def kernel_path(
     measured the routing itself (the accel A/B leg does); pass
     ``metric``/``storage_dtype`` to ask the shared
     :func:`~raft_tpu.neighbors._common.pallas_scan_enabled` gate; with
-    neither, fall back to the ``RAFT_TPU_PALLAS`` env opt-in alone.
+    neither, ask ``kernels.use_pallas()`` alone.
     """
     if pallas is None:
         if metric is not None and storage_dtype is not None:
@@ -78,7 +78,9 @@ def kernel_path(
 
             pallas = pallas_scan_enabled(metric, storage_dtype)
         else:
-            pallas = _env.env_str("RAFT_TPU_PALLAS") == "1"
+            from raft_tpu.kernels import use_pallas
+
+            pallas = use_pallas()
     return {"pallas": bool(pallas)}
 
 

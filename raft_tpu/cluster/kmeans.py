@@ -222,7 +222,7 @@ def _lloyd_sharded_program(
     cache and re-trace the while_loop every call."""
     from jax.sharding import PartitionSpec as P
 
-    from raft_tpu.core.compat import shard_map
+    from jax import shard_map
     from raft_tpu.comms.quantized import quantized_psum
 
     def local(x, w, c0):

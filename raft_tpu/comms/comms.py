@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -161,8 +162,6 @@ def local_comms(n_devices: Optional[int] = None) -> Comms:
 # validation (ref: comms/comms_test.hpp:33-107, raft_dask comms_utils.pyx:79).
 # Same here: each returns True iff the collective produced the expected value
 # on every shard.
-
-from raft_tpu.core.compat import shard_map as _shard_map  # noqa: E402
 
 
 def _run(comms: Comms, fn, out_specs=P()):

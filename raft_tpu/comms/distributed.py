@@ -23,10 +23,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from raft_tpu.core.compat import shard_map
 
 from raft_tpu.comms.comms import Comms
 from raft_tpu.distance.pairwise import DISTANCE_TYPES, distance_matrix_tile

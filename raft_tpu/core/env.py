@@ -258,14 +258,10 @@ KNOWN_VARS: Tuple[EnvVar, ...] = (
            "triggers (0 disables the capture, the event still fires)"),
     EnvVar("RAFT_TPU_PERF_CAPTURE_DIR", "str", "flight dir",
            "where regression-triggered profiler captures are written"),
-    EnvVar("RAFT_TPU_PEAK_FLOPS", "float", "per-platform",
-           "roofline FLOP/s peak for obs.cost utilization estimates"),
-    EnvVar("RAFT_TPU_PEAK_BW", "float", "per-platform",
-           "roofline bytes/s peak for obs.cost utilization estimates"),
     # -- kernels / planners --------------------------------------------------
-    EnvVar("RAFT_TPU_PALLAS", "str", "unset",
-           "1 routes supported kernels through the Pallas "
-           "implementations (kernels.use_pallas also accepts 0/auto)"),
+    EnvVar("RAFT_TPU_PALLAS", "str", "auto",
+           "auto runs the Pallas kernels on TPU; 1 forces them "
+           "(interpret mode off-TPU); 0 keeps the XLA paths"),
     EnvVar("RAFT_TPU_PALLAS_SELECT_K", "bool", "1",
            "0 reverts the fused k-selection kernel to the XLA "
            "select paths (under the master RAFT_TPU_PALLAS gate)"),
@@ -278,8 +274,6 @@ KNOWN_VARS: Tuple[EnvVar, ...] = (
     EnvVar("RAFT_TPU_PLATFORM", "str", "auto",
            "force the jax platform for the raft_tpu.bench sweeps "
            "(cpu/tpu)"),
-    EnvVar("RAFT_TPU_CACHE_DIR", "str", "~/.cache/raft_tpu/jax_cache",
-           "persistent XLA compile cache location"),
     EnvVar("RAFT_TPU_NO_COMPILE_CACHE", "bool", "unset",
            "1 disables the persistent compile cache"),
     EnvVar("RAFT_TPU_COORDINATOR", "str", "unset",
@@ -307,8 +301,6 @@ KNOWN_VARS: Tuple[EnvVar, ...] = (
     EnvVar("RAFT_TPU_RUN_SLOW", "bool", "unset",
            "1 opts into @pytest.mark.slow tests (bench smokes, scale "
            "runs)"),
-    EnvVar("RAFT_TPU_TEST_DEVICE", "bool", "unset",
-           "1 enables the on-device test assertions"),
     EnvVar("RAFT_TPU_SCALE_N", "int", "test default",
            "corpus size override for the scale test suite"),
 )

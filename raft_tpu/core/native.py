@@ -37,18 +37,26 @@ _DTYPES_INV = {v: k for k, v in _DTYPES.items()}
 LOG_CALLBACK = ctypes.CFUNCTYPE(None, ctypes.c_int, ctypes.c_char_p, ctypes.c_void_p)
 
 
-def _build(force: bool = False) -> Optional[str]:
+def _stale(cpp: str, so: str) -> bool:
+    """True when the library is missing or older than any of its inputs:
+    a source, a header or the Makefile."""
     import glob as _glob
 
+    inputs = (
+        _glob.glob(os.path.join(cpp, "src", "*.cc"))
+        + _glob.glob(os.path.join(cpp, "include", "**", "*"), recursive=True)
+        + [os.path.join(cpp, "Makefile")]
+    )
+    return not os.path.exists(so) or any(
+        os.path.getmtime(p) > os.path.getmtime(so)
+        for p in inputs if os.path.isfile(p)
+    )
+
+
+def _build(force: bool = False) -> Optional[str]:
     cpp = os.path.abspath(_CPP_DIR)
     so = os.path.join(cpp, "libraft_tpu_core.so")
-    srcs = _glob.glob(os.path.join(cpp, "src", "*.cc"))
-    if (
-        not force
-        and os.path.exists(so)
-        and srcs
-        and all(os.path.getmtime(so) >= os.path.getmtime(s) for s in srcs)
-    ):
+    if not force and not _stale(cpp, so):
         return so
     try:
         if force:

@@ -5,11 +5,12 @@ The reference spends its hand-written-kernel budget on exactly these spots
 select_warpsort.cuh), fused distance+reduction (distance/fused_l2_nn-inl.cuh,
 spatial/knn/detail/fused_l2_knn-inl.cuh), and the IVF-PQ LUT scan
 (neighbors/detail/ivf_pq_compute_similarity-inl.cuh).  On TPU the XLA
-formulations of these are already strong, so each Pallas kernel here is an
-*alternative* code path behind a dispatch flag — A/B measured by
-``python -m raft_tpu.bench prims`` and enabled where it wins.
+formulations of these are already strong, so each Pallas kernel here has
+an XLA twin that tests hold it to (A/B by ``python -m raft_tpu.bench
+prims``).
 
-Dispatch: ``use_pallas()`` consults RAFT_TPU_PALLAS:
+Dispatch: every kernel call site asks ``use_pallas()``, which consults
+RAFT_TPU_PALLAS:
   - "0"    — never (pure XLA paths)
   - "1"    — always (interpret mode off-TPU; for tests)
   - "auto" — (default) on TPU backends only

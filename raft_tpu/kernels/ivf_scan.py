@@ -15,8 +15,8 @@ Role parity: the reference's per-list ``compute_similarity`` scan kernel
 its shmem LUT + warp select; here the "LUT" is the decoded scan cache and
 the warp queue is the VMEM fold.
 
-Used by the ivf_pq AND ivf_flat probe-major paths when
-``RAFT_TPU_PALLAS=1`` (same gate as the fused kNN kernel).  Coverage
+Used by the ivf_pq AND ivf_flat scan paths when ``kernels.use_pallas()``
+holds (same gate as every kernel).  Coverage
 (round 4 widened to match the reference's compute_similarity surface):
 
 - **Metrics**: L2 (sqeuclidean/euclidean), **inner product**, and
@@ -406,7 +406,7 @@ def _scan_qm_kernel(probes_ref, dec_ref, y2_ref, ids_ref, filt_ref, q_ref,
     score scratch; after the block's last (p, i) step, ONE fold over the
     whole [G, P*cap] pool extracts every member's top-kk.  The G-wide
     fold is the point: a per-query fold would waste 7 of 8 sublanes and
-    dominate the kernel (measured reasoning in ROUND4_NOTES); batching G
+    dominate the kernel (round-4 chip measurement); batching G
     queries' pools through fold_topk amortizes it G-fold."""
     p = pl.program_id(1)
     i = pl.program_id(2)

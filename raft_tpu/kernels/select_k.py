@@ -90,11 +90,12 @@ def _select_kernel(v_ref, tie_ref, pay_ref, out_v_ref, out_i_ref, *,
 
     def extract(t, carry):
         removed, out_v, out_i = carry
-        eff = jnp.where(removed, _INF, v)
+        live = removed == 0
+        eff = jnp.where(live, v, _INF)
         m = jnp.min(eff, axis=1)
         # removed entries sit at +inf; exclude them so an all-inf tail
         # round still picks a fresh entry
-        is_min = (eff == m[:, None]) & ~removed
+        is_min = (eff == m[:, None]) & live
         sel_tie = jnp.min(jnp.where(is_min, tie, _SENTINEL), axis=1)
         cand = is_min & (tie == sel_tie[:, None])
         first = jnp.min(jnp.where(cand, pos, n_pad), axis=1)
@@ -103,9 +104,11 @@ def _select_kernel(v_ref, tie_ref, pay_ref, out_v_ref, out_i_ref, *,
         hole = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1) == t
         out_v = jnp.where(hole, m[:, None], out_v)
         out_i = jnp.where(hole, sel_pay[:, None], out_i)
-        return removed | pick, out_v, out_i
+        return removed | pick.astype(jnp.int32), out_v, out_i
 
-    removed0 = jnp.zeros((rows, n_pad), jnp.bool_)
+    # int32, not bool: Mosaic cannot legalize an scf.for carrying an i1
+    # vector, so a bool mask fails to compile for the chip
+    removed0 = jnp.zeros((rows, n_pad), jnp.int32)
     out_v0 = jnp.full((rows, k), _INF, jnp.float32)
     out_i0 = jnp.full((rows, k), -1, jnp.int32)
     _, out_v, out_i = jax.lax.fori_loop(

@@ -166,8 +166,8 @@ def knn(
     select_min = canonical != "inner_product"
     n, d = dataset.shape
 
-    # perf-ledger attribution: brute force has no Pallas leg — every
-    # dispatch is the tiled XLA matmul path
+    # perf-ledger attribution: the tiled XLA matmul path unless the fused
+    # Pallas leg below takes the call
     from raft_tpu.kernels import stamp_kernel_path
 
     stamp_kernel_path("xla")
@@ -189,14 +189,14 @@ def knn(
 
     # Pallas fused distance+topk path (ref: the fusedL2Knn fast path,
     # spatial/knn/detail/fused_l2_knn-inl.cuh — fuses the distance tile and
-    # selection so the [n_q, n] score matrix never reaches HBM). Opt-in via
-    # RAFT_TPU_PALLAS=1 until the on-chip A/B vs the XLA formulation is
-    # recorded (bench/prims); interpret mode keeps it testable on CPU.
-    from raft_tpu.core import env as _env
+    # selection so the [n_q, n] score matrix never reaches HBM). Same gate
+    # as every kernel (kernels.use_pallas); interpret mode keeps it
+    # testable on CPU.
+    from raft_tpu.kernels import use_pallas
 
     canonical_f32 = dataset.dtype == jnp.float32 and queries.dtype == jnp.float32
     if (
-        _env.env_str("RAFT_TPU_PALLAS") == "1"
+        use_pallas()
         and canonical in ("sqeuclidean", "euclidean", "inner_product")
         and k <= 128
         and canonical_f32
@@ -205,6 +205,7 @@ def knn(
         from raft_tpu.kernels import interpret_mode
         from raft_tpu.kernels.fused_knn import fused_l2_topk
 
+        stamp_kernel_path("pallas")
         if canonical == "inner_product":
             vals, idx = fused_l2_topk(
                 queries, dataset, jnp.zeros(n), int(k), mode="ip",

@@ -107,8 +107,9 @@ def refine(
     if k > candidates.shape[1]:
         raise ValueError(f"k={k} > candidate count {candidates.shape[1]}")
     if host:
+        # the explicit CPU refine; serving refines on device (host=False)
         return _refine_host(
-            np.asarray(dataset), np.asarray(queries), np.asarray(candidates), k, canonical
+            np.asarray(dataset), np.asarray(queries), np.asarray(candidates), k, canonical  # raft-tpu: ignore[HOSTSYNC] opt-in host refine
         )
     tile = _refine_query_tile(
         candidates.shape[0], candidates.shape[1], dataset.shape[1]

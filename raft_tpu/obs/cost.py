@@ -19,9 +19,8 @@ Three layers:
 - :func:`analyze_callable` + :func:`record_cost` — AOT-compile a callable
   at given arg shapes, time one execution of the already-compiled
   executable, and publish ``raft_tpu_xla_*`` gauges with a roofline
-  utilization estimate against configurable device peaks
-  (``RAFT_TPU_PEAK_FLOPS`` / ``RAFT_TPU_PEAK_BW`` env vars, else
-  per-platform defaults).
+  utilization estimate against the published peaks of the device
+  (:data:`PEAKS`, keyed by ``device_kind``).
 - :func:`refresh_live_buffer_gauges` — walks an
   :class:`~raft_tpu.serve.registry.IndexRegistry`'s weakly-referenced
   version history and publishes ``raft_tpu_index_live_bytes`` per
@@ -39,36 +38,37 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
-from raft_tpu.core import env as _env
 from raft_tpu.core.logger import child as _child_logger
 from raft_tpu.obs.registry import MetricsRegistry, default_registry
 
 _log = _child_logger("obs.cost")
 
-#: (peak FLOP/s, peak memory bandwidth bytes/s) per platform family.
-#: TPU figures track a v5e-class part (bf16 matmul peak, HBM2e bw); the
-#: CPU default is a deliberately round server-class estimate.  Override
-#: with RAFT_TPU_PEAK_FLOPS / RAFT_TPU_PEAK_BW for the actual part.
-DEFAULT_PEAKS: Dict[str, Tuple[float, float]] = {
-    "tpu": (197e12, 819e9),
-    "gpu": (312e12, 2039e9),
-    "cpu": (1e11, 5e10),
+#: (peak bf16 FLOP/s, peak HBM bytes/s) per chip, keyed by JAX's
+#: ``device_kind``: the published figures, never a guess.  "TPU v5 lite" is
+#: TPU v5e: 197 TFLOP/s bf16, 819 GB/s (Google Cloud documentation,
+#: "TPU v5e").  A TPU missing here is an error; other platforms (the CPU
+#: test backend) have no roofline.
+PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
 }
 
 
-def device_peaks(platform: Optional[str] = None) -> Tuple[float, float]:
-    """(peak_flops_per_s, peak_bytes_per_s) for the active platform."""
-    if platform is None:
-        try:
-            import jax
+def device_peaks(kind: Optional[str] = None) -> Optional[Tuple[float, float]]:
+    """(peak_flops_per_s, peak_bytes_per_s) of the device kind (default: the
+    first JAX device), or None off-TPU.  Raises ``KeyError`` for a TPU kind
+    with no published peaks in :data:`PEAKS`."""
+    if kind is None:
+        import jax
 
-            platform = jax.devices()[0].platform
-        except Exception:  # no backend at all — fall through to cpu row
-            platform = "cpu"
-    flops, bw = DEFAULT_PEAKS.get(platform, DEFAULT_PEAKS["cpu"])
-    flops = _env.env_float("RAFT_TPU_PEAK_FLOPS", flops)
-    bw = _env.env_float("RAFT_TPU_PEAK_BW", bw)
-    return flops, bw
+        kind = jax.devices()[0].device_kind
+    if kind in PEAKS:
+        return PEAKS[kind]
+    if kind.startswith("TPU"):
+        raise KeyError(
+            f"no published peaks for device_kind {kind!r}; add them to "
+            f"raft_tpu.obs.cost.PEAKS with their source"
+        )
+    return None
 
 
 @dataclass
@@ -160,7 +160,7 @@ def roofline_utilization(
     flops: Optional[float],
     bytes_accessed: Optional[float],
     seconds: Optional[float],
-    platform: Optional[str] = None,
+    kind: Optional[str] = None,
 ) -> Optional[float]:
     """Achieved FLOP/s as a fraction of the roofline-attainable rate.
 
@@ -168,11 +168,15 @@ def roofline_utilization(
     roofline ceiling at the program's arithmetic intensity.  1.0 means
     the executable runs as fast as this hardware can run *this* program;
     low values point at launch overhead or a mis-scheduled kernel rather
-    than "needs a bigger chip".  None when any input is unknown.
+    than "needs a bigger chip".  None when any input is unknown, or the
+    device (``kind``, default the first JAX device) has no peaks.
     """
     if not flops or not seconds or seconds <= 0:
         return None
-    peak_flops, peak_bw = device_peaks(platform)
+    peaks = device_peaks(kind)
+    if peaks is None:
+        return None
+    peak_flops, peak_bw = peaks
     attainable = peak_flops
     if bytes_accessed and bytes_accessed > 0:
         attainable = min(peak_flops, (flops / bytes_accessed) * peak_bw)
@@ -196,9 +200,19 @@ def analyze_callable(fn, *args, time_run: bool = True) -> Optional[CostReport]:
 
     from raft_tpu.ops import cost as ops_cost
 
+    flat = jax.tree_util.tree_leaves(args)
     try:
         with ops_cost.capture() as notes:
-            compiled = jax.jit(fn).lower(*args).compile()
+            # lower with the arrays ``fn`` closes over (a served index's
+            # whole payload) as parameters: jit of the closure would embed
+            # them as program constants — a GB-sized program per bucket at
+            # deployment sizes, host memory and minutes of compile each
+            closed = jax.make_jaxpr(fn)(*args)
+
+            def program(consts, *xs):
+                return jax.core.eval_jaxpr(closed.jaxpr, consts, *xs)
+
+            compiled = jax.jit(program).lower(closed.consts, *flat).compile()
     except Exception as exc:
         _log.debug("cost analysis unavailable: %r", exc)
         return None
@@ -218,7 +232,7 @@ def analyze_callable(fn, *args, time_run: bool = True) -> Optional[CostReport]:
     if time_run:
         try:
             t0 = time.perf_counter()
-            jax.block_until_ready(compiled(*args))
+            jax.block_until_ready(compiled(closed.consts, *flat))
             rep.seconds = time.perf_counter() - t0
         except Exception:
             rep.seconds = None

@@ -68,7 +68,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raft_tpu import obs
@@ -76,7 +76,6 @@ from raft_tpu.cluster import kmeans_balanced
 from raft_tpu.comms.comms import Comms, local_comms
 from raft_tpu.comms.quantized import quantized_psum, reduce_dtype_from_env
 from raft_tpu.core import env as _env
-from raft_tpu.core.compat import shard_map
 from raft_tpu.core.logger import logger as _log
 from raft_tpu.core.resources import Resources, ensure
 from raft_tpu.core.trace import trace_range, traced

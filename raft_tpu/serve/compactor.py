@@ -55,6 +55,7 @@ from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from raft_tpu import obs
@@ -429,6 +430,9 @@ class Compactor:
                 struct_bytes += int(nb)
         padded = _next_pow2(m + 1)
         shadow_bytes = int(struct_bytes * (padded / max(mi.main_size, 1)))
+        if mi.refine_dataset is not None:
+            # the shadow's refine rows, and the source's read back whole
+            shadow_bytes += (padded + mi.main_size) * mi.dim * 4
         chunk_bytes = min(self.policy.chunk_rows, padded) * mi.dim * 4
         return rows_bytes + shadow_bytes + chunk_bytes
 
@@ -439,11 +443,18 @@ class Compactor:
 
         Main rows stream through :meth:`MutableIndex.iter_main_rows` so
         the full structure is never decoded twice; the captured tombstone
-        mask (not the live one) keeps the capture consistent."""
+        mask (not the live one) keeps the capture consistent.  An index
+        with refine rows takes its main rows from them: they are exact,
+        the decoded codes are not."""
         rows = np.empty((m, mi.dim), np.float32)
         gids = np.empty((m,), np.int64)
         off = 0
-        for ridx, chunk in mi.iter_main_rows(self.policy.chunk_rows):
+        if mi.refine_dataset is None:
+            main = mi.iter_main_rows(self.policy.chunk_rows)
+        else:
+            main = [(np.arange(mi.main_size),
+                     np.asarray(mi.refine_dataset, np.float32))]
+        for ridx, chunk in main:
             keep = ~cap.deleted[ridx]
             n = int(keep.sum())
             if not n:
@@ -509,6 +520,10 @@ class Compactor:
             kind=mi.kind,
             search_params=mi.search_params,
             main_ids=all_gids,
+            # the refine rows follow the shadow's row order
+            refine_dataset=(
+                None if mi.refine_dataset is None else jnp.asarray(all_rows)
+            ),
         )
         with shadow._lock:
             shadow._deleted[m:] = True
@@ -530,7 +545,6 @@ class Compactor:
             return brute_force.build(all_rows, metric=mi.metric)
         if mi.kind == "ivf_flat":
             from raft_tpu.neighbors import ivf_flat
-            import jax.numpy as jnp
 
             old = mi.index
             L = old.centers.shape[0]
@@ -547,7 +561,6 @@ class Compactor:
             return ivf_flat.extend(empty, all_rows, ids)
         if mi.kind == "ivf_pq":
             from raft_tpu.neighbors import ivf_pq
-            import jax.numpy as jnp
 
             old = mi.index
             L = old.centers.shape[0]
@@ -702,6 +715,7 @@ class Compactor:
                 shadow.index, kind=shadow.kind,
                 search_params=shadow.search_params,
                 main_ids=shadow._main_ids,
+                refine_dataset=shadow.refine_dataset,
             )
             with warm._lock:
                 warm._deleted[:] = shadow._deleted
@@ -796,6 +810,10 @@ class Compactor:
                 "name": name, "status": "noop",
                 "reason": f"not mutable ({type(mi).__name__})",
             }
+        if mi.refine_dataset is not None:
+            return self.abort(
+                name, "refined", "a sharded index has no refine leg"
+            )
         with self._pass_lock:
             with mi._lock:
                 cap = _capture_locked(mi)

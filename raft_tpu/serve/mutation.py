@@ -53,6 +53,10 @@ _SERVE_SERIALIZATION_VERSION = 1
 
 _MIN_SIDE_CAP = 8
 
+#: candidates per result row that an index with ``refine_dataset`` re-ranks
+#: exactly (the reference recipe's refine ratio)
+REFINE_RATIO = 4
+
 
 def _kind_module(kind: str):
     from raft_tpu import neighbors
@@ -122,10 +126,18 @@ class MutableIndex:
         (the in-search filter tests the backend's stored ids, which are
         rows).  ``None`` (the default, and what direct builds want)
         means identity.
+    refine_dataset:
+        Exact re-rank of the main search (the reference's IVF-PQ search +
+        ``refine`` recipe): when given, the main search returns
+        ``k * REFINE_RATIO`` candidates and
+        :func:`~raft_tpu.neighbors.refine.refine` re-scores them against
+        ``refine_dataset`` (``[main_size, dim]``, on device) to the exact
+        top-k.  Compressed backends need it for recall their codes cannot
+        reach alone.
     """
 
     def __init__(self, index, *, kind: Optional[str] = None, search_params=None,
-                 main_ids: Optional[np.ndarray] = None):
+                 main_ids: Optional[np.ndarray] = None, refine_dataset=None):
         self.kind = kind if kind is not None else _infer_kind(index)
         mod = _kind_module(self.kind)  # validates kind
         self.index = index
@@ -135,6 +147,14 @@ class MutableIndex:
         if search_params is None and self.kind != "brute_force":
             search_params = mod.SearchParams()
         self.search_params = search_params
+        if refine_dataset is not None and (
+            tuple(refine_dataset.shape) != (self.main_size, self.dim)
+        ):
+            raise ValueError(
+                f"refine_dataset has shape {tuple(refine_dataset.shape)}, "
+                f"the index needs [{self.main_size}, {self.dim}]"
+            )
+        self.refine_dataset = refine_dataset
 
         if main_ids is not None:
             main_ids = np.asarray(main_ids, dtype=np.int64).reshape(-1)
@@ -209,6 +229,7 @@ class MutableIndex:
 
         total = sum(_nb(v) for v in vars(self.index).values())
         total += _nb(self._main_ids) + _nb(self._main_ids_dev)
+        total += _nb(self.refine_dataset)
         with self._lock:
             total += _nb(self._side_data) + _nb(self._side_ids)
             total += _nb(self._side_live) + _nb(self._deleted)
@@ -373,9 +394,19 @@ class MutableIndex:
             )
         params = self.search_params if search_params is None \
             else search_params
-        return mod.search(
-            params, self.index, queries, k,
+        if self.refine_dataset is None:
+            return mod.search(
+                params, self.index, queries, k,
+                deleted_mask=tombstones, sample_filter=sample_filter,
+            )
+        from raft_tpu.neighbors.refine import refine
+
+        _, cand = mod.search(
+            params, self.index, queries, k * REFINE_RATIO,
             deleted_mask=tombstones, sample_filter=sample_filter,
+        )
+        return refine(
+            self.refine_dataset, queries, cand, k, metric=self.metric
         )
 
     def _side_passes(self, snap: _Snapshot, sample_filter):
@@ -637,6 +668,8 @@ class MutableIndex:
                 # compacted indexes serve remapped ids; dropping the map on
                 # restore would silently re-serve dense row ids
                 arrays["main_ids"] = self._main_ids
+            if self.refine_dataset is not None:
+                arrays["refine_dataset"] = np.asarray(self.refine_dataset)
             tiered = getattr(self.index, "paged", None)
             if tiered is not None:
                 # paged layout survives the roundtrip: load re-paginates at
@@ -680,6 +713,10 @@ class MutableIndex:
         out = cls(
             index, kind=scalars["kind"], search_params=search_params,
             main_ids=arrays.get("main_ids"),
+            refine_dataset=(
+                jnp.asarray(arrays["refine_dataset"])
+                if "refine_dataset" in arrays else None
+            ),
         )
         with out._lock:
             out._deleted = np.asarray(arrays["deleted"], dtype=bool)

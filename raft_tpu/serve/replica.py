@@ -33,12 +33,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raft_tpu import obs
 from raft_tpu.comms.comms import Comms, local_comms
-from raft_tpu.core.compat import shard_map
 from raft_tpu.core.trace import trace_range
 from raft_tpu.serve.registry import IndexRegistry
 

@@ -49,6 +49,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raft_tpu import obs
@@ -56,7 +57,6 @@ from raft_tpu import kernels as _kernels
 from raft_tpu.comms.comms import Comms, local_comms
 from raft_tpu.core import env as _env
 from raft_tpu.core.bitset import Bitset, RowFilter, WORD_BITS
-from raft_tpu.core.compat import shard_map
 from raft_tpu.core.trace import trace_range
 from raft_tpu.distance.pairwise import DISTANCE_TYPES
 from raft_tpu.ops import matrix
@@ -201,6 +201,11 @@ class ShardedIndex:
             merge_dtype = merge_dtype_from_env()
         deleted = None
         if isinstance(index, MutableIndex):
+            if index.refine_dataset is not None:
+                raise ValueError(
+                    "cannot shard a MutableIndex with exact refine: the "
+                    "sharded search has no refine leg"
+                )
             with index._lock:
                 if int(index._side_live.sum()) > 0:
                     raise ValueError(

@@ -8,9 +8,6 @@ each other to (in practice the suites observe identical ids, asserted
 as distance-multiset equality to stay tie-robust).
 """
 
-import os
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -171,18 +168,3 @@ def test_zero_post_warmup_recompiles_with_kernels_enabled(
     assert compile_count() - c0 == 0, (
         "shuffled same-shape traffic recompiled with the fused hop on"
     )
-
-
-@pytest.mark.skipif(
-    jax.devices()[0].platform != "tpu",
-    reason="real Mosaic compile needs a TPU backend",
-)
-def test_cagra_traverse_compiles_on_tpu(corpus, built):
-    x, q = corpus
-    os.environ["RAFT_TPU_PALLAS"] = "1"
-    try:
-        _, gt = brute_force.knn(x, q, 10)
-        _, idx = cagra.search(cagra.SearchParams(itopk_size=64), built, q, 10)
-        assert _recall(idx, gt) >= 0.9
-    finally:
-        os.environ.pop("RAFT_TPU_PALLAS", None)

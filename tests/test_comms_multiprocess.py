@@ -26,8 +26,7 @@ proc_id, nprocs, port = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
 
 import jax
 jax.config.update("jax_platforms", "cpu")
-from raft_tpu.core.compat import set_host_device_count
-set_host_device_count(2)
+jax.config.update("jax_num_cpu_devices", 2)
 
 from raft_tpu import comms as rc
 
@@ -78,8 +77,7 @@ _ENV_WORKER_SRC = r"""
 import os
 import jax
 jax.config.update("jax_platforms", "cpu")
-from raft_tpu.core.compat import set_host_device_count
-set_host_device_count(2)
+jax.config.update("jax_num_cpu_devices", 2)
 
 from raft_tpu import comms as rc
 
@@ -189,8 +187,7 @@ proc_id, nprocs, port = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
 
 import jax
 jax.config.update("jax_platforms", "cpu")
-from raft_tpu.core.compat import set_host_device_count
-set_host_device_count(2)
+jax.config.update("jax_num_cpu_devices", 2)
 
 import numpy as np
 from raft_tpu import comms as rc

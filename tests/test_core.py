@@ -194,3 +194,29 @@ class TestFanout:
         for c, o in zip(chunks, out):
             assert isinstance(o, jax.Array)
             np.testing.assert_array_equal(np.asarray(o), c)
+
+
+@pytest.mark.parametrize("env_dir", [None, "set"])
+def test_compile_cache_location(tmp_path, env_dir):
+    """The persistent compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says, and otherwise at the fixed <checkout>/.jax_cache (import-time
+    config, so each branch runs in a fresh interpreter)."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "RAFT_TPU_NO_COMPILE_CACHE")}
+    env["JAX_PLATFORMS"] = "cpu"
+    want = os.path.join(root, ".jax_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import raft_tpu, jax; print(jax.config.jax_compilation_cache_dir)"],
+        cwd=tmp_path, env={**env, "PYTHONPATH": root},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == want

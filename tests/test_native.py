@@ -314,3 +314,19 @@ def test_native_eps_neighbors(rng):
     d2 = ((q[:, None, :] - x[None, :, :]) ** 2).sum(-1)
     np.testing.assert_array_equal(adj, d2 <= eps * eps)
     np.testing.assert_array_equal(vd, (d2 <= eps * eps).sum(1))
+
+
+@pytest.mark.parametrize("edited", ["src/a.cc", "include/core/b.hpp", "Makefile"])
+def test_native_rebuild_inputs(tmp_path, edited):
+    """A source, a header or a Makefile newer than the library rebuilds it."""
+    from raft_tpu.core.native import _stale
+
+    so = tmp_path / "lib.so"
+    for rel in ("src/a.cc", "include/core/b.hpp", "Makefile", "lib.so"):
+        f = tmp_path / rel
+        f.parent.mkdir(parents=True, exist_ok=True)
+        f.write_text("")
+        os.utime(f, (100, 100))
+    assert not _stale(str(tmp_path), str(so))
+    os.utime(tmp_path / edited, (200, 200))
+    assert _stale(str(tmp_path), str(so))

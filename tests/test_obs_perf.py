@@ -73,9 +73,15 @@ def test_ledger_accounting_and_hotspot_ranking():
 
 
 def test_ledger_measured_roofline(monkeypatch):
+    # the peak table is keyed by device_kind: give the CPU test device
     # generous peaks so measured utilization lands strictly inside (0, 1]
-    monkeypatch.setenv("RAFT_TPU_PEAK_FLOPS", "1e18")
-    monkeypatch.setenv("RAFT_TPU_PEAK_BW", "1e15")
+    import jax
+
+    from raft_tpu.obs import cost
+
+    monkeypatch.setitem(
+        cost.PEAKS, jax.devices()[0].device_kind, (1e18, 1e15)
+    )
     led = perf.PerfLedger(min_samples=10_000)
     led.register_cost("a", 8, flops=1e6, bytes_accessed=1e5)
     for _ in range(4):

@@ -395,11 +395,27 @@ def test_analyze_callable_reports_real_numbers_on_cpu():
         index="mm", bucket="16") > 0
 
 
+def test_analyze_callable_hoists_closed_over_arrays():
+    """A served search closes over its index: the cost program must take
+    those arrays as parameters, not embed them as constants."""
+    big = jnp.ones((2048, 64), jnp.float32)
+    rep = obs_cost.analyze_callable(
+        lambda q: q @ big.T, np.ones((4, 64), np.float32)
+    )
+    assert rep is not None and rep.flops
+    assert rep.argument_memory_bytes >= big.nbytes
+
+
 def test_roofline_utilization_bounds():
     assert obs_cost.roofline_utilization(None, 1.0, 1.0) is None
     assert obs_cost.roofline_utilization(1e9, 1e6, None) is None
-    u = obs_cost.roofline_utilization(1e9, 1e9, 1.0, platform="cpu")
+    u = obs_cost.roofline_utilization(1e9, 1e9, 1.0, kind="TPU v5 lite")
     assert u is not None and u > 0
+    # published v5e peaks; no roofline off-TPU; an unknown TPU is an error
+    assert obs_cost.device_peaks("TPU v5 lite") == (197e12, 819e9)
+    assert obs_cost.roofline_utilization(1e9, 1e9, 1.0, kind="cpu") is None
+    with pytest.raises(KeyError, match="TPU v9"):
+        obs_cost.device_peaks("TPU v9")
 
 
 def test_live_buffer_gauges_retire_collected_versions():

@@ -1,6 +1,8 @@
 """Pallas TPU kernels, validated in interpret mode on CPU
 (SURVEY §5: interpret=True doubles as the OOB sanitizer)."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,104 +124,10 @@ def test_tile_policy_alignment_under_pressure():
     assert p2.tile_n % tk.LANE == 0 and p2.tile_n >= tk.LANE
 
 
-@pytest.mark.skipif(
-    jax.devices()[0].platform != "tpu",
-    reason="Mosaic compile check needs a real TPU (interpret mode only "
-    "validates semantics; tiling/layout constraints fail at compile time)",
-)
-class TestPallasCompilesOnTpu:
-    """interpret=False compile+run checks (VERDICT r2 #4: prove the
-    kernels actually compile through Mosaic on-chip, don't just pass the
-    CPU interpreter)."""
-
-    def test_fused_l2_topk_compiles(self, rng):
-        x = jnp.asarray(rng.standard_normal((4096, 128)).astype(np.float32))
-        q = jnp.asarray(rng.standard_normal((256, 128)).astype(np.float32))
-        xx = jnp.sum(x * x, axis=1)
-        vals, idx = fused_l2_topk(q, x, xx, 10, interpret=False)
-        d2 = np.asarray(
-            xx[None, :]
-            - 2.0 * jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
-        )
-        want = np.sort(d2, axis=1)[:, :10]
-        np.testing.assert_allclose(np.asarray(vals), want, rtol=1e-3, atol=1e-3)
-
-    def test_fused_l2_argmin_compiles(self, rng):
-        x = jnp.asarray(rng.standard_normal((8192, 96)).astype(np.float32))
-        c = jnp.asarray(rng.standard_normal((512, 96)).astype(np.float32))
-        cc = jnp.sum(c * c, axis=1)
-        vals, idx = fused_l2_argmin(x, c, cc, interpret=False)
-        d2 = np.asarray(
-            cc[None, :]
-            - 2.0 * jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
-        )
-        np.testing.assert_array_equal(np.asarray(idx), d2.argmin(1))
-
-    @pytest.mark.parametrize("decoded_dtype", ["bfloat16", "int8"])
-    def test_ivf_scan_compiles(self, decoded_dtype):
-        """ivf_scan's dynamic-BlockSpec gather, SMEM scalar, and (int8
-        leg) quantized MXU dot must survive Mosaic compilation — these are
-        exactly the constructs interpret mode cannot vouch for."""
-        from raft_tpu.neighbors import ivf_pq
-        from raft_tpu.random import make_blobs
-
-        key = jax.random.PRNGKey(5)
-        x, _, _ = make_blobs(key, 20000, 96, n_clusters=64, cluster_std=2.0)
-        x = np.asarray(x)
-        index = ivf_pq.build(
-            ivf_pq.IndexParams(
-                n_lists=64, pq_dim=48, kmeans_n_iters=4,
-                decoded_dtype=decoded_dtype,
-            ),
-            x,
-        )
-        q = jnp.asarray(x[:512] + 0.01)
-        sp = ivf_pq.SearchParams(n_probes=16, strategy="probe_major")
-        v_x, i_x = ivf_pq.search(sp, index, q, 10)
-        import os
-
-        os.environ["RAFT_TPU_PALLAS"] = "1"
-        try:
-            v_p, i_p = ivf_pq.search(sp, index, q, 10)
-        finally:
-            os.environ.pop("RAFT_TPU_PALLAS", None)
-        assert (np.asarray(i_x) == np.asarray(i_p)).mean() >= 0.99
-
-    @pytest.mark.parametrize("decoded_dtype", ["float32", "bfloat16", "int8"])
-    def test_ivf_scan_query_major_compiles(self, decoded_dtype):
-        """The query-major kernel adds a 3-axis grid, VMEM score scratch,
-        and a group-end fold — Mosaic must take all three."""
-        from raft_tpu.neighbors import ivf_pq
-        from raft_tpu.random import make_blobs
-
-        key = jax.random.PRNGKey(5)
-        x, _, _ = make_blobs(key, 20000, 96, n_clusters=64, cluster_std=2.0)
-        x = np.asarray(x)
-        index = ivf_pq.build(
-            ivf_pq.IndexParams(
-                n_lists=64, pq_dim=48, kmeans_n_iters=4,
-                decoded_dtype=decoded_dtype,
-            ),
-            x,
-        )
-        q = jnp.asarray(x[:512] + 0.01)
-        sp = ivf_pq.SearchParams(n_probes=8, strategy="query_major")
-        v_x, i_x = ivf_pq.search(sp, index, q, 10)
-        import os
-
-        os.environ["RAFT_TPU_PALLAS"] = "1"
-        try:
-            v_p, i_p = ivf_pq.search(sp, index, q, 10)
-        finally:
-            os.environ.pop("RAFT_TPU_PALLAS", None)
-        assert (np.asarray(i_x) == np.asarray(i_p)).mean() >= 0.99
-
-
 class TestIvfScanKernel:
     """Fused Pallas probe-major IVF scan (kernels/ivf_scan.py) must agree
     with the XLA probe-major schedule exactly (interpret mode; the compile
-    leg lives in TestPallasCompilesOnTpu-style gating via RAFT_TPU_PALLAS
-    on chip)."""
+    leg lives in tests/test_tpu_compile.py)."""
 
     def _index(self, n=8000, d=32):
         from raft_tpu.neighbors import ivf_pq
@@ -469,7 +377,7 @@ class TestIvfScanKernel:
 class TestIvfScanQueryMajor:
     """Fused query-major scan (ivf_scan_query_major) must agree with the
     XLA query-major schedule (interpret mode; Mosaic leg in
-    TestPallasCompilesOnTpu)."""
+    tests/test_tpu_compile.py)."""
 
     def _index(self, decoded_dtype="bfloat16", n=8000, d=32):
         from raft_tpu.neighbors import ivf_pq
@@ -660,3 +568,33 @@ class TestIvfPqDescriptorLeg:
         monkeypatch.setenv("RAFT_TPU_PALLAS", "1")
         ivf_pq.search(sp, index, queries, 10, sample_filter=plain)
         assert kernels.consume_kernel_path() == "xla_filter_fallback"
+
+
+@pytest.mark.parametrize("leg", ["brute_force", "ivf_pq"])
+def test_prims_xla_arm_stays_xla_on_tpu(monkeypatch, leg):
+    """The prims A/B's XLA arm pins RAFT_TPU_PALLAS=0: on a TPU an unset
+    gate means auto (Pallas), which would time Pallas under the XLA label."""
+    from raft_tpu import kernels
+    from raft_tpu.bench.prims import pallas_arm
+    from raft_tpu.neighbors import brute_force, ivf_pq
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(600, 16)).astype(np.float32)
+    q = rng.normal(size=(8, 16)).astype(np.float32)
+    if leg == "brute_force":
+        def search(qq):
+            return brute_force.knn(x, qq, 5)
+    else:
+        index = ivf_pq.build(
+            ivf_pq.IndexParams(n_lists=8, pq_dim=8, kmeans_n_iters=2), x
+        )
+
+        def search(qq):
+            return ivf_pq.search(ivf_pq.SearchParams(n_probes=4), index, qq, 5)
+
+    monkeypatch.delenv("RAFT_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernels, "_platform", lambda: "tpu")
+    assert kernels.use_pallas()  # unset means auto: Pallas on a TPU
+    pallas_arm(search, False)(q)
+    assert kernels.consume_kernel_path() == "xla"
+    assert "RAFT_TPU_PALLAS" not in os.environ  # restored

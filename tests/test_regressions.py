@@ -40,7 +40,7 @@ def test_comms_prod_with_negatives():
     from jax.sharding import PartitionSpec as P
 
     from raft_tpu.comms import local_comms
-    from raft_tpu.core.compat import shard_map
+    from jax import shard_map
 
     comms = local_comms(8)
 

@@ -9,7 +9,6 @@ ids, +inf merge padding) because the tie break is exactly where a
 selection kernel silently diverges.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -160,17 +159,3 @@ class TestRouting:
         monkeypatch.setenv("RAFT_TPU_PALLAS", "0")
         v1, i1 = matrix.select_k(wide, 4)
         np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
-
-
-# -- TPU compile smoke ------------------------------------------------------
-
-@pytest.mark.skipif(
-    jax.devices()[0].platform != "tpu",
-    reason="real Mosaic compile needs a TPU backend",
-)
-def test_select_k_compiles_on_tpu(rng):
-    s = jnp.asarray(rng.standard_normal((64, 512)).astype(np.float32))
-    v0, i0 = matrix.select_k(s, 32, algo="topk")
-    v1, i1 = select_k_pallas(s, 32, interpret=False)
-    np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))

@@ -12,6 +12,7 @@ import jax
 
 from raft_tpu import serve
 from raft_tpu.neighbors import brute_force, cagra, ivf_flat, ivf_pq
+from raft_tpu.serve.mutation import REFINE_RATIO
 from raft_tpu.stats import neighborhood_recall
 
 
@@ -81,6 +82,36 @@ def test_batcher_zero_recompiles_after_warmup(corpus):
         assert st["p50_ms"] is not None and st["batch_fill"] > 0
     finally:
         svc.stop()
+
+
+def test_mutable_index_exact_refine(corpus, tmp_path):
+    """refine_dataset re-ranks k·REFINE_RATIO PQ candidates exactly: served
+    ids are the direct search + refine ids, a misshapen dataset is refused,
+    and save/load keeps the refine rows."""
+    from raft_tpu.neighbors.refine import refine
+
+    x, q = corpus
+    idx = ivf_pq.build(ivf_pq.IndexParams(n_lists=16, pq_dim=8), x)
+    sp = ivf_pq.SearchParams(n_probes=4)
+    mi = serve.MutableIndex(idx, search_params=sp, refine_dataset=x)
+    d, ids = mi.search(q, 5)
+    _, cand = ivf_pq.search(sp, idx, q, 5 * REFINE_RATIO)
+    d_ref, ids_ref = refine(x, q, cand, 5)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids_ref))
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(d_ref))
+    # exact distances: re-derivable from the raw rows
+    i0 = np.asarray(ids)[0]
+    np.testing.assert_allclose(
+        np.asarray(d)[0], ((x[i0] - q[0]) ** 2).sum(1), rtol=1e-4
+    )
+    with pytest.raises(ValueError, match="refine_dataset"):
+        serve.MutableIndex(idx, search_params=sp, refine_dataset=x[1:])
+    path = str(tmp_path / "refined.mut")
+    mi.save(path)
+    back = serve.MutableIndex.load(path, search_params=sp)
+    np.testing.assert_array_equal(np.asarray(back.refine_dataset), x)
+    np.testing.assert_array_equal(np.asarray(back.search(q, 5)[1]),
+                                  np.asarray(ids))
 
 
 def test_batcher_coalesces_into_pow2_buckets(corpus):

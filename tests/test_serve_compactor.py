@@ -11,6 +11,7 @@ degrading serving.
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -38,12 +39,13 @@ def _build(kind: str, x: np.ndarray) -> serve.MutableIndex:
         return serve.MutableIndex(
             idx, search_params=ivf_flat.SearchParams(n_probes=16)
         )
-    if kind == "ivf_pq":
+    if kind in ("ivf_pq", "ivf_pq_refined"):
         idx = ivf_pq.build(
             ivf_pq.IndexParams(n_lists=16, pq_dim=24, pq_bits=8), x
         )
         return serve.MutableIndex(
-            idx, search_params=ivf_pq.SearchParams(n_probes=16)
+            idx, search_params=ivf_pq.SearchParams(n_probes=16),
+            refine_dataset=jnp.asarray(x) if kind == "ivf_pq_refined" else None,
         )
     idx = cagra.build(cagra.IndexParams(graph_degree=32), x)
     return serve.MutableIndex(
@@ -57,6 +59,7 @@ _RECALL_FLOOR = {
     "brute_force": 1.0,
     "ivf_flat": 0.95,
     "ivf_pq": 0.8,
+    "ivf_pq_refined": 0.95,
     "cagra": 0.7,
 }
 
@@ -86,7 +89,7 @@ def test_policy_from_env(monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "kind", ["brute_force", "ivf_flat", "ivf_pq", "cagra"]
+    "kind", ["brute_force", "ivf_flat", "ivf_pq", "ivf_pq_refined", "cagra"]
 )
 def test_compact_folds_mutations(kind, corpus):
     """One pass folds tombstones + side rows into the main structure,
@@ -120,6 +123,19 @@ def test_compact_folds_mutations(kind, corpus):
         _d, ids = served.search(q, 10)
         rec = recall_at_k(np.asarray(ids), gt)
         assert rec >= _RECALL_FLOOR[kind], (kind, rec)
+        if kind == "ivf_pq_refined":
+            # the refine rows followed the renumbering: served distances
+            # are still exact against the raw live rows
+            assert served.refine_dataset.shape == (served.main_size, D)
+            row = {int(g): r for g, r in zip(live_ids, live_rows)}
+            i0 = np.asarray(ids)[0]
+            np.testing.assert_allclose(
+                np.asarray(_d)[0],
+                [((row[int(g)] - q[0]) ** 2).sum() for g in i0], rtol=1e-4,
+            )
+            # a sharded rebuild would drop the refine leg: refused loudly
+            out = comp.rebuild_sharded(kind)
+            assert (out["status"], out["reason"]) == ("aborted", "refined")
 
         # ids survived the fold: writes through the retired handle land
         probe = int(keep[0])
