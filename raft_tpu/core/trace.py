@@ -6,27 +6,33 @@ at e.g. neighbors/detail/ivf_pq_build.cuh:1687).  The TPU equivalents are
 
 - ``jax.profiler.TraceAnnotation`` — host-side Perfetto trace range, shows
   up in ``jax.profiler.trace`` captures (the "domain" maps to the
-  ``raft_tpu.`` prefix);
+  ``raft_tpu.`` prefix); :func:`host_range` is this alone;
 - ``jax.named_scope`` — attaches the name to the HLO ops traced under the
   range so device-side work is attributable in the profile;
 - a :mod:`raft_tpu.obs` span — the queryable record: every range reports
   wall time into the metrics registry and becomes the attribution point
   for XLA compile/cache/transfer events, with no profiler attached.
 
-All three are near-zero-cost when nothing is listening; the obs span adds
-one histogram record per call (bounded by ``tests/test_obs.py``'s
-overhead guard).
+:func:`trace_range` is all three.  All are near-zero-cost when nothing is
+listening; the obs span adds one histogram record per call (bounded by
+``tests/test_obs.py``'s overhead guard).
+
+The module also owns the process-wide garbage-collection hook
+(:func:`install_gc_hook`): it counts collections and their pauses, and
+while a profiler records it marks each pause as a ``raft_tpu.host.gc``
+range on the thread that triggered it.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Callable, Optional, TypeVar
+import gc
+import threading
+import time
+from typing import Callable, Dict, List, Optional, TypeVar
 
 import jax
-
-from raft_tpu.core import env as _env
 
 DOMAIN = "raft_tpu"
 
@@ -45,6 +51,13 @@ def _obs_spans():
     return _spans
 
 
+def host_range(name: str) -> jax.profiler.TraceAnnotation:
+    """Profiler range ``raft_tpu.<name>`` on the host timeline, and nothing
+    else: no named scope, no obs span, no histogram.  ``name`` must be a
+    static string — a capture keys ranges by their full name."""
+    return jax.profiler.TraceAnnotation(f"{DOMAIN}.{name}")
+
+
 @contextlib.contextmanager
 def trace_range(name: str):
     """Scoped profiler range ``raft_tpu.<name>`` (ref: nvtx.hpp range).
@@ -57,8 +70,7 @@ def trace_range(name: str):
             if sp is not None:
                 sp.add_stage("dispatch", dt)
     """
-    full = f"{DOMAIN}.{name}"
-    with jax.profiler.TraceAnnotation(full), jax.named_scope(name):
+    with host_range(name), jax.named_scope(name):
         with _obs_spans().span(name) as sp:
             yield sp
 
@@ -85,17 +97,58 @@ def traced(name: Optional[str] = None) -> Callable[[F], F]:
     return deco
 
 
-@contextlib.contextmanager
-def profile(log_dir: str, *, host_tracer_level: int = 2):
-    """Capture a profiler trace of the enclosed block into ``log_dir``.
+# ---- garbage-collection pauses --------------------------------------------
 
-    Thin wrapper over ``jax.profiler.trace`` so benches/tests don't import
-    jax.profiler directly (mirrors the reference gating NVTX behind a CMake
-    flag — here a no-op if RAFT_TPU_DISABLE_PROFILER is set).  The
-    span-integrated variant lives at :func:`raft_tpu.obs.profile`.
-    """
-    if _env.env_bool("RAFT_TPU_DISABLE_PROFILER"):
-        yield
+GC_RANGE = "host.gc"
+
+_profiling = jax.profiler.TraceAnnotation.is_enabled
+_gc_lock = threading.Lock()
+_gc_installed = False
+# CPython runs one collection at a time, start and stop on the thread that
+# triggered it, so one slot holds the open collection's state
+_gc_t0 = 0.0
+_gc_range: Optional[jax.profiler.TraceAnnotation] = None
+_gc_count: List[int] = [0, 0, 0]
+_gc_pause_s: List[float] = [0.0, 0.0, 0.0]
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    global _gc_t0, _gc_range
+    if phase == "start":
+        if _profiling():
+            _gc_range = host_range(GC_RANGE)
+            _gc_range.__enter__()
+        _gc_t0 = time.perf_counter()
         return
-    with jax.profiler.trace(log_dir):
-        yield
+    gen = info["generation"]
+    _gc_pause_s[gen] += time.perf_counter() - _gc_t0
+    _gc_count[gen] += 1
+    if _gc_range is not None:
+        r, _gc_range = _gc_range, None
+        r.__exit__(None, None, None)
+
+
+def install_gc_hook() -> None:
+    """Count collections and time their pauses (idempotent, process-wide).
+
+    With no profiler recording, a collection costs the hook two clock
+    reads and two additions; with one recording, each pause is also a
+    ``raft_tpu.host.gc`` range on the collecting thread."""
+    global _gc_installed
+    with _gc_lock:
+        if not _gc_installed:
+            gc.callbacks.append(_on_gc)
+            _gc_installed = True
+
+
+def gc_stats() -> Dict[str, object]:
+    """Collections and pause seconds since :func:`install_gc_hook`, in total
+    and by generation (0, 1, 2).  Cumulative: difference two reads to get
+    a window's."""
+    count, pause = list(_gc_count), list(_gc_pause_s)
+    return {
+        "gc_count": sum(count),
+        "gc_pause_s": sum(pause),
+        "gc_count_by_gen": count,
+        "gc_pause_s_by_gen": pause,
+    }

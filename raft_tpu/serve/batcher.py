@@ -75,7 +75,7 @@ import jax
 import numpy as np
 
 from raft_tpu.core import env as _env
-from raft_tpu.core.trace import trace_range
+from raft_tpu.core.trace import host_range, trace_range
 from raft_tpu import kernels as _kernels
 from raft_tpu.kernels.toolkit import next_pow2
 from raft_tpu.obs import events as obs_events
@@ -127,7 +127,7 @@ class _InFlight:
         "batch", "padded", "n", "bucket", "queue_waits", "t_pad",
         "inflight_wait", "t_dispatch", "t_enqueued", "dist", "ids",
         "compiles", "sp", "done", "seq", "t_pickup", "hedged",
-        "kernel_path", "admit_level", "page", "dispatch_info",
+        "kernel_path", "admit_level", "page", "dispatch_info", "coalesce",
     )
 
     def __init__(self, batch: List[_Request]):
@@ -138,6 +138,7 @@ class _InFlight:
         self.admit_level = 0
         self.page = None           # explain: page-cache stats stamp
         self.dispatch_info = None  # explain: ragged dispatch params stamp
+        self.coalesce = None       # straggler wait before the cut (worker)
 
 
 class MicroBatcher:
@@ -737,26 +738,30 @@ class MicroBatcher:
             rows += nxt.rows.shape[0]
         return taken
 
-    def _coalesce_locked(self) -> List[_Request]:
+    def _coalesce_locked(self) -> Tuple[List[_Request], float]:
         """Wait (condition held) for stragglers up to the oldest queued
         request's deadline, then pop a batch; [] if the queue emptied
-        under us (a racing flush took everything)."""
+        under us (a racing flush took everything).  Also returns the
+        seconds spent waiting (the ``coalesce`` stage)."""
         if not self._queue:
-            return []
+            return [], 0.0
         deadline = self._queue[0].t_submit + self.max_delay_s
-        while (
-            sum(r.rows.shape[0] for r in self._queue) < self.max_batch
-            and not self._stopping
-        ):
-            remaining = deadline - time.perf_counter()
-            if remaining <= 0:
-                break
-            self._cond.wait(timeout=remaining)
-            if not self._queue:
-                return []
+        t0 = time.perf_counter()
+        with host_range("serve.coalesce"):
+            while (
+                sum(r.rows.shape[0] for r in self._queue) < self.max_batch
+                and not self._stopping
+            ):
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                self._cond.wait(timeout=remaining)
+                if not self._queue:
+                    break
+        waited = time.perf_counter() - t0
         if not self._queue:
-            return []
-        return self._take_batch_locked()
+            return [], waited
+        return self._take_batch_locked(), waited
 
     def _admit(self, batch: List[_Request]) -> List[_Request]:
         """Batch-cut admission: expire deadlines and, with a controller
@@ -765,37 +770,42 @@ class MicroBatcher:
         callbacks inline.  Returns the requests that may dispatch."""
         if not batch:
             return batch
-        ctrl = self.admission
-        index = self.metrics.name or "default"
-        if ctrl is None:
-            alive = expire_deadlines(
-                batch, index=index, metrics=self.metrics,
-            )
-            self._last_admit_level = 0
-            if len(alive) != len(batch) and obs_explain.enabled():
-                alive_ids = {id(r) for r in alive}
-                obs_explain.observe_admission(
-                    index,
-                    expired=[r for r in batch if id(r) not in alive_ids],
+        with host_range("serve.admit"):
+            ctrl = self.admission
+            index = self.metrics.name or "default"
+            if ctrl is None:
+                alive = expire_deadlines(
+                    batch, index=index, metrics=self.metrics,
                 )
-            return alive
-        decision = ctrl.decide(
-            batch, queue_rows=self.queue_depth(), max_batch=self.max_batch,
-        )
-        # recorded where the decision is already made (no re-derivation on
-        # the completion path); read by the same thread that dispatches
-        self._last_admit_level = decision.level
-        if self.degraded is not None:
-            self.degraded.step(decision.level > 0)
-        if (decision.shed or decision.expired) and obs_explain.enabled():
-            # shed / expired requests never reach a batch record — archive
-            # their minimal plans here (futures already carry the typed
-            # errors; this only observes)
-            obs_explain.observe_admission(
-                index, shed=decision.shed, expired=decision.expired,
-                level=decision.level,
+                self._last_admit_level = 0
+                if len(alive) != len(batch) and obs_explain.enabled():
+                    alive_ids = {id(r) for r in alive}
+                    obs_explain.observe_admission(
+                        index,
+                        expired=[r for r in batch
+                                 if id(r) not in alive_ids],
+                    )
+                return alive
+            decision = ctrl.decide(
+                batch, queue_rows=self.queue_depth(),
+                max_batch=self.max_batch,
             )
-        return list(decision.admitted)
+            # recorded where the decision is already made (no re-derivation
+            # on the completion path); read by the same thread that
+            # dispatches
+            self._last_admit_level = decision.level
+            if self.degraded is not None:
+                self.degraded.step(decision.level > 0)
+            if ((decision.shed or decision.expired)
+                    and obs_explain.enabled()):
+                # shed / expired requests never reach a batch record —
+                # archive their minimal plans here (futures already carry
+                # the typed errors; this only observes)
+                obs_explain.observe_admission(
+                    index, shed=decision.shed, expired=decision.expired,
+                    level=decision.level,
+                )
+            return list(decision.admitted)
 
     def _worker(self) -> None:
         # continuous admission (ragged + pipeline): claim the in-flight
@@ -807,34 +817,38 @@ class MicroBatcher:
         continuous = self.ragged is not None and self.pipeline_depth > 1
         while True:
             with self._cond:
-                while not self._queue and not self._stopping:
-                    self._cond.wait()
+                if not self._queue and not self._stopping:
+                    with host_range("serve.idle"):
+                        while not self._queue and not self._stopping:
+                            self._cond.wait()
                 if self._stopping:
                     return
                 if not continuous:
                     # coalescing window: wait for stragglers, bounded by
                     # the oldest request's deadline
-                    batch = self._coalesce_locked()
+                    batch, coalesce_s = self._coalesce_locked()
                     if not batch:
                         continue
             if continuous:
-                self._inflight_sem.acquire()
+                with host_range("serve.inflight_wait"):
+                    self._inflight_sem.acquire()
                 with self._cond:
-                    batch = self._coalesce_locked()
+                    batch, coalesce_s = self._coalesce_locked()
                 batch = self._admit(batch)
                 if not batch:
                     self._inflight_sem.release()
                     continue
-                self._dispatch_pipelined(batch, sem_held=True)
+                self._dispatch_pipelined(batch, sem_held=True,
+                                         coalesce_s=coalesce_s)
             else:
                 batch = self._admit(batch)
                 if not batch:
                     continue
                 if self.pipeline_depth > 1:
-                    self._dispatch_pipelined(batch)
+                    self._dispatch_pipelined(batch, coalesce_s=coalesce_s)
                 else:
                     with self._dispatch_lock:
-                        self._dispatch_locked(batch)
+                        self._dispatch_locked(batch, coalesce_s)
 
     def _dispatch(self, batch: List[_Request]) -> None:
         with self._dispatch_lock:
@@ -923,7 +937,8 @@ class MicroBatcher:
         if explain_on:
             obs_explain.observe_batch(record)
 
-    def _dispatch_locked(self, batch: List[_Request]) -> None:
+    def _dispatch_locked(self, batch: List[_Request],
+                         coalesce_s: Optional[float] = None) -> None:
         if not batch:
             return
         seq = next(self._batch_seq)
@@ -932,12 +947,13 @@ class MicroBatcher:
         queue_waits = [t_start - r.t_submit for r in batch]
         n = sum(r.rows.shape[0] for r in batch)
         bucket = self.bucket_for(n)
-        padded = np.zeros((bucket, self.dim), dtype=np.float32)
-        off = 0
-        for req in batch:
-            m = req.rows.shape[0]
-            padded[off : off + m] = req.rows
-            off += m
+        with host_range("serve.pad"):
+            padded = np.zeros((bucket, self.dim), dtype=np.float32)
+            off = 0
+            for req in batch:
+                m = req.rows.shape[0]
+                padded[off : off + m] = req.rows
+                off += m
         t_pad = time.perf_counter() - t_start
         sp = None
         err_stage = "dispatch"
@@ -946,13 +962,15 @@ class MicroBatcher:
             with trace_range("serve.batch") as sp:
                 t0 = time.perf_counter()
                 # dispatch: host-side tracing + enqueue of the executable
-                dist, ids = self._invoke(padded, batch)
+                with host_range("serve.dispatch"):
+                    dist, ids = self._invoke(padded, batch)
                 t1 = time.perf_counter()
                 err_stage = "device"
                 # device: waiting for the result to materialize — the serial
                 # path's one intended sync (the pipelined path moves it to
                 # the completion thread)
-                jax.block_until_ready((dist, ids))  # raft-tpu: ignore[HOSTSYNC] serial-path batch barrier
+                with host_range("serve.device_wait"):
+                    jax.block_until_ready((dist, ids))  # raft-tpu: ignore[HOSTSYNC] serial-path batch barrier
                 t2 = time.perf_counter()
                 if sp is not None:
                     sp.add_stage("queue", max(queue_waits, default=0.0))
@@ -960,8 +978,9 @@ class MicroBatcher:
                     sp.add_stage("dispatch", t1 - t0)
                     sp.add_stage("device", t2 - t1)
             compiles = compile_count(thread=True) - c0
-            dist = np.asarray(dist)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
-            ids = np.asarray(ids)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
+            with host_range("serve.copy_out"):
+                dist = np.asarray(dist)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
+                ids = np.asarray(ids)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
         except Exception as exc:  # noqa: BLE001 — fail the waiting futures
             self._record_flight(
                 seq=seq, batch=batch, n=n, bucket=bucket,
@@ -984,77 +1003,88 @@ class MicroBatcher:
         done = time.perf_counter()
         off = 0
         lats = []
-        for req in batch:
-            req.future.set_result(self._result_view(req, dist, ids, off))
-            off += req.rows.shape[0]
-            lats.append(done - req.t_submit)
-        observer = self.observer
-        if observer is not None:
-            # futures are already resolved; the observer (quality auditor)
-            # sees only the real rows and must itself be non-blocking
-            try:
-                observer(padded[:n], dist[:n], ids[:n])
-            except Exception:  # noqa: BLE001 — auditing never fails serving
-                pass
-        self.metrics.record_queue_depth(self.queue_depth())
-        self.metrics.record_batch(
-            n, bucket, lats, compiles,
-            stages={
+        with host_range("serve.resolve"):
+            for req in batch:
+                req.future.set_result(self._result_view(req, dist, ids, off))
+                off += req.rows.shape[0]
+                lats.append(done - req.t_submit)
+        t_rec = time.perf_counter()
+        with host_range("serve.record"):
+            observer = self.observer
+            if observer is not None:
+                # futures are already resolved; the observer (quality
+                # auditor) sees only the real rows and must itself be
+                # non-blocking
+                try:
+                    observer(padded[:n], dist[:n], ids[:n])
+                except Exception:  # noqa: BLE001 — auditing never fails serving
+                    pass
+            self.metrics.record_queue_depth(self.queue_depth())
+            stages = {
                 "queue": queue_waits,
                 "pad": (t_pad,),
                 "dispatch": (t1 - t0,),
                 "device": (t2 - t1,),
-            },
-            request_ids=[r.req_id for r in batch],
-            kernel_path=self._last_kernel_path,
-        )
-        if self._perf is not None:
-            # ledger entry rides the t1/t2 stamps already taken above —
-            # zero new clock calls on the hot path
-            backend, ver = self._perf_meta()
-            self._perf.record(
-                index=self.metrics.name or "default", backend=backend,
-                bucket=bucket, kernel_path=self._last_kernel_path,
-                version=ver, device_s=t2 - t1, rows=n, padded_rows=bucket,
+            }
+            if coalesce_s is not None:
+                stages["coalesce"] = (coalesce_s,)
+            self.metrics.record_batch(
+                n, bucket, lats, compiles,
+                stages=stages,
+                request_ids=[r.req_id for r in batch],
+                kernel_path=self._last_kernel_path,
             )
-        self._record_flight(
-            seq=seq, batch=batch, n=n, bucket=bucket, compiles=compiles,
-            t_pickup=t_start, t_done=done,
-            stages_s={
-                "pad": t_pad,
-                "dispatch": t1 - t0,
-                "device": t2 - t1,
-                "copy_out": done - t2,
-            },
-            waits_s={"queue": max(queue_waits, default=0.0)},
-            kernel_path=self._last_kernel_path,
-            hedged=self._last_hedged,
-            admit_level=self._last_admit_level,
-            page=self._last_page_stats,
-            dispatch_info=self._last_dispatch_info,
-        )
-        if compiles and self._warm:
-            # a recompile on the warmed hot path is a shape leak: capture
-            # the surrounding traffic while it is still in the ring
-            obs_events.publish(
-                "hot_recompile",
-                index=self.metrics.name, bucket=bucket, compiles=compiles,
-            )
-        if sp is not None:
-            slowlog.maybe_record(
-                sp,
-                latency_s=max(lats, default=0.0),
-                detail={
-                    "index": self.metrics.name,
-                    "requests": len(batch),
-                    "bucket": bucket,
-                    "compiles": compiles,
-                    "request_ids": [r.req_id for r in batch],
-                    **self._explain_summary(
-                        self._last_kernel_path, self._last_page_stats
-                    ),
+            if self._perf is not None:
+                # ledger entry rides the t1/t2 stamps already taken
+                # above — zero new clock calls on the hot path
+                backend, ver = self._perf_meta()
+                self._perf.record(
+                    index=self.metrics.name or "default", backend=backend,
+                    bucket=bucket, kernel_path=self._last_kernel_path,
+                    version=ver, device_s=t2 - t1, rows=n,
+                    padded_rows=bucket,
+                )
+            self._record_flight(
+                seq=seq, batch=batch, n=n, bucket=bucket, compiles=compiles,
+                t_pickup=t_start, t_done=done,
+                stages_s={
+                    "pad": t_pad,
+                    "dispatch": t1 - t0,
+                    "device": t2 - t1,
+                    "copy_out": done - t2,
                 },
+                waits_s={"queue": max(queue_waits, default=0.0)},
+                kernel_path=self._last_kernel_path,
+                hedged=self._last_hedged,
+                admit_level=self._last_admit_level,
+                page=self._last_page_stats,
+                dispatch_info=self._last_dispatch_info,
             )
+            if compiles and self._warm:
+                # a recompile on the warmed hot path is a shape leak:
+                # capture the surrounding traffic while it is still in the
+                # ring
+                obs_events.publish(
+                    "hot_recompile",
+                    index=self.metrics.name, bucket=bucket,
+                    compiles=compiles,
+                )
+            if sp is not None:
+                slowlog.maybe_record(
+                    sp,
+                    latency_s=max(lats, default=0.0),
+                    detail={
+                        "index": self.metrics.name,
+                        "requests": len(batch),
+                        "bucket": bucket,
+                        "compiles": compiles,
+                        "request_ids": [r.req_id for r in batch],
+                        **self._explain_summary(
+                            self._last_kernel_path, self._last_page_stats
+                        ),
+                    },
+                )
+        self.metrics.record_stage("record", time.perf_counter() - t_rec)
 
     def _explain_summary(self, kernel_path: str,
                          page: Optional[Dict[str, object]]):
@@ -1123,7 +1153,9 @@ class MicroBatcher:
         t.start()
 
     def _dispatch_pipelined(self, batch: List[_Request], *,
-                            sem_held: bool = False) -> Optional[_InFlight]:
+                            sem_held: bool = False,
+                            coalesce_s: Optional[float] = None,
+                            ) -> Optional[_InFlight]:
         """Stage 1+2: pad into a staging buffer, enqueue device work, hand
         the record to the completion thread.  Never blocks on the device;
         blocks only on the in-flight window (``inflight_wait``).  Returns
@@ -1142,28 +1174,31 @@ class MicroBatcher:
             # acquire the window slot BEFORE the dispatch lock: a full
             # window must stall this dispatcher without also blocking the
             # completion thread's progress (it never takes either)
-            self._inflight_sem.acquire()
+            with host_range("serve.inflight_wait"):
+                self._inflight_sem.acquire()
         t_acquired = time.perf_counter()
         with self._dispatch_lock:
             rec = _InFlight(batch)
             rec.seq = next(self._batch_seq)
             rec.t_pickup = t_acquired
             rec.inflight_wait = t_acquired - t_arrive
+            rec.coalesce = coalesce_s
             # queue-wait ends when the batch is picked up for dispatch
             rec.queue_waits = [t_acquired - r.t_submit for r in batch]
             n = sum(r.rows.shape[0] for r in batch)
             bucket = self.bucket_for(n)
             t0 = time.perf_counter()
-            padded = self._staging_buffer(bucket)
-            off = 0
-            for req in batch:
-                m = req.rows.shape[0]
-                padded[off : off + m] = req.rows
-                off += m
-            if off < bucket:
-                # zero the tail so depth>1 results stay bit-identical to
-                # the serial path's freshly-zeroed pad
-                padded[off:] = 0.0
+            with host_range("serve.pad"):
+                padded = self._staging_buffer(bucket)
+                off = 0
+                for req in batch:
+                    m = req.rows.shape[0]
+                    padded[off : off + m] = req.rows
+                    off += m
+                if off < bucket:
+                    # zero the tail so depth>1 results stay bit-identical
+                    # to the serial path's freshly-zeroed pad
+                    padded[off:] = 0.0
             rec.n, rec.bucket, rec.padded = n, bucket, padded
             rec.t_pad = time.perf_counter() - t0
             # detached span: opened here, closed by the completion thread
@@ -1171,7 +1206,8 @@ class MicroBatcher:
             try:
                 c0 = compile_count(thread=True)
                 t1 = time.perf_counter()
-                dist, ids = self._invoke(padded, batch)
+                with host_range("serve.dispatch"):
+                    dist, ids = self._invoke(padded, batch)
                 t2 = time.perf_counter()
                 rec.t_dispatch = t2 - t1
                 # compiles happen synchronously at trace/enqueue time, so
@@ -1244,10 +1280,12 @@ class MicroBatcher:
             # the pipelined path's intended sync point: the completion
             # thread blocks on the oldest in-flight batch off the dispatch
             # path, then copies results out
-            jax.block_until_ready((rec.dist, rec.ids))  # raft-tpu: ignore[HOSTSYNC] completion-thread batch barrier
+            with host_range("serve.device_wait"):
+                jax.block_until_ready((rec.dist, rec.ids))  # raft-tpu: ignore[HOSTSYNC] completion-thread batch barrier
             t4 = time.perf_counter()
-            dist = np.asarray(rec.dist)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
-            ids = np.asarray(rec.ids)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
+            with host_range("serve.copy_out"):
+                dist = np.asarray(rec.dist)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
+                ids = np.asarray(rec.ids)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
         except Exception as exc:  # noqa: BLE001 — fail only this batch
             spans.finish_span(rec.sp)
             self._record_flight(
@@ -1295,85 +1333,95 @@ class MicroBatcher:
         done = time.perf_counter()
         off = 0
         lats = []
-        for req in batch:
-            req.future.set_result(self._result_view(req, dist, ids, off))
-            off += req.rows.shape[0]
-            lats.append(done - req.t_submit)
-        observer = self.observer
-        if observer is not None:
-            # the staging slot outlives this call only until the semaphore
-            # releases, but the auditor holds samples longer — hand it a
-            # copy of the real rows (dist/ids are fresh arrays already)
-            try:
-                observer(rec.padded[: rec.n].copy(), dist[: rec.n],
-                         ids[: rec.n])
-            except Exception:  # noqa: BLE001 — auditing never fails serving
-                pass
-        self.metrics.record_queue_depth(self.queue_depth())
-        self.metrics.record_batch(
-            rec.n, rec.bucket, lats, rec.compiles,
-            stages={
+        with host_range("serve.resolve"):
+            for req in batch:
+                req.future.set_result(self._result_view(req, dist, ids, off))
+                off += req.rows.shape[0]
+                lats.append(done - req.t_submit)
+        t_rec = time.perf_counter()
+        with host_range("serve.record"):
+            observer = self.observer
+            if observer is not None:
+                # the staging slot outlives this call only until the
+                # semaphore releases, but the auditor holds samples longer —
+                # hand it a copy of the real rows (dist/ids are fresh arrays
+                # already)
+                try:
+                    observer(rec.padded[: rec.n].copy(), dist[: rec.n],
+                             ids[: rec.n])
+                except Exception:  # noqa: BLE001 — auditing never fails serving
+                    pass
+            self.metrics.record_queue_depth(self.queue_depth())
+            stages = {
                 "queue": rec.queue_waits,
                 "pad": (rec.t_pad,),
                 "inflight_wait": (rec.inflight_wait,),
                 "dispatch": (rec.t_dispatch,),
                 "device": (t_device,),
-            },
-            request_ids=[r.req_id for r in batch],
-            kernel_path=rec.kernel_path,
-        )
-        if self._perf is not None:
-            # same t3/t4 stamps the "device" stage above is built from, so
-            # per-key ledger totals reconcile with stage_totals()["device"]
-            backend, ver = self._perf_meta()
-            self._perf.record(
-                index=self.metrics.name or "default", backend=backend,
-                bucket=rec.bucket, kernel_path=rec.kernel_path,
-                version=ver, device_s=t_device, rows=rec.n,
-                padded_rows=rec.bucket,
+            }
+            if rec.coalesce is not None:
+                stages["coalesce"] = (rec.coalesce,)
+            self.metrics.record_batch(
+                rec.n, rec.bucket, lats, rec.compiles,
+                stages=stages,
+                request_ids=[r.req_id for r in batch],
+                kernel_path=rec.kernel_path,
             )
-        self._record_flight(
-            seq=rec.seq, batch=batch, n=rec.n, bucket=rec.bucket,
-            compiles=rec.compiles,
-            t_pickup=rec.t_pickup, t_done=done,
-            stages_s={
-                "pad": rec.t_pad,
-                "dispatch": rec.t_dispatch,
-                "completer_wait": max(0.0, t3 - rec.t_enqueued),
-                "device": t_device,
-                "copy_out": done - t4,
-            },
-            waits_s={
-                "queue": max(rec.queue_waits, default=0.0),
-                "inflight_wait": rec.inflight_wait,
-            },
-            kernel_path=rec.kernel_path,
-            hedged=rec.hedged,
-            admit_level=rec.admit_level,
-            page=rec.page,
-            dispatch_info=rec.dispatch_info,
-        )
-        if rec.compiles and self._warm:
-            # a recompile on the warmed hot path is a shape leak: capture
-            # the surrounding traffic while it is still in the ring
-            obs_events.publish(
-                "hot_recompile",
-                index=self.metrics.name, bucket=rec.bucket,
+            if self._perf is not None:
+                # same t3/t4 stamps the "device" stage above is built from,
+                # so per-key ledger totals reconcile with
+                # stage_totals()["device"]
+                backend, ver = self._perf_meta()
+                self._perf.record(
+                    index=self.metrics.name or "default", backend=backend,
+                    bucket=rec.bucket, kernel_path=rec.kernel_path,
+                    version=ver, device_s=t_device, rows=rec.n,
+                    padded_rows=rec.bucket,
+                )
+            self._record_flight(
+                seq=rec.seq, batch=batch, n=rec.n, bucket=rec.bucket,
                 compiles=rec.compiles,
-            )
-        if rec.sp is not None:
-            slowlog.maybe_record(
-                rec.sp,
-                latency_s=max(lats, default=0.0),
-                detail={
-                    "index": self.metrics.name,
-                    "requests": len(batch),
-                    "bucket": rec.bucket,
-                    "compiles": rec.compiles,
-                    "request_ids": [r.req_id for r in batch],
-                    **self._explain_summary(rec.kernel_path, rec.page),
+                t_pickup=rec.t_pickup, t_done=done,
+                stages_s={
+                    "pad": rec.t_pad,
+                    "dispatch": rec.t_dispatch,
+                    "completer_wait": max(0.0, t3 - rec.t_enqueued),
+                    "device": t_device,
+                    "copy_out": done - t4,
                 },
+                waits_s={
+                    "queue": max(rec.queue_waits, default=0.0),
+                    "inflight_wait": rec.inflight_wait,
+                },
+                kernel_path=rec.kernel_path,
+                hedged=rec.hedged,
+                admit_level=rec.admit_level,
+                page=rec.page,
+                dispatch_info=rec.dispatch_info,
             )
+            if rec.compiles and self._warm:
+                # a recompile on the warmed hot path is a shape leak:
+                # capture the surrounding traffic while it is still in the
+                # ring
+                obs_events.publish(
+                    "hot_recompile",
+                    index=self.metrics.name, bucket=rec.bucket,
+                    compiles=rec.compiles,
+                )
+            if rec.sp is not None:
+                slowlog.maybe_record(
+                    rec.sp,
+                    latency_s=max(lats, default=0.0),
+                    detail={
+                        "index": self.metrics.name,
+                        "requests": len(batch),
+                        "bucket": rec.bucket,
+                        "compiles": rec.compiles,
+                        "request_ids": [r.req_id for r in batch],
+                        **self._explain_summary(rec.kernel_path, rec.page),
+                    },
+                )
+        self.metrics.record_stage("record", time.perf_counter() - t_rec)
 
 
 def _squeeze_result(inner: Future, outer: Future) -> None:

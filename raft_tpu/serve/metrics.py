@@ -14,7 +14,10 @@ Latency keeps a bounded reservoir (last ``_RESERVOIR`` request latencies)
 — percentile math stays O(reservoir), not O(uptime).  QPS is measured over
 the same window from completion timestamps.  The batcher additionally
 reports *stage* reservoirs (queue-wait / pad / dispatch / device), so a
-p99 excursion decomposes into "where" without a profiler.
+p99 excursion decomposes into "where" without a profiler.  Beside each
+reservoir sits an exact cumulative sum and count (``stage_sum_s`` /
+``stage_n`` in :meth:`ServingMetrics.snapshot`): two snapshots differenced
+give a window's mean stage time however many batches it held.
 
 :class:`ServingMetrics` is also a :mod:`raft_tpu.obs` registry client:
 named instances mirror requests/batches/recompiles into process-wide
@@ -35,11 +38,16 @@ from raft_tpu import obs
 
 _RESERVOIR = 4096
 
-#: stage names the batcher reports, in display order.  ``inflight_wait``
-#: only appears at pipeline_depth > 1: it is the time a formed batch
-#: waited for an in-flight window slot (device backpressure), measured
-#: before the dispatch stage.
-STAGES = ("queue", "pad", "inflight_wait", "dispatch", "device")
+#: stage names the batcher reports, in display order.  ``queue`` is per
+#: request, the rest per batch.  ``coalesce`` is the worker's wait for
+#: stragglers before it cuts a batch (absent for flushed batches).
+#: ``inflight_wait`` only appears at pipeline_depth > 1: it is the time a
+#: formed batch waited for an in-flight window slot (device backpressure),
+#: measured before the dispatch stage.  ``record`` is the per-batch hook
+#: cost after the futures resolved (observer, metrics, perf ledger, flight
+#: recorder, slow-log).
+STAGES = ("queue", "coalesce", "pad", "inflight_wait", "dispatch", "device",
+          "record")
 
 # ---- process-wide XLA compile counter -------------------------------------
 
@@ -116,6 +124,9 @@ class ServingMetrics:
         self._stage_lat: Dict[str, deque] = {
             s: deque(maxlen=reservoir) for s in STAGES
         }
+        # exact cumulative sum (s) and count of every stage value recorded
+        self._stage_sum: Dict[str, float] = {}
+        self._stage_n: Dict[str, int] = {}
         self.name = name
         self.requests = 0
         self.batches = 0
@@ -188,13 +199,30 @@ class ServingMetrics:
                 self._done_ts.append(now)
             if stages:
                 for s, vals in stages.items():
-                    dq = self._stage_lat.setdefault(
-                        s, deque(maxlen=self._latencies.maxlen)
-                    )
-                    for v in vals:
-                        dq.append(float(v))
+                    self._add_stage_locked(s, vals)
         self._mirror_batch(n_real_rows, bucket_rows, latencies_s, compiles,
                            stages, request_ids, kernel_path)
+
+    def _add_stage_locked(self, stage: str, vals: Iterable[float]) -> None:
+        dq = self._stage_lat.setdefault(
+            stage, deque(maxlen=self._latencies.maxlen)
+        )
+        total, n = 0.0, 0
+        for v in vals:
+            v = float(v)
+            dq.append(v)
+            total += v
+            n += 1
+        self._stage_sum[stage] = self._stage_sum.get(stage, 0.0) + total
+        self._stage_n[stage] = self._stage_n.get(stage, 0) + n
+
+    def record_stage(self, stage: str, seconds: float) -> None:
+        """One per-batch stage value measured after :meth:`record_batch`
+        (the batcher's ``record`` stage times that call itself).  Kept in
+        the reservoir and the exact sums only: mirroring it into the
+        registry would add to the hook cost it measures."""
+        with self._lock:
+            self._add_stage_locked(stage, (seconds,))
 
     def _mirror_batch(self, n_real_rows, bucket_rows, latencies_s, compiles,
                       stages, request_ids=None, kernel_path=None) -> None:
@@ -344,6 +372,9 @@ class ServingMetrics:
                 },
                 # dispatched batches per routed kernel leg (live A/B)
                 "kernel_paths": dict(self._kernel_paths),
+                # exact cumulative stage sums (s) and value counts
+                "stage_sum_s": dict(self._stage_sum),
+                "stage_n": dict(self._stage_n),
             }
         if lat.size:
             out["p50_ms"] = float(np.percentile(lat, 50) * 1e3)
@@ -364,17 +395,12 @@ class ServingMetrics:
         return out
 
     def stage_totals(self) -> Dict[str, float]:
-        """Sum of each stage reservoir in seconds.
+        """Exact cumulative sum of each recorded stage in seconds.
 
         Input to the bench's device-idle-fraction estimate: the ``device``
-        total approximates how long the device had work outstanding.
-        Approximate once a reservoir wraps (bounded at construction), so
-        benches must keep their batch count under the reservoir size for
-        the number to be exact."""
+        total approximates how long the device had work outstanding."""
         with self._lock:
-            return {
-                s: float(sum(dq)) for s, dq in self._stage_lat.items() if dq
-            }
+            return dict(self._stage_sum)
 
 
 def timed_percentiles(latencies_s, qs=(50, 99)) -> Optional[Dict[str, float]]:

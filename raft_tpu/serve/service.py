@@ -38,7 +38,7 @@ import numpy as np
 
 from raft_tpu import obs
 from raft_tpu.core import env as _env
-from raft_tpu.core.trace import traced
+from raft_tpu.core.trace import gc_stats, install_gc_hook, traced
 from raft_tpu.obs import autotune as obs_autotune
 from raft_tpu.obs import cost as obs_cost
 from raft_tpu.obs import explain as obs_explain
@@ -110,6 +110,7 @@ class SearchService:
         ] = None,
     ):
         install_compile_listener()
+        install_gc_hook()
         # full pipeline: XLA event attribution + span/slowlog snapshot
         # sections — the service is the component that promises "where did
         # the milliseconds go" has an answer
@@ -806,7 +807,11 @@ class SearchService:
 
         Includes the per-stage latency breakdown under ``stages`` —
         queue-wait / pad / dispatch / device p50+p99 — so a p99 excursion
-        decomposes without a profiler session.
+        decomposes without a profiler capture; the exact cumulative stage
+        sums and counts under ``stage_sum_s`` / ``stage_n``; and the
+        process's garbage-collection counts and pause seconds under
+        ``host`` (:func:`raft_tpu.core.trace.gc_stats`).  The cumulative
+        fields difference over a window: ``stats1 - stats0``.
         """
         index, version = self.registry.get_versioned(name)
         out = self._batcher(name).metrics.snapshot()
@@ -818,6 +823,7 @@ class SearchService:
             size=index.size,
             pending_deletes=deleted,
             side_rows=side,
+            host=gc_stats(),
         )
         ctrl = self._admission.get(name)
         if ctrl is not None:
