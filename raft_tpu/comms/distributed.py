@@ -225,7 +225,7 @@ def sharded_ivf_pq_search(
     Returns replicated (distances [q, k], ids [q, k]).
     """
     from raft_tpu.distance.pairwise import DISTANCE_TYPES, _PREC
-    from raft_tpu.neighbors._common import run_probe_major
+    from raft_tpu.neighbors._common import lane_pad, run_probe_major
 
     metric = DISTANCE_TYPES[sharded["metric"]]
     mesh, axis = comms.mesh, comms.axis
@@ -250,6 +250,9 @@ def sharded_ivf_pq_search(
         _, probes = select_k(coarse, p_local, select_min=True)
 
         q_rot = jnp.matmul(q, rot.T, precision=_PREC)
+        q2 = jnp.sum(q_rot * q_rot, axis=1)               # [q]
+        # zero lanes up to the cache's padded width add exact zeros
+        q_rot = lane_pad(q_rot, data_s.shape[-1])
         # scan compute dtype per lut_dtype (f32 upcast of the stored rows by
         # default — the single-device kernel's knob); f32 accumulation.
         # int8 caches instead ride the MXU's native int8 path with the
@@ -278,7 +281,6 @@ def sharded_ivf_pq_search(
             # _common.run_probe_major): each local list streams once per
             # bucket, partials merge per query
             kk = min(k_local, cap)
-            q2 = jnp.sum(q_rot * q_rot, axis=1)           # hoisted [q]
 
             def score_fn(bl, bq):
                 dec = data_s[bl]                          # [bb, cap, rot]
@@ -311,8 +313,7 @@ def sharded_ivf_pq_search(
             if metric == "inner_product":
                 scores = -ip
             else:
-                qq = jnp.sum(q_rot * q_rot, axis=1)
-                scores = y2 - 2.0 * ip + qq[:, None, None]
+                scores = y2 - 2.0 * ip + q2[:, None, None]
             # padding slots carry id −1; +inf scores keep them losing
             scores = jnp.where(ids < 0, jnp.inf, scores)
             flat_s = scores.reshape(n_q, p_local * cap)

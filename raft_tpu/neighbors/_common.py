@@ -21,6 +21,32 @@ def round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
+#: lanes of a TPU vector register: the minor dim of a row-major tile
+LANES = 128
+
+
+def padded_width(d: int) -> int:
+    """``d`` rounded up to whole 128-lane tiles."""
+    return round_up(d, LANES)
+
+
+def lane_pad(x, width: Optional[int] = None):
+    """Zero-pad the last dim of ``x`` to ``width`` (default
+    :func:`padded_width` of it); ``x`` itself when it is already that wide.
+
+    A resident row array whose width is not a lane multiple is stored by
+    the TPU runtime in a compact transposed tiling, while the scan kernels
+    and row gathers read the row-major one — so XLA relayouts the whole
+    array at the entry of every program that reads it.  Stored padded, the
+    array's default layout is already row-major.  Zero lanes add exact
+    zeros to every dot product against a query padded the same way."""
+    d = x.shape[-1]
+    pad = (padded_width(d) if width is None else width) - d
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def merge_split_lists(centers: np.ndarray, labels: np.ndarray):
     """Collapse split shards (bit-identical duplicated centroids) back to
     their parent list before a re-pack.

@@ -60,7 +60,10 @@ def ivf_pq_reconstruct_list(
     (ref: ivf_pq_helpers.cuh reconstruct_list_data): decoded rotated
     reconstructions mapped back through the orthonormal rotation."""
     size = int(index.list_sizes[list_id])
-    y_rot = index.list_data[list_id, :size].astype(jnp.float32)  # [size, rot]
+    # the cache's zero lanes past rot_dim are not part of the vectors
+    y_rot = index.list_data[list_id, :size, : index.rot_dim].astype(
+        jnp.float32
+    )  # [size, rot]
     if index.list_data.dtype == jnp.int8:
         y_rot = y_rot * index.scan_scale  # dequantize the memory-lean cache
     vecs = jnp.matmul(y_rot, index.rotation)  # R^T maps rotated → original

@@ -67,7 +67,9 @@ from raft_tpu.neighbors._common import (
     default_max_cap,
     invalid_mask,
     invalid_mask_rows,
+    lane_pad,
     merge_split_lists,
+    padded_width,
     pallas_scan_enabled,
     run_probe_major,
     run_query_tiled,
@@ -242,8 +244,11 @@ class Index:
                    assemble + O(appended) fast-extend scatters); not on
                    the scan path but counted in the HBM budget (the
                    "+ pq_dim" term of the auto-dtype projection)
-      list_data    [L, cap, rot_dim] bf16/f32 — decoded reconstructions
-                   (center_rot + codebook decode), the search scan target
+      list_data    [L, cap, padded_width(rot_dim)] bf16/f32/int8 — decoded
+                   reconstructions (center_rot + codebook decode), the
+                   search scan target; zero lanes past rot_dim make each
+                   row whole 128-lane tiles (_common.lane_pad), so the
+                   array's stored TPU layout is the one the scans read
       list_y2      [L, cap] f32 — ‖reconstruction‖² (from the stored dtype)
       list_index   [L, cap] int32 (-1 past size)
       list_sizes   [L] int32
@@ -442,9 +447,10 @@ def _decode_lists(
     list_index: np.ndarray,
     dtype,
 ) -> Tuple[jax.Array, jax.Array, float]:
-    """Host-side decode of packed lists → (list_data [L,cap,rot] dtype,
-    list_y2 [L,cap] f32, scan_scale). y = center_rot + concat_j
-    codebook[j, code_j]; padding slots are zeroed. y2 is computed from the
+    """Host-side decode of packed lists → (list_data [L, cap,
+    padded_width(rot)] dtype, list_y2 [L,cap] f32, scan_scale).
+    y = center_rot + concat_j codebook[j, code_j]; padding slots and the
+    lanes past rot are zeroed. y2 is computed from the
     *stored* (rounded/quantized) values so scores match what the scan kernel
     sees exactly.
 
@@ -483,8 +489,9 @@ def _decode_lists(
         """Write decoded chunks into preallocated (donated) buffers so peak
         HBM is one final cache + one chunk, never 2× (the concatenate of a
         parts list doubles residency exactly on the just-fits indexes the
-        int8 mode exists for)."""
-        data = jnp.zeros((L, cap, rot_dim), out_dtype)
+        int8 mode exists for).  The chunks are rot_dim wide; the buffer's
+        lanes past them stay zero."""
+        data = jnp.zeros((L, cap, padded_width(rot_dim)), out_dtype)
         y2 = jnp.zeros((L, cap), jnp.float32)
         s = 0
         for part_d, part_y2 in part_iter:
@@ -514,7 +521,8 @@ def _decode_lists(
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _write_rows(buf, part, start):
     """Donated in-place row-block write (start is traced → one compiled
-    program regardless of chunk count)."""
+    program regardless of chunk count); ``part`` may be narrower than
+    ``buf`` in its trailing dims, which it then fills from index 0."""
     return lax.dynamic_update_slice_in_dim(buf, part, start, axis=0)
 
 
@@ -599,7 +607,9 @@ def _scatter_chunk(
     return (
         l_codes.at[lst, slot].set(codes, mode="drop"),
         l_index.at[lst, slot].set(ids, mode="drop"),
-        l_data.at[lst, slot].set(stored, mode="drop"),
+        l_data.at[lst, slot].set(
+            lane_pad(stored, l_data.shape[-1]), mode="drop"
+        ),
         l_y2.at[lst, slot].set(y2, mode="drop"),
     )
 
@@ -691,7 +701,7 @@ def _assemble_lists(
 
     l_codes = jnp.zeros((L, cap, pq_dim), jnp.uint8)
     l_index = jnp.full((L, cap), -1, jnp.int32)
-    l_data = jnp.zeros((L, cap, rot_dim), dtype)
+    l_data = jnp.zeros((L, cap, padded_width(rot_dim)), dtype)
     l_y2 = jnp.zeros((L, cap), jnp.float32)
     for s in range(0, n, chunk):
         c, l = chunk_codes(s)
@@ -818,7 +828,7 @@ def build(
         # projected footprint at bf16: padded rows × (scan cache + codes +
         # y2 + ids); 1.35 ≈ split/headroom padding allowance
         est_rows = int(n * 1.35) + 8 * params.n_lists
-        bf16_bytes = est_rows * (rot_dim * 2 + pq_dim + 8)
+        bf16_bytes = est_rows * (padded_width(rot_dim) * 2 + pq_dim + 8)
         total, limit_is_real = _device_memory_budget()
         budget = int(_AUTO_HBM_FRACTION * total)
         # int8 is an accuracy-class change: only auto-select it against a
@@ -855,7 +865,7 @@ def build(
         np.zeros((params.n_lists, 8, pq_dim), np.uint8),
         jnp.full((params.n_lists, 8), -1, jnp.int32),
         jnp.zeros((params.n_lists,), jnp.int32),
-        jnp.zeros((params.n_lists, 8, rot_dim), dec_dtype),
+        jnp.zeros((params.n_lists, 8, padded_width(rot_dim)), dec_dtype),
         jnp.zeros((params.n_lists, 8), jnp.float32),
         headroom=not params.conservative_memory_allocation,
     )
@@ -936,7 +946,9 @@ def _extend_fast(index: Index, codes_np, labels_np, new_ids):
         jnp.asarray(index.list_codes).at[lj, sj].set(jnp.asarray(codes_np)),
         index.list_index.at[lj, sj].set(ids_j),
         index.list_sizes + jnp.asarray(counts_new, jnp.int32),
-        index.list_data.at[lj, sj].set(dec_rows),
+        index.list_data.at[lj, sj].set(
+            lane_pad(dec_rows, index.list_data.shape[-1])
+        ),
         index.list_y2.at[lj, sj].set(y2_rows),
         index.scan_scale,
         headroom=index.headroom,
@@ -1074,7 +1086,7 @@ def _search_jit(
     queries,      # [q, dim] f32
     centers,      # [L, dim]
     rotation,     # [rot_dim, dim]
-    list_data,    # [L, cap, rot_dim] bf16/f32 — decoded reconstructions
+    list_data,    # [L, cap, padded_width(rot_dim)] — decoded reconstructions
     list_y2,      # [L, cap] f32
     list_index,   # [L, cap] int32
     filter_words,
@@ -1118,9 +1130,11 @@ def _search_jit(
 
     def tile(args):
         qr, pp, fw_t = args  # [t, rot_dim], [t, p], [t, W]
-        dec = _gather_lists(list_data, pp)               # [t, p, cap, rot]
+        dec = _gather_lists(list_data, pp)               # [t, p, cap, rot_w]
         ids = list_index[pp]                             # [t, p, cap]
         y2 = list_y2[pp]                                 # [t, p, cap]
+        # zero lanes up to the cache's padded width add exact zeros
+        qw = lane_pad(qr, list_data.shape[-1])           # [t, rot_w]
         # ip[t,p,c] = q_rot[t]·y[t,p,c] — batched over t, contracting rot
         # acc_dtype = the reference's internal_distance_dtype knob: the
         # score accumulator precision (ivf_pq_types.hpp:139-172)
@@ -1129,11 +1143,11 @@ def _search_jit(
             # the query per-row and ride the MXU's native int8 path, then
             # rescale the int32 accumulator (the fp8-LUT accuracy analog)
             ip = int8_scored_ip(
-                qr, dec, (((1,), (3,)), ((0,), (0,))), scan_scale
+                qw, dec, (((1,), (3,)), ((0,), (0,))), scan_scale
             )                                            # [t, p, cap]
         else:
             ip = lax.dot_general(
-                qr.astype(scan_dtype),
+                qw.astype(scan_dtype),
                 dec.astype(scan_dtype),
                 (((1,), (3,)), ((0,), (0,))),            # contract rot; batch t
                 preferred_element_type=acc_dtype,
@@ -1180,7 +1194,7 @@ def _search_probe_major_jit(
     queries,      # [q, dim] f32
     centers,      # [L, dim]
     rotation,     # [rot_dim, dim]
-    list_data,    # [L, cap, rot_dim] bf16/f32/int8
+    list_data,    # [L, cap, padded_width(rot_dim)] bf16/f32/int8
     list_y2,      # [L, cap] f32
     list_index,   # [L, cap] int32
     filter_words,
@@ -1202,16 +1216,17 @@ def _search_probe_major_jit(
     scattered back to (query, probe) order and merged with one select_k.
     """
     q, dim = queries.shape
-    L, cap, rot_dim = list_data.shape
+    L, cap, rot_w = list_data.shape
     G = bucket
     kk = min(k, cap)
 
     probes = coarse_select(queries, centers, metric, n_probes)  # [q, p]
     q_rot = jnp.matmul(queries, rotation.T, precision=_PREC)    # [q, rot]
     q2 = jnp.sum(q_rot * q_rot, axis=1)                         # [q]
+    q_rot = lane_pad(q_rot, rot_w)                            # [q, rot_w]
 
     def score_fn(bl, bq):
-        dec = _gather_lists(list_data, bl)                         # [bb, cap, rot]
+        dec = _gather_lists(list_data, bl)                         # [bb, cap, rot_w]
         ids = list_index[bl]                                       # [bb, cap]
         y2 = list_y2[bl]
         qr = q_rot[jnp.clip(bq, 0)]                                # [bb, G, rot]
@@ -1279,14 +1294,15 @@ def _search_probe_major_pallas(
     )
 
     q, _ = queries.shape
-    L, cap, rot_dim = list_data.shape
+    L, cap, rot_w = list_data.shape
     G = bucket
     kk = min(k, cap)
     probes = coarse_select(queries, centers, metric, n_probes)
     q_rot = jnp.matmul(queries, rotation.T, precision=_PREC)
     q2 = jnp.sum(q_rot * q_rot, axis=1)
+    q_rot = lane_pad(q_rot, rot_w)  # the cache's padded width
     bucket_list, bucket_query, bucket_pair, B = _invert(probes, L, G)
-    qg = q_rot[jnp.clip(bucket_query, 0)]                   # [B, G, rot]
+    qg = q_rot[jnp.clip(bucket_query, 0)]                   # [B, G, rot_w]
     q2g = jnp.where(bucket_query >= 0, q2[jnp.clip(bucket_query, 0)], jnp.inf)
     vals, ids = ivf_scan_probe_major(
         bucket_list, qg, q2g, list_data, list_y2, list_index, kk,
@@ -1332,6 +1348,7 @@ def _search_query_major_pallas(
     probes = coarse_select(queries, centers, metric, n_probes)
     q_rot = jnp.matmul(queries, rotation.T, precision=_PREC)
     q2 = jnp.sum(q_rot * q_rot, axis=1)
+    q_rot = lane_pad(q_rot, list_data.shape[-1])  # the cache's padded width
     pad = (-q) % _QM_GROUP
     if pad:
         probes = jnp.pad(probes, ((0, pad), (0, 0)))
@@ -1407,7 +1424,8 @@ def search(
         req_strategy = "query_major"
     strategy, bucket, bb, q_tile = select_scan_strategy(
         req_strategy, queries.shape[0], n_probes, index.n_lists,
-        index.list_cap, index.rot_dim, res.workspace_limit_bytes, k=int(k),
+        index.list_cap, index.list_data.shape[-1], res.workspace_limit_bytes,
+        k=int(k),
     )
     # paged index: prefetch + pin the probed lists' pages before the scan
     # executables dispatch; ``list_data`` becomes the PagedLists view and
@@ -1541,7 +1559,7 @@ def search(
         itemsize = 1
     else:
         itemsize = 2 if scan_dtype == jnp.bfloat16 else 4
-    per_q = n_probes * index.list_cap * (index.rot_dim * itemsize + 12)
+    per_q = n_probes * index.list_cap * (list_data.shape[-1] * itemsize + 12)
     query_tile = int(min(max(queries.shape[0], 1), max(1, res.workspace_rows(per_q, cap=1024))))
     # per-row filters land here only when the fused descriptor leg was
     # unavailable — stamp the fallback distinctly for the perf ledger A/B

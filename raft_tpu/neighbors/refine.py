@@ -25,6 +25,7 @@ import numpy as np
 
 from raft_tpu.core.resources import Resources, ensure
 from raft_tpu.distance.pairwise import DISTANCE_TYPES, _PREC
+from raft_tpu.neighbors._common import lane_pad
 from raft_tpu.ops.matrix import select_k
 from raft_tpu.core.trace import traced
 
@@ -40,6 +41,19 @@ def _refine_query_tile(q: int, kprime: int, d: int) -> int:
     per_row = kprime * d * 4
     tile = max(8, _REFINE_TILE_BYTES // max(1, per_row))
     return min(q, 1 << (tile.bit_length() - 1))
+
+
+def prepare_rows(dataset) -> jax.Array:
+    """The device rows a refine that is called again and again should keep:
+    ``dataset`` zero-padded to whole 128-lane tiles (``_common.lane_pad``;
+    the array itself when its width already is), in its own dtype.
+
+    The refine's row gather reads the row-major tiling; a resident
+    ``[n, 96]`` array is stored transposed instead, and every refine call
+    would relayout all of it first.  :func:`refine` takes the padded rows
+    with queries of the logical width and returns the same results: it
+    drops the zero lanes from each gathered candidate block."""
+    return lane_pad(jnp.asarray(dataset))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "tile"))
@@ -63,7 +77,9 @@ def _refine_jit(dataset, queries, candidates, k: int, metric: str,
 
 def _refine_tile(dataset, queries, candidates, k: int, metric: str):
     safe = jnp.clip(candidates, 0, dataset.shape[0] - 1)
-    cand = dataset[safe].astype(jnp.float32)          # [q, k', d] gather
+    # rows from prepare_rows are wider than the queries: the gather reads
+    # them as stored, and their zero lanes are dropped from the block
+    cand = dataset[safe][..., : queries.shape[1]].astype(jnp.float32)
     qf = queries.astype(jnp.float32)
     ip = jnp.einsum("qd,qcd->qc", qf, cand, precision=_PREC)
     if metric == "inner_product":
@@ -99,18 +115,24 @@ def refine(
     """Exact re-rank of ``candidates`` [q, k'] → top-k (distances, indices).
 
     Negative candidate ids are treated as invalid (distance +inf), matching
-    the reference's handling of underfull candidate lists.
+    the reference's handling of underfull candidate lists.  ``dataset`` may
+    be the lane-padded rows of :func:`prepare_rows`.
     """
     res = ensure(res)
     canonical = DISTANCE_TYPES[metric]
     candidates = jnp.asarray(candidates, jnp.int32)
     if k > candidates.shape[1]:
         raise ValueError(f"k={k} > candidate count {candidates.shape[1]}")
+    width, d = np.shape(dataset)[1], np.shape(queries)[1]
+    if width < d:
+        raise ValueError(f"dataset rows are {width} wide, the queries {d}")
     if host:
         # the explicit CPU refine; serving refines on device (host=False)
-        return _refine_host(
-            np.asarray(dataset), np.asarray(queries), np.asarray(candidates), k, canonical  # raft-tpu: ignore[HOSTSYNC] opt-in host refine
-        )
+        qh = np.asarray(queries)  # raft-tpu: ignore[HOSTSYNC] opt-in host refine
+        ds = np.asarray(dataset)  # raft-tpu: ignore[HOSTSYNC] opt-in host refine
+        if width != d:  # drop the zero lanes of prepare_rows
+            ds = np.ascontiguousarray(ds[:, :d])
+        return _refine_host(ds, qh, np.asarray(candidates), k, canonical)  # raft-tpu: ignore[HOSTSYNC] opt-in host refine
     tile = _refine_query_tile(
         candidates.shape[0], candidates.shape[1], dataset.shape[1]
     )

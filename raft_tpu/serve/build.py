@@ -80,6 +80,7 @@ from raft_tpu.core.logger import logger as _log
 from raft_tpu.core.resources import Resources, ensure
 from raft_tpu.core.trace import trace_range, traced
 from raft_tpu.distance.pairwise import DISTANCE_TYPES, _PREC, distance_matrix_tile
+from raft_tpu.neighbors._common import padded_width
 from raft_tpu.obs import events
 from raft_tpu.ops import matrix
 from raft_tpu.serve.shard import (
@@ -647,7 +648,7 @@ def _build_ivf_pq_sharded(comms, data_np, x_sh, w_sh, n, params,
             "list_codes": l_codes.reshape(s_count, lp, cap, pq_dim),
             "list_index": l_index.reshape(s_count, lp, cap),
             "list_sizes": sizes.reshape(s_count, lp),
-            "list_data": l_data.reshape(s_count, lp, cap, rot_dim),
+            "list_data": l_data.reshape(s_count, lp, cap, l_data.shape[-1]),
             "list_y2": l_y2.reshape(s_count, lp, cap),
         }
         replicated = {"rotation": np.asarray(rotation)}
@@ -705,7 +706,7 @@ def _resolve_decoded_dtype(params, n, rot_dim, pq_dim):
     decoded = params.decoded_dtype
     if decoded == "auto":
         est_rows = int(n * 1.35) + 8 * params.n_lists
-        bf16_bytes = est_rows * (rot_dim * 2 + pq_dim + 8)
+        bf16_bytes = est_rows * (padded_width(rot_dim) * 2 + pq_dim + 8)
         total, limit_is_real = ivf_pq._device_memory_budget()
         budget = int(ivf_pq._AUTO_HBM_FRACTION * total)
         decoded = "int8" if bf16_bytes > budget and limit_is_real else "bfloat16"

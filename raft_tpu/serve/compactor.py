@@ -430,7 +430,7 @@ class Compactor:
                 struct_bytes += int(nb)
         padded = _next_pow2(m + 1)
         shadow_bytes = int(struct_bytes * (padded / max(mi.main_size, 1)))
-        if mi.refine_dataset is not None:
+        if mi.refine_rows is not None:
             # the shadow's refine rows, and the source's read back whole
             shadow_bytes += (padded + mi.main_size) * mi.dim * 4
         chunk_bytes = min(self.policy.chunk_rows, padded) * mi.dim * 4
@@ -449,7 +449,7 @@ class Compactor:
         rows = np.empty((m, mi.dim), np.float32)
         gids = np.empty((m,), np.int64)
         off = 0
-        if mi.refine_dataset is None:
+        if mi.refine_rows is None:
             main = mi.iter_main_rows(self.policy.chunk_rows)
         else:
             main = [(np.arange(mi.main_size),
@@ -522,7 +522,7 @@ class Compactor:
             main_ids=all_gids,
             # the refine rows follow the shadow's row order
             refine_dataset=(
-                None if mi.refine_dataset is None else jnp.asarray(all_rows)
+                None if mi.refine_rows is None else jnp.asarray(all_rows)
             ),
         )
         with shadow._lock:
@@ -570,7 +570,7 @@ class Compactor:
                 np.zeros((L, 8, old.pq_dim), np.uint8),
                 jnp.full((L, 8), -1, jnp.int32),
                 jnp.zeros((L,), jnp.int32),
-                jnp.zeros((L, 8, old.rot_dim), old.list_data.dtype),
+                jnp.zeros((L, 8, old.list_data.shape[-1]), old.list_data.dtype),
                 jnp.zeros((L, 8), jnp.float32),
                 headroom=old.headroom,
             )
@@ -715,8 +715,8 @@ class Compactor:
                 shadow.index, kind=shadow.kind,
                 search_params=shadow.search_params,
                 main_ids=shadow._main_ids,
-                refine_dataset=shadow.refine_dataset,
             )
+            warm.refine_rows = shadow.refine_rows  # shared, not re-padded
             with warm._lock:
                 warm._deleted[:] = shadow._deleted
                 warm._n_deleted = shadow._n_deleted
@@ -810,7 +810,7 @@ class Compactor:
                 "name": name, "status": "noop",
                 "reason": f"not mutable ({type(mi).__name__})",
             }
-        if mi.refine_dataset is not None:
+        if mi.refine_rows is not None:
             return self.abort(
                 name, "refined", "a sharded index has no refine leg"
             )

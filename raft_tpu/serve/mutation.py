@@ -131,9 +131,12 @@ class MutableIndex:
         ``refine`` recipe): when given, the main search returns
         ``k * REFINE_RATIO`` candidates and
         :func:`~raft_tpu.neighbors.refine.refine` re-scores them against
-        ``refine_dataset`` (``[main_size, dim]``, on device) to the exact
-        top-k.  Compressed backends need it for recall their codes cannot
-        reach alone.
+        ``refine_dataset`` (``[main_size, dim]``) to the exact top-k.
+        Compressed backends need it for recall their codes cannot reach
+        alone.  The index keeps only the lane-padded device copy,
+        ``refine_rows`` (:func:`~raft_tpu.neighbors.refine.prepare_rows`);
+        the ``refine_dataset`` attribute reads it back at ``[main_size,
+        dim]``.
     """
 
     def __init__(self, index, *, kind: Optional[str] = None, search_params=None,
@@ -154,7 +157,13 @@ class MutableIndex:
                 f"refine_dataset has shape {tuple(refine_dataset.shape)}, "
                 f"the index needs [{self.main_size}, {self.dim}]"
             )
-        self.refine_dataset = refine_dataset
+        # the rows the refine gathers, lane-padded once here so that no
+        # refine dispatch relayouts them (neighbors.refine.prepare_rows)
+        if refine_dataset is not None:
+            from raft_tpu.neighbors.refine import prepare_rows
+
+            refine_dataset = prepare_rows(refine_dataset)
+        self.refine_rows = refine_dataset
 
         if main_ids is not None:
             main_ids = np.asarray(main_ids, dtype=np.int64).reshape(-1)
@@ -211,6 +220,16 @@ class MutableIndex:
             )
 
     @property
+    def refine_dataset(self):
+        """The exact refine rows at ``[main_size, dim]``, or None.  A slice
+        of ``refine_rows`` made on each read, for save, compaction and
+        sharding; the search reads ``refine_rows`` itself."""
+        rows = self.refine_rows
+        if rows is None or rows.shape[1] == self.dim:
+            return rows
+        return rows[:, : self.dim]
+
+    @property
     def generation(self) -> int:
         """Monotonic mutation counter (bumps on every upsert/delete)."""
         with self._lock:
@@ -229,7 +248,7 @@ class MutableIndex:
 
         total = sum(_nb(v) for v in vars(self.index).values())
         total += _nb(self._main_ids) + _nb(self._main_ids_dev)
-        total += _nb(self.refine_dataset)
+        total += _nb(self.refine_rows)
         with self._lock:
             total += _nb(self._side_data) + _nb(self._side_ids)
             total += _nb(self._side_live) + _nb(self._deleted)
@@ -241,6 +260,27 @@ class MutableIndex:
                 if bs is not None:
                     total += _nb(bs.words)
         return total
+
+    def lane_pad_bytes(self) -> int:
+        """Bytes of zero lanes the resident arrays hold so that each row
+        is whole 128-lane tiles (``neighbors._common.lane_pad``): the
+        IVF-PQ scan cache past ``rot_dim`` and the refine rows past
+        ``dim``.  0 where the widths are lane multiples already."""
+        total = 0
+        if self.kind == "ivf_pq":
+            ld = self.index.list_data
+            extra = ld.shape[-1] - self.index.rot_dim
+            total += (
+                int(np.prod(ld.shape[:-1])) * extra
+                * np.dtype(ld.dtype).itemsize
+            )
+        rows = self.refine_rows
+        if rows is not None:
+            total += (
+                rows.shape[0] * (rows.shape[1] - self.dim)
+                * np.dtype(rows.dtype).itemsize
+            )
+        return int(total)
 
     def contains(self, id_: int) -> bool:
         with self._lock:
@@ -394,7 +434,7 @@ class MutableIndex:
             )
         params = self.search_params if search_params is None \
             else search_params
-        if self.refine_dataset is None:
+        if self.refine_rows is None:
             return mod.search(
                 params, self.index, queries, k,
                 deleted_mask=tombstones, sample_filter=sample_filter,
@@ -406,7 +446,7 @@ class MutableIndex:
             deleted_mask=tombstones, sample_filter=sample_filter,
         )
         return refine(
-            self.refine_dataset, queries, cand, k, metric=self.metric
+            self.refine_rows, queries, cand, k, metric=self.metric
         )
 
     def _side_passes(self, snap: _Snapshot, sample_filter):
@@ -622,8 +662,9 @@ class MutableIndex:
             rows = data[valid]
             if self.kind == "ivf_pq":
                 # decoded reconstructions live in rotated space (possibly
-                # int8 scan cache, hence scan_scale); invert the rotation
-                rows = (rows * scale) @ rot
+                # int8 scan cache, hence scan_scale, lane-padded past
+                # rot_dim); invert the rotation
+                rows = (rows[:, : rot.shape[0]] * scale) @ rot
             yield idx[valid].astype(np.int64), rows
 
     def _main_dataset(self) -> np.ndarray:
@@ -639,7 +680,8 @@ class MutableIndex:
             # decoded reconstructions live in rotated space (possibly int8
             # scan cache, hence scan_scale); invert the orthonormal rotation
             rot = np.asarray(self.index.rotation, dtype=np.float32)
-            out[idx[valid]] = (data[valid] * float(self.index.scan_scale)) @ rot
+            rows = data[valid][:, : rot.shape[0]]  # drop the zero lanes
+            out[idx[valid]] = (rows * float(self.index.scan_scale)) @ rot
         else:
             out[idx[valid]] = data[valid]
         return out
@@ -668,7 +710,7 @@ class MutableIndex:
                 # compacted indexes serve remapped ids; dropping the map on
                 # restore would silently re-serve dense row ids
                 arrays["main_ids"] = self._main_ids
-            if self.refine_dataset is not None:
+            if self.refine_rows is not None:
                 arrays["refine_dataset"] = np.asarray(self.refine_dataset)
             tiered = getattr(self.index, "paged", None)
             if tiered is not None:

@@ -808,10 +808,12 @@ class SearchService:
         Includes the per-stage latency breakdown under ``stages`` —
         queue-wait / pad / dispatch / device p50+p99 — so a p99 excursion
         decomposes without a profiler capture; the exact cumulative stage
-        sums and counts under ``stage_sum_s`` / ``stage_n``; and the
+        sums and counts under ``stage_sum_s`` / ``stage_n``; the
         process's garbage-collection counts and pause seconds under
-        ``host`` (:func:`raft_tpu.core.trace.gc_stats`).  The cumulative
-        fields difference over a window: ``stats1 - stats0``.
+        ``host`` (:func:`raft_tpu.core.trace.gc_stats`); and, for a
+        :class:`~raft_tpu.serve.mutation.MutableIndex`, the resident bytes
+        of zero lanes under ``lane_pad_bytes`` (its ``lane_pad_bytes()``).  The
+        cumulative fields difference over a window: ``stats1 - stats0``.
         """
         index, version = self.registry.get_versioned(name)
         out = self._batcher(name).metrics.snapshot()
@@ -825,6 +827,9 @@ class SearchService:
             side_rows=side,
             host=gc_stats(),
         )
+        lane_pad_bytes = getattr(index, "lane_pad_bytes", None)
+        if lane_pad_bytes is not None:
+            out["lane_pad_bytes"] = lane_pad_bytes()
         ctrl = self._admission.get(name)
         if ctrl is not None:
             out.update(

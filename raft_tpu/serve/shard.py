@@ -201,7 +201,7 @@ class ShardedIndex:
             merge_dtype = merge_dtype_from_env()
         deleted = None
         if isinstance(index, MutableIndex):
-            if index.refine_dataset is not None:
+            if index.refine_rows is not None:
                 raise ValueError(
                     "cannot shard a MutableIndex with exact refine: the "
                     "sharded search has no refine leg"

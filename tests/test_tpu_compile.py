@@ -163,3 +163,98 @@ def test_ivf_pq_search_program_compiles(one_chip, monkeypatch):
         ((1998, CAP), jnp.float32),
         ((1998, CAP), jnp.int32),
     )
+
+
+def _param_copies(text: str, names) -> list:
+    """``copy`` instructions that XLA put in for an entry parameter named in
+    ``names``: such a copy carries the parameter's own name as its
+    ``op_name`` (whether it reads the parameter or an alias of it)."""
+    return [
+        line.strip()[:160] for line in text.splitlines()
+        if " copy(" in line
+        and any(f'op_name="{name}"' in line for name in names)
+    ]
+
+
+def _deep_programs(width: int, q: int):
+    """(fn, arg specs, resident parameter names) of the three programs a
+    DEEP search dispatch runs, over a scan cache and refine rows ``width``
+    lanes wide, at a small list count."""
+    import importlib
+
+    from raft_tpu.neighbors import ivf_pq
+
+    refine_mod = importlib.import_module("raft_tpu.neighbors.refine")
+    n_lists, n_probes = 64, 32
+    qm = jax.jit(
+        ivf_pq._search_query_major_pallas.__wrapped__,
+        static_argnames=("n_probes", "k", "metric", "scan_dtype", "interpret"),
+    )
+    pm = jax.jit(
+        ivf_pq._search_probe_major_pallas.__wrapped__,
+        static_argnames=(
+            "n_probes", "k", "metric", "bucket", "scan_dtype", "interpret"
+        ),
+    )
+    lists = [
+        ((q, 96), jnp.float32),
+        ((n_lists, 96), jnp.float32),
+        ((ROT, 96), jnp.float32),
+        ((n_lists, 1000, width), jnp.bfloat16),
+        ((n_lists, 1000), jnp.float32),
+        ((n_lists, 1000), jnp.int32),
+    ]
+    return {
+        "query_major": (
+            lambda qq, c, rot, list_data, y2, idx: qm(
+                qq, c, rot, list_data, y2, idx, None, 1.0, n_probes=n_probes,
+                k=40, metric="inner_product", scan_dtype="float32",
+                interpret=False,
+            ),
+            lists, ("list_data",),
+        ),
+        "probe_major": (
+            lambda qq, c, rot, list_data, y2, idx: pm(
+                qq, c, rot, list_data, y2, idx, None, 1.0, n_probes=n_probes,
+                k=40, metric="inner_product", bucket=16, scan_dtype="float32",
+                interpret=False,
+            ),
+            lists, ("list_data",),
+        ),
+        "refine": (
+            lambda dataset, qq, cand: refine_mod._refine_jit(
+                dataset, qq, cand, 10, "inner_product", tile=None
+            ),
+            [
+                ((999_000, width), jnp.float32),
+                ((q, 96), jnp.float32),
+                ((q, 40), jnp.int32),
+            ],
+            ("dataset",),
+        ),
+    }
+
+
+@pytest.mark.parametrize("program,q", [
+    ("query_major", 1), ("query_major", 1024), ("probe_major", 256),
+    ("refine", 1), ("refine", 1024),
+])
+def test_deep_search_programs_read_lane_padded_rows_in_place(
+    one_chip, monkeypatch, program, q
+):
+    """The scan cache and the refine rows are stored lane-padded
+    (``_common.lane_pad``): the compiled search and refine programs then
+    read them in their default layout, with no whole-array ``copy`` of
+    the resident parameter at entry.  At the unpadded DEEP width 96 the
+    same programs do relayout it: the check sees the copy it guards."""
+    from raft_tpu.neighbors._common import padded_width
+
+    monkeypatch.setattr(kernels, "_platform", lambda: "tpu")
+    monkeypatch.delenv("RAFT_TPU_PALLAS", raising=False)
+    for width, copied in ((padded_width(ROT), False), (ROT, True)):
+        fn, shapes, names = _deep_programs(width, q)[program]
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        copies = _param_copies(text, names)
+        assert bool(copies) == copied, (width, copies)
