@@ -19,6 +19,7 @@ RAFT_TPU_PALLAS:
 from __future__ import annotations
 
 import threading
+from typing import Optional, Tuple
 
 import jax
 
@@ -85,6 +86,38 @@ def consume_kernel_path(default: str = "unknown") -> str:
     return path if path is not None else default
 
 
+# ---------------------------------------------------------------------------
+# data-dependent search work
+#
+# A graph walk's length depends on the data and its loop exits early, so
+# its work cannot be counted from shapes.  The search returns the counts
+# as one more small device array of the same program; the routing code
+# stamps it here beside the kernel path, and the batcher reads it in the
+# copy-out it already makes, after the batch barrier — no extra sync.
+
+_search_work_tls = threading.local()
+
+
+def stamp_search_work(work: jax.Array, n_rows: int) -> None:
+    """Record the work counts of the current search call: ``work`` is an
+    int32 device array whose first ``n_rows`` entries are each query row's
+    expanded parents and whose remaining entries are the loop trips of
+    each query tile.  Like the kernel-path stamp, the last call wins.  A
+    search traced inside an outer jit or shard_map stamps nothing: its
+    counts are tracers, which must not outlive their trace."""
+    if isinstance(work, jax.core.Tracer):
+        return
+    _search_work_tls.value = (work, int(n_rows))
+
+
+def consume_search_work() -> Optional[Tuple[jax.Array, int]]:
+    """Pop the stamp left by the last search on this thread (None when
+    that search stamped none: the IVF and brute-force paths)."""
+    stamp = getattr(_search_work_tls, "value", None)
+    _search_work_tls.value = None
+    return stamp
+
+
 def interpret_mode() -> bool:
     """Pallas interpret=True off-TPU so kernels are testable on CPU
     (SURVEY §5: sanitizer analog — interpret mode is also the OOB guard)."""
@@ -104,6 +137,8 @@ __all__ = [
     "interpret_mode",
     "stamp_kernel_path",
     "consume_kernel_path",
+    "stamp_search_work",
+    "consume_search_work",
     "fused_l2_topk",
     "fused_l2_argmin",
     "ivf_scan_probe_major",

@@ -62,6 +62,9 @@ _INF = float("inf")
 #: rounds stop paying past it
 MAX_ITOPK = 512
 
+#: name of the hop's op in a device trace (the named scope of its call)
+HOP_OP = "cagra_fused_hop"
+
 
 def traverse_supported(dataset, itopk: int) -> bool:
     """Routing gate for the fused hop: dense float dataset (f32/bf16 —
@@ -128,11 +131,15 @@ def _hop_kernel(par_ref, cand_ref, g_blk, q_blk, bd_blk, bi_blk, be_blk,
     if metric == "inner_product":
         cd = -ip
     else:
-        # v² via an MXU ones-contraction keeps every vector op 2-D
+        # v² via an MXU ones-contraction keeps every vector op 2-D; at
+        # HIGHEST like ip: a DEFAULT-precision dot rounds the squares to
+        # bf16 (one pass), which put ~4e-4 of the pair's scale into every
+        # distance on the chip and scrambled the ranking of near neighbours
         v2 = lax.dot_general(
             jnp.full((1, d), 1.0, jnp.float32), rows * rows,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=lax.Precision.HIGHEST,
         )                                                    # [1, deg]
         q2 = jnp.sum(q * q, axis=1, keepdims=True)           # [1, 1]
         cd = jnp.maximum(q2 + v2 - 2.0 * ip, 0.0)
@@ -275,7 +282,7 @@ def cagra_fused_hop(
             cand.reshape(-1).astype(jnp.int32),
         )
         ds_operand = dataset
-    od, oi, oe = pl.pallas_call(
+    hop = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
@@ -285,13 +292,18 @@ def cagra_fused_hop(
         ],
         cost_estimate=c.as_pallas(),
         interpret=interpret,
-    )(
-        *scalars,
-        graph.reshape(n, 1, deg),
-        queries[:, None, :],
-        buf_d[:, None, :],
-        buf_i[:, None, :],
-        explored[:, None, :].astype(jnp.int32),
-        ds_operand,
     )
+    # the scope names the custom call: a device trace shows the hop's time
+    # under ``%cagra_fused_hop.<n>`` (HOP_OP), wherever the loop calling it
+    # sits
+    with jax.named_scope(HOP_OP):
+        od, oi, oe = hop(
+            *scalars,
+            graph.reshape(n, 1, deg),
+            queries[:, None, :],
+            buf_d[:, None, :],
+            buf_i[:, None, :],
+            explored[:, None, :].astype(jnp.int32),
+            ds_operand,
+        )
     return od[:, 0, :], oi[:, 0, :], oe[:, 0, :] != 0

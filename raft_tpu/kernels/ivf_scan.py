@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from raft_tpu.kernels.toolkit import fold_topk, quantize_queries_i8
+from raft_tpu.kernels.toolkit import fold_topk, quantize_queries_i8, select_topk
 from raft_tpu.ops import cost as ops_cost
 from raft_tpu.store.paged import PagedLists
 
@@ -166,22 +166,31 @@ def _scan_kernel(bucket_list_ref, dec_ref, y2_ref, ids_ref, filt_ref, qg_ref,
     (scale_ref, SMEM) — the memory-lean DEEP-100M mode's scoring, fused.
     ``metric`` picks the score: L2 (y² − 2ip + q²) or inner product
     (−ip); ``filtered`` expands the list's packed filter words to a lane
-    mask and demotes failing slots."""
+    mask and demotes failing slots.  A padding bucket (list id < 0: the
+    grid runs the static bucket bound, and a batch fills only part of it)
+    writes empty partials without scoring or folding."""
     G = qg_ref.shape[1]
-    # Mosaic lowering: every vector op stays 2-D — q2 rides as a [G, 1]
-    # column block and y2/ids as [1, cap] rows, so the masks build from
-    # plain 2-D broadcasts (1-D reshapes/transposes crash tpu_compile)
-    scores, cand_i = _score_against_list(
-        dec_ref[0], qg_ref[0], q2_ref[0], y2_ref[0], ids_ref[0],
-        filt_ref[0], scale_ref[0, 0],
-        metric=metric, filtered=filtered, scan_dtype=scan_dtype,
-    )
-    run_v = jnp.full((G, kk), _WORST, jnp.float32)
-    run_i = jnp.full((G, kk), -1, jnp.int32)
-    v, i = fold_topk(run_v, run_i, scores, cand_i, kk)
-    i = jnp.where(jnp.isfinite(v), i, -1)
-    vals_ref[0] = v
-    out_ids_ref[0] = i
+    live = bucket_list_ref[pl.program_id(0)] >= 0
+
+    @pl.when(live)
+    def _scan():
+        # Mosaic lowering: every vector op stays 2-D — q2 rides as a
+        # [G, 1] column block and y2/ids as [1, cap] rows, so the masks
+        # build from plain 2-D broadcasts (1-D reshapes/transposes crash
+        # tpu_compile)
+        scores, cand_i = _score_against_list(
+            dec_ref[0], qg_ref[0], q2_ref[0], y2_ref[0], ids_ref[0],
+            filt_ref[0], scale_ref[0, 0],
+            metric=metric, filtered=filtered, scan_dtype=scan_dtype,
+        )
+        v, i = select_topk(scores, cand_i, kk)
+        vals_ref[0] = v
+        out_ids_ref[0] = jnp.where(jnp.isfinite(v), i, -1)
+
+    @pl.when(jnp.logical_not(live))
+    def _empty():
+        vals_ref[0] = jnp.full((G, kk), _WORST, jnp.float32)
+        out_ids_ref[0] = jnp.full((G, kk), -1, jnp.int32)
 
 
 def _scan_paged_kernel(bucket_list_ref, page_slot_ref, dec_ref, y2_ref,
@@ -337,6 +346,10 @@ def ivf_scan_probe_major(
         )
     B, G, rot = q_gathered.shape
     L, cap, _ = list_data.shape
+    # a bucket whose first slot holds no query is padding: mark it -1 so
+    # the kernel skips it, and read list 0's blocks for it (fetched once,
+    # since consecutive padding buckets share the block index)
+    bucket_list = jnp.where(jnp.isfinite(q2_gathered[:, 0]), bucket_list, -1)
     filtered = list_filter is not None
     if not filtered:
         # single-word dummy rides the same BlockSpec; the kernel skips it
@@ -354,11 +367,14 @@ def ivf_scan_probe_major(
         grid=(B,),
         in_specs=[
             pl.BlockSpec(       # dec: the bucket's list rows (dynamic)
-                (1, cap, rot), lambda b, bl: (bl[b], 0, 0)
+                (1, cap, rot), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)
             ),
-            pl.BlockSpec((1, 1, cap), lambda b, bl: (bl[b], 0, 0)),   # y2
-            pl.BlockSpec((1, 1, cap), lambda b, bl: (bl[b], 0, 0)),   # ids
-            pl.BlockSpec((1, 1, cap_w), lambda b, bl: (bl[b], 0, 0)),  # filt
+            pl.BlockSpec(                                             # y2
+                (1, 1, cap), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)),
+            pl.BlockSpec(                                             # ids
+                (1, 1, cap), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)),
+            pl.BlockSpec(                                             # filt
+                (1, 1, cap_w), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)),
             pl.BlockSpec((1, G, rot), lambda b, bl: (b, 0, 0)),  # queries
             pl.BlockSpec((1, G, 1), lambda b, bl: (b, 0, 0)),    # q2 column
             pl.BlockSpec(memory_space=pltpu.SMEM),               # scan_scale

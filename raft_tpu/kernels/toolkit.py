@@ -135,10 +135,28 @@ def fold_topk(
     with no sort network — the right trade for the k ≤ 128 regime the fused
     kernels serve. Returns ([rows, k_pad] vals, idx) with slots ≥ k = worst.
     """
-    rows, k_pad = run_v.shape
     pool_v = jnp.concatenate([run_v, cand_v], axis=1)
     pool_i = jnp.concatenate([run_i, cand_i], axis=1)
-    n_pool = pool_v.shape[1]
+    return _extract_topk(pool_v, pool_i, k, run_v.shape[1], worst)
+
+
+def select_topk(
+    cand_v: jax.Array,  # [rows, c] candidate values, c >= k
+    cand_i: jax.Array,  # [rows, c] their indices
+    k: int,
+    *,
+    worst: float = float("inf"),
+) -> Tuple[jax.Array, jax.Array]:
+    """The k smallest of each row of one candidate tile, ascending:
+    :func:`fold_topk` into an empty resident top-k, without extracting
+    over the empty run's k slots. Returns ([rows, k] vals, idx)."""
+    return _extract_topk(cand_v, cand_i, k, k, worst)
+
+
+def _extract_topk(pool_v, pool_i, k: int, k_pad: int, worst: float):
+    """k rounds of masked min-extraction over ``pool``; ties go to the
+    first position."""
+    rows, n_pool = pool_v.shape
     pos = jax.lax.broadcasted_iota(jnp.int32, (rows, n_pool), 1)
 
     def extract(t, carry):

@@ -51,7 +51,7 @@ from raft_tpu.neighbors import brute_force, ivf_pq, nn_descent
 from raft_tpu.neighbors._common import sorted_id_dedup
 from raft_tpu.neighbors.refine import refine
 from raft_tpu.ops.matrix import select_k
-from raft_tpu.core.trace import traced
+from raft_tpu.core.trace import host_range, traced
 from raft_tpu.core.logger import logger as _log
 
 _SERIALIZATION_VERSION = 1
@@ -359,6 +359,33 @@ def _graph_build_ivf_pq_params(params: IndexParams, n: int, d: int):
     return ip, sp, gpu_top_k
 
 
+def _graph_source_candidates(idx, sp, dataset, k: int, res) -> jax.Array:
+    """[n, k] ids: every row's ``k`` nearest by the graph source's IVF-PQ
+    search.  The rows are searched list by list: a tile of neighbouring
+    rows probes few lists, so the probe-major scan fills its buckets and
+    skips the rest of its static bucket bound.  Each row's candidates are
+    the same in any order."""
+    n, d = dataset.shape
+    order = _list_order(idx.list_index)
+    qtile = _graph_build_qtile(res, n, d)
+    parts = []
+    for s in range(0, n, qtile):
+        rows = order[s : s + qtile]
+        parts.append(ivf_pq.search(sp, idx, dataset[rows], k, res=res)[1])
+    listed = jnp.concatenate(parts)
+    del parts
+    at = np.empty(n, np.int32)
+    at[order] = np.arange(n, dtype=np.int32)   # row -> its place in order
+    return listed[at]
+
+
+def _list_order(list_index: jax.Array) -> np.ndarray:
+    """The row ids in the order the IVF lists hold them (each row sits in
+    exactly one list)."""
+    ids = np.asarray(list_index).reshape(-1)
+    return ids[ids >= 0].astype(np.int32)
+
+
 def _graph_build_qtile(res, n: int, d: int) -> int:
     """Row-query tile for the search-all-rows graph stage (bounded by the
     per-query candidate workspace)."""
@@ -423,12 +450,7 @@ def build(
         # gpu_top_k = degree * refine_rate → exact refine → drop self
         ip, sp, gpu_top_k = _graph_build_ivf_pq_params(params, n, d)
         idx = ivf_pq.build(ip, dataset, res=res)
-        cand_parts = []
-        qtile = _graph_build_qtile(res, n, d)
-        for s in range(0, n, qtile):
-            _, ids = ivf_pq.search(sp, idx, dataset[s : s + qtile], gpu_top_k, res=res)
-            cand_parts.append(ids)
-        cands = jnp.concatenate(cand_parts)
+        cands = _graph_source_candidates(idx, sp, dataset, gpu_top_k, res)
         _, knn_graph = refine(
             dataset, dataset, cands, inter + 1, metric=params.metric, res=res
         )
@@ -628,7 +650,7 @@ def _search_jit(
         res_i = jnp.where(jnp.isfinite(res_d), res_i, -1)
 
         def cond(state):
-            it, buf_i, buf_d, explored, res_i, res_d = state
+            it, buf_i, buf_d, explored, res_i, res_d, _ = state
             frontier = ~explored & jnp.isfinite(buf_d)
             return (it < min_iter) | ((it < max_iter) & jnp.any(frontier))
 
@@ -638,7 +660,7 @@ def _search_jit(
         earlier = jnp.triu(jnp.ones((c_w, c_w), bool), k=1)
 
         def body(state):
-            it, buf_i, buf_d, explored, res_i, res_d = state
+            it, buf_i, buf_d, explored, res_i, res_d, expanded = state
             # ---- pick search_width best unexplored parents
             # (ref: pickup_next_parents search_single_cta_kernel-inl.cuh:55)
             front_d = jnp.where(explored | ~jnp.isfinite(buf_d), jnp.inf, buf_d)
@@ -648,6 +670,8 @@ def _search_jit(
             explored = explored.at[
                 jnp.arange(tile)[:, None], ppos
             ].set(True)
+            # the walk's work: (query, parent) pairs with a real parent
+            expanded = expanded + jnp.sum(parent_ok, axis=1, dtype=jnp.int32)
             if fused:
                 # ---- fused hop: expand + score + dedup + merge ride one
                 # Pallas kernel (kernels/cagra_traverse.py). Only the tiny
@@ -664,7 +688,8 @@ def _search_jit(
                     dataset, graph, qs, parents_m, buf_d, buf_i, explored,
                     metric=metric, interpret=interpret_mode(),
                 )
-                return it + 1, buf_i, buf_d, explored, res_i, res_d
+                return (it + 1, buf_i, buf_d, explored, res_i, res_d,
+                        expanded)
             # ---- expand: gather graph rows (the data-dependent gather)
             nbrs = graph[jnp.clip(parents, 0, n - 1)]             # [t, w, deg]
             nbrs = jnp.where(parent_ok[:, :, None], nbrs, -1)
@@ -713,11 +738,13 @@ def _search_jit(
             buf_i = jnp.where(jnp.isfinite(buf_d), buf_i, -1)
             explored = jnp.take_along_axis(all_e, pos, axis=1)
             explored = explored | ~jnp.isfinite(buf_d)
-            return it + 1, buf_i, buf_d, explored, res_i, res_d
+            return (it + 1, buf_i, buf_d, explored, res_i, res_d,
+                    expanded)
 
-        _, buf_i, buf_d, _, res_i, res_d = lax.while_loop(
+        it, buf_i, buf_d, _, res_i, res_d, expanded = lax.while_loop(
             cond, body,
-            (jnp.zeros((), jnp.int32), buf_i, buf_d, explored, res_i, res_d),
+            (jnp.zeros((), jnp.int32), buf_i, buf_d, explored, res_i, res_d,
+             jnp.zeros((tile,), jnp.int32)),
         )
         if filter_words is None:
             v, i = select_k(buf_d, k, select_min=True, input_indices=buf_i)
@@ -733,10 +760,13 @@ def _search_jit(
             v = -v
         elif metric == "euclidean":
             v = jnp.sqrt(jnp.maximum(v, 0.0))
-        return v, i
+        return v, i, expanded, it
 
-    vals, idx = lax.map(one_tile, (qt, st, ft))
-    return vals.reshape(-1, k)[:q], idx.reshape(-1, k)[:q]
+    vals, idx, expanded, trips = lax.map(one_tile, (qt, st, ft))
+    # work counts ride out beside the answers: each query's expanded
+    # parents, then each tile's loop trips (kernels.stamp_search_work)
+    work = jnp.concatenate([expanded.reshape(-1)[:q], trips])
+    return vals.reshape(-1, k)[:q], idx.reshape(-1, k)[:q], work
 
 
 # --------------------------------------------------------------------------
@@ -853,7 +883,12 @@ def search(
     bit-identical regardless of how the query batch is split.
 
     ``deleted_mask`` excludes set bits (tombstones, raft_tpu.serve) and
-    composes with ``sample_filter`` (pass-bits kept)."""
+    composes with ``sample_filter`` (pass-bits kept).
+
+    The walk's work — each query's expanded (query, parent) pairs and
+    each query tile's loop trips — comes out of the same program and is
+    stamped with ``kernels.stamp_search_work`` for the serving batcher,
+    which reads it in its copy-out."""
     res = ensure(res)
     from raft_tpu.neighbors._common import resolve_pass_filter
 
@@ -897,7 +932,8 @@ def search(
     # topped up with random seeds for graphs/queries the coarse table
     # mis-covers (ref rand_xor_mask seeds + num_random_samplings).
     if seed_ids is None:
-        seed_ids = make_seed_ids(params, index, queries, k, itopk=itopk)
+        with host_range("cagra.seed"):
+            seed_ids = make_seed_ids(params, index, queries, k, itopk=itopk)
     else:
         seed_ids = jnp.asarray(seed_ids, jnp.int32)
 
@@ -937,11 +973,13 @@ def search(
         and traverse_supported(dataset, itopk)
     )
     _kernels.stamp_kernel_path("pallas" if fused else "xla")
-    return _search_jit(
+    v, i, work = _search_jit(
         dataset, index.graph, queries, fw, seed_ids,
         int(k), int(itopk), int(width), int(max_iter), int(min_iter),
         metric, int(tile), fused=fused,
     )
+    _kernels.stamp_search_work(work, q)
+    return v, i
 
 
 # --------------------------------------------------------------------------

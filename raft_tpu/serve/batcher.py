@@ -119,6 +119,18 @@ class _Request:
         self.deadline = deadline    # absolute perf_counter s, or None
 
 
+def _read_search_work(stamp, n_real: int) -> Optional[Tuple[int, int]]:
+    """(expanded parents over the batch's ``n_real`` real rows, loop trips)
+    of a search-work stamp (``kernels.stamp_search_work``), or None.  Read
+    in the copy-out: the counts come from the program whose answers the
+    barrier already waited for."""
+    if stamp is None:
+        return None
+    work, n_rows = stamp
+    w = np.asarray(work)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
+    return int(w[: min(n_real, n_rows)].sum()), int(w[n_rows:].sum())
+
+
 class _InFlight:
     """One dispatched-but-not-completed batch, handed from the dispatch
     thread to the completion thread in submission order."""
@@ -128,6 +140,7 @@ class _InFlight:
         "inflight_wait", "t_dispatch", "t_enqueued", "dist", "ids",
         "compiles", "sp", "done", "seq", "t_pickup", "hedged",
         "kernel_path", "admit_level", "page", "dispatch_info", "coalesce",
+        "search_work",
     )
 
     def __init__(self, batch: List[_Request]):
@@ -139,6 +152,7 @@ class _InFlight:
         self.page = None           # explain: page-cache stats stamp
         self.dispatch_info = None  # explain: ragged dispatch params stamp
         self.coalesce = None       # straggler wait before the cut (worker)
+        self.search_work = None    # graph walk's work-count stamp
 
 
 class MicroBatcher:
@@ -313,6 +327,10 @@ class MicroBatcher:
         self._last_page_stats = None
         self._last_dispatch_info = None
         self._last_admit_level = 0
+        # the graph walk's work-count stamp (kernels.stamp_search_work),
+        # consumed per dispatch like the kernel path; None off the graph
+        # index
+        self._last_search_work = None
 
         self._cond = threading.Condition()
         self._queue: Deque[_Request] = deque()
@@ -447,6 +465,7 @@ class MicroBatcher:
         hedged = hedger is not None and any(r.priority == 0 for r in batch)
         self._last_hedged = hedged
         _kernels.consume_kernel_path()  # drop any stale stamp first
+        _kernels.consume_search_work()
         obs_explain.consume_page_stats()
         obs_explain.consume_dispatch()
         if hedged:
@@ -456,6 +475,7 @@ class MicroBatcher:
         self._last_kernel_path = _kernels.consume_kernel_path(
             self._kpath_default
         )
+        self._last_search_work = _kernels.consume_search_work()
         # explain stamps ride the same thread-local side channel as the
         # kernel-path stamp; empty (None) unless explain collection is on
         self._last_page_stats = obs_explain.consume_page_stats()
@@ -981,6 +1001,7 @@ class MicroBatcher:
             with host_range("serve.copy_out"):
                 dist = np.asarray(dist)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
                 ids = np.asarray(ids)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
+                work = _read_search_work(self._last_search_work, n)
         except Exception as exc:  # noqa: BLE001 — fail the waiting futures
             self._record_flight(
                 seq=seq, batch=batch, n=n, bucket=bucket,
@@ -1034,6 +1055,8 @@ class MicroBatcher:
                 request_ids=[r.req_id for r in batch],
                 kernel_path=self._last_kernel_path,
             )
+            if work is not None:
+                self.metrics.record_search_work(n, *work)
             if self._perf is not None:
                 # ledger entry rides the t1/t2 stamps already taken
                 # above — zero new clock calls on the hot path
@@ -1216,6 +1239,7 @@ class MicroBatcher:
                 rec.dist, rec.ids = dist, ids
                 rec.hedged = self._last_hedged
                 rec.kernel_path = self._last_kernel_path
+                rec.search_work = self._last_search_work
                 # explain stamps: instance state is only valid on this
                 # thread (dispatch lock held) — carry them on the record
                 # for the completion thread
@@ -1286,6 +1310,7 @@ class MicroBatcher:
             with host_range("serve.copy_out"):
                 dist = np.asarray(rec.dist)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
                 ids = np.asarray(rec.ids)  # raft-tpu: ignore[HOSTSYNC] staged copy-out after the barrier
+                work = _read_search_work(rec.search_work, rec.n)
         except Exception as exc:  # noqa: BLE001 — fail only this batch
             spans.finish_span(rec.sp)
             self._record_flight(
@@ -1367,6 +1392,8 @@ class MicroBatcher:
                 request_ids=[r.req_id for r in batch],
                 kernel_path=rec.kernel_path,
             )
+            if work is not None:
+                self.metrics.record_search_work(rec.n, *work)
             if self._perf is not None:
                 # same t3/t4 stamps the "device" stage above is built from,
                 # so per-key ledger totals reconcile with

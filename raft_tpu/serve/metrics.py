@@ -145,6 +145,9 @@ class ServingMetrics:
         self._inflight_peak = 0    # high-water mark of the above
         # kernel_path → dispatched batches: the live pallas/xla A/B tally
         self._kernel_paths: Dict[str, int] = {}
+        # a graph walk's data-dependent work, summed over the dispatches
+        # that stamped it (None until one does: the IVF paths never do)
+        self._search_work: Optional[Dict[str, int]] = None
         if name is not None:
             obs.default_registry().register_provider(
                 f"serve.{name}", self.snapshot
@@ -202,6 +205,21 @@ class ServingMetrics:
                     self._add_stage_locked(s, vals)
         self._mirror_batch(n_real_rows, bucket_rows, latencies_s, compiles,
                            stages, request_ids, kernel_path)
+
+    def record_search_work(self, n_real_rows: int, expansions: int,
+                           iterations: int) -> None:
+        """One dispatch's graph-walk work: ``expansions`` (query, parent)
+        pairs with a real parent over its ``n_real_rows`` real rows, and
+        ``iterations`` loop trips summed over its query tiles."""
+        with self._lock:
+            w = self._search_work
+            if w is None:
+                w = self._search_work = {"dispatches": 0, "rows": 0,
+                                         "expansions": 0, "iterations": 0}
+            w["dispatches"] += 1
+            w["rows"] += int(n_real_rows)
+            w["expansions"] += int(expansions)
+            w["iterations"] += int(iterations)
 
     def _add_stage_locked(self, stage: str, vals: Iterable[float]) -> None:
         dq = self._stage_lat.setdefault(
@@ -376,6 +394,9 @@ class ServingMetrics:
                 "stage_sum_s": dict(self._stage_sum),
                 "stage_n": dict(self._stage_n),
             }
+            if self._search_work is not None:
+                # cumulative: difference two snapshots over a window
+                out["search_work"] = dict(self._search_work)
         if lat.size:
             out["p50_ms"] = float(np.percentile(lat, 50) * 1e3)
             out["p99_ms"] = float(np.percentile(lat, 99) * 1e3)

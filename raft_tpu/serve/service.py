@@ -812,8 +812,11 @@ class SearchService:
         process's garbage-collection counts and pause seconds under
         ``host`` (:func:`raft_tpu.core.trace.gc_stats`); and, for a
         :class:`~raft_tpu.serve.mutation.MutableIndex`, the resident bytes
-        of zero lanes under ``lane_pad_bytes`` (its ``lane_pad_bytes()``).  The
-        cumulative fields difference over a window: ``stats1 - stats0``.
+        of zero lanes under ``lane_pad_bytes`` (its ``lane_pad_bytes()``).
+        A graph index (CAGRA) adds ``search_work``: dispatches, real rows,
+        expanded (query, parent) pairs and loop iterations, summed over
+        the dispatches it served.  The cumulative fields difference over
+        a window: ``stats1 - stats0``.
         """
         index, version = self.registry.get_versioned(name)
         out = self._batcher(name).metrics.snapshot()

@@ -88,6 +88,33 @@ def test_ivf_pq_build_algo(data):
     assert r >= 0.8, r
 
 
+def test_graph_source_candidates_do_not_depend_on_row_order(
+    data, monkeypatch
+):
+    """The build searches the rows list by list; every row gets the
+    candidates a search in row order, in tiles of the same size, gives."""
+    from raft_tpu.core.resources import ensure
+    from raft_tpu.neighbors import ivf_pq
+
+    x, _ = data
+    params = cagra.IndexParams(
+        intermediate_graph_degree=48, graph_degree=24, build_algo="ivf_pq"
+    )
+    ip, sp, k = cagra._graph_build_ivf_pq_params(params, *x.shape)
+    idx = ivf_pq.build(ip, x)
+    tile = 1000
+    monkeypatch.setattr(cagra, "_graph_build_qtile", lambda *_: tile)
+    order = cagra._list_order(idx.list_index)
+    assert sorted(order.tolist()) == list(range(x.shape[0]))
+    assert not np.array_equal(order, np.arange(x.shape[0]))
+    got = cagra._graph_source_candidates(idx, sp, jnp.asarray(x), k, ensure(None))
+    want = np.concatenate([
+        np.asarray(ivf_pq.search(sp, idx, x[s:s + tile], k)[1])
+        for s in range(0, x.shape[0], tile)
+    ])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_inner_product_metric(data):
     x, q = data
     params = cagra.IndexParams(

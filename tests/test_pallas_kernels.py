@@ -86,6 +86,26 @@ class TestToolkit:
         small = tk.choose_tile_policy(16, 100, 8)
         assert small.tile_m <= 512 and small.grid == (1, 1)
 
+    def test_select_topk_equals_fold_into_empty_run(self, rng):
+        from raft_tpu.kernels import toolkit as tk
+
+        rows, c, k = 8, 300, 40
+        # repeated values and +inf slots: ties go to the first position
+        a = rng.integers(0, 50, (rows, c)).astype(np.float32)
+        a[:, ::7] = np.inf
+        ia = jnp.asarray(rng.integers(0, 10_000, (rows, c)).astype(np.int32))
+        v1, i1 = tk.select_topk(jnp.asarray(a), ia, k)
+        v2, i2 = tk.fold_topk(
+            jnp.full((rows, k), float("inf")), jnp.full((rows, k), -1),
+            jnp.asarray(a), ia, k,
+        )
+        np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+        np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+        order = np.argsort(a, axis=1, kind="stable")[:, :k]
+        np.testing.assert_array_equal(
+            np.asarray(i1), np.take_along_axis(np.asarray(ia), order, 1)
+        )
+
     def test_fold_topk_matches_sort(self, rng):
         from raft_tpu.kernels import toolkit as tk
 
@@ -142,6 +162,40 @@ class TestIvfScanKernel:
             ),
             x,
         )
+
+    def test_padding_buckets_are_empty(self):
+        """A bucket whose first slot holds no query (q2 = +inf) is padding
+        of the static bucket bound: the kernel writes +inf / -1 there and
+        scores the live buckets' queries against their lists."""
+        from raft_tpu.kernels.ivf_scan import ivf_scan_probe_major
+
+        rng = np.random.default_rng(3)
+        L, cap, rot, B, G, kk = 4, 256, 32, 6, 8, 5
+        data = rng.standard_normal((L, cap, rot)).astype(np.float32)
+        y2 = (data.astype(np.float64) ** 2).sum(-1)
+        ids = np.arange(L * cap, dtype=np.int32).reshape(L, cap)
+        qg = rng.standard_normal((B, G, rot)).astype(np.float32)
+        q2 = (qg.astype(np.float64) ** 2).sum(-1)
+        q2[4:] = np.inf            # padding buckets
+        q2[1, 5:] = np.inf         # a part-filled live bucket
+        bl = np.array([2, 0, 3, 1, 0, 0], np.int32)
+        v, i = ivf_scan_probe_major(
+            jnp.asarray(bl), jnp.asarray(qg), jnp.asarray(q2, jnp.float32),
+            jnp.asarray(data), jnp.asarray(y2, jnp.float32), jnp.asarray(ids),
+            kk, scan_dtype="float32", interpret=True,
+        )
+        v, i = np.asarray(v), np.asarray(i)
+        assert np.isinf(v[4:]).all() and (i[4:] == -1).all()
+        assert np.isinf(v[1, 5:]).all() and (i[1, 5:] == -1).all()
+        for b in range(4):
+            live = np.isfinite(q2[b])
+            x = data[bl[b]].astype(np.float64)
+            d = y2[bl[b]][None] - 2 * qg[b][live] @ x.T + q2[b][live][:, None]
+            want = np.argsort(d, axis=1, kind="stable")[:, :kk]
+            np.testing.assert_array_equal(i[b][live], ids[bl[b]][want])
+            np.testing.assert_allclose(
+                v[b][live], np.take_along_axis(d, want, 1), rtol=1e-4,
+                atol=1e-3)
 
     def test_matches_xla_probe_major(self, monkeypatch):
         from raft_tpu.neighbors import ivf_pq
