@@ -10,26 +10,37 @@ sort are the hop's dominant HBM traffic — exactly the irregular workload
 the "Ragged Paged Attention" line of work (PAPERS.md) shows hand-written
 TPU kernels beat dense HLO at.
 
-This kernel fuses one hop over a ``grid=(tile, width)`` schedule:
+This kernel fuses one hop over a query-blocked schedule: each grid step
+walks a block of ``qb`` queries of the tile (whole sublane tiles, up to
+128, derived from the shapes by :func:`_query_block`; a tile that is not a
+multiple of ``qb`` is padded with inert rows whose parent is −1):
 
-- the *scalar-prefetched frontier ids* drive a dynamic-BlockSpec DMA of
-  each parent's neighbor list into VMEM (the ivf_scan pattern), and the
-  candidate ids (a tiny pre-gathered int table riding as a second
-  prefetched scalar) drive per-row in-kernel DMAs of the candidates'
-  dataset rows — the [tile, width·deg, d] gather copy never exists;
-- MXU scoring ([1, d] × [deg, d]ᵀ, f32 accumulate at HIGHEST precision,
-  matching the XLA hop's ``_query_distance`` einsum);
-- visited-dedup by membership against the VMEM-resident merged buffer
-  plus a strict-upper within-step mask (the reference's visited-hashmap
-  role, detail/cagra/hashmap.hpp);
-- itopk buffer maintenance in VMEM via ``toolkit.fold_topk``, with the
-  same resident-wins tie discipline as the XLA merge (buffer entries
-  occupy the pool's first positions).
+- its only view of the graph is the candidate-id table, the parents'
+  neighbor rows as XLA gathers them from the graph in whatever layout the
+  chip stores it (the kernel takes no graph operand, so nothing forces a
+  relayout of the whole graph inside the walk loop).  The table rides
+  twice: as prefetched scalars that address the candidates' row DMAs,
+  and as a VMEM block the dedup reads;
+- every one of the block's ``width·qb·deg`` row copies is started before
+  any is waited on, so a step pays about one HBM round trip rather than
+  one per row; the [tile, width·deg, d] gather copy never exists;
+- MXU scoring, eight queries at a time ([8, d] × [8·deg, d]ᵀ, f32
+  accumulate at HIGHEST precision, matching the XLA hop's
+  ``_query_distance`` einsum); each query's own ``deg`` columns are taken
+  out of the group's product by a masked roll-and-add tree, which adds
+  exact zeros;
+- visited-dedup by membership against each row's live buffer plus a
+  strict-upper within-parent mask (the reference's visited-hashmap role,
+  detail/cagra/hashmap.hpp);
+- itopk buffer maintenance via ``toolkit.fold_topk`` on [qb, itopk + deg]
+  pools, one fold per parent in parent order, with the same resident-wins
+  tie discipline as the XLA merge (buffer entries occupy the pool's first
+  positions).  Explored flags ride through the fold inside the ids (an
+  explored id ``i`` is held as ``−i−2``), so no membership pass has to
+  recover them.
 
-The merged buffer lives in scratch across the ``width`` steps of one
-query; the final step recovers explored flags by membership against the
-input buffer (buffer ids are unique, so the flag transfers exactly) and
-writes the three state planes once.
+Every row's arithmetic is its own: a row's result does not depend on
+``qb`` or on which rows share its block.
 
 The hop is bit-equivalent to the XLA body up to value ties at the
 buffer's eviction boundary (an evicted-then-reencountered id can displace
@@ -81,120 +92,183 @@ def traverse_supported(dataset, itopk: int) -> bool:
     )
 
 
-def _hop_kernel(par_ref, cand_ref, g_blk, q_blk, bd_blk, bi_blk, be_blk,
-                dataset_ref, od_blk, oi_blk, oe_blk, rows_s, md_s, mi_s,
-                sem, *, metric: str, deg: int, itopk: int, width: int,
-                d: int, page_rows=None, ps_ref=None):
-    """One (query, parent) step.  Scratch (rows_s, md_s, mi_s) persists
-    across the ``width`` steps of a query; w==0 seeds the merged buffer
-    from the input planes and w==width−1 writes the merged state once.
+def _round_up(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
 
-    ``page_rows``/``ps_ref`` select the paged leg: ``dataset_ref`` is then
-    the HBM page pool ``[slots, page_rows, d]`` and each candidate row DMA
-    translates its global id through the prefetched ``page_slot`` table —
-    the "one more prefetched indirection" the page table costs."""
-    t = pl.program_id(0)
-    w = pl.program_id(1)
-    pid = par_ref[t * width + w]
 
-    @pl.when(w == 0)
-    def _seed():
-        md_s[...] = bd_blk[0]
-        # invariant from the XLA wrapper: buf_i is −1 wherever buf_d is
-        # +inf, so membership below never matches a stale id
-        mi_s[...] = bi_blk[0]
+#: queries a score group takes: one sublane tile
+_GROUP = 8
+#: most queries a grid step walks
+_MAX_BLOCK = 128
+#: VMEM a block's candidate rows, and its dedup compares, may take
+_VMEM_BUDGET = 4 << 20
 
-    # ---- candidate dataset rows: per-row DMA driven by the prefetched
-    # candidate-id table (invalid ids clamp to row 0; scores masked below)
-    def load(j, _):
-        cid = jnp.maximum(cand_ref[(t * width + w) * deg + j], 0)
-        if page_rows is None:
-            src = dataset_ref.at[pl.ds(cid, 1), :]
-        else:
-            pg = cid // page_rows
-            slot = jnp.maximum(ps_ref[pg], 0)
-            src = dataset_ref.at[slot, pl.ds(cid - pg * page_rows, 1), :]
-        cp = pltpu.make_async_copy(src, rows_s.at[pl.ds(j, 1), :], sem)
-        cp.start()
-        cp.wait()
-        return 0
 
-    lax.fori_loop(0, deg, load, 0)
+def _query_block(tile: int, width: int, deg_p: int, d: int, itopk: int,
+                 itemsize: int) -> int:
+    """Queries per grid step: the tile rounded up to whole sublane tiles,
+    at most ``_MAX_BLOCK``, and no more than the block's candidate rows
+    (``width·deg_p·d`` elements a query) and dedup compares
+    (``deg_p·itopk``) fit the VMEM budget.  A step's fold runs its
+    ``itopk`` rounds in about the same time for 8 rows as for 128, so the
+    block is as tall as that allows."""
+    per_query = max(width * deg_p * d * itemsize, deg_p * itopk * 4)
+    fit = _VMEM_BUDGET // per_query // _GROUP * _GROUP
+    return max(_GROUP, min(_round_up(tile, _GROUP), _MAX_BLOCK, fit))
 
-    rows = rows_s[...].astype(jnp.float32)                   # [deg, d]
-    q = q_blk[0].astype(jnp.float32)                         # [1, d]
+
+def _own_columns(x, deg_p: int):
+    """[8, 8·deg_p] → [8, deg_p]: row b keeps columns b·deg_p … of its
+    group's product (its own query's candidates).  The other columns are
+    zeroed and the 8 column groups summed by a roll-and-add tree, so each
+    kept value only has exact zeros added to it."""
+    nw = _GROUP * deg_p
+    row = lax.broadcasted_iota(jnp.int32, (_GROUP, nw), 0)
+    col = lax.broadcasted_iota(jnp.int32, (_GROUP, nw), 1)
+    x = jnp.where((col >= row * deg_p) & (col < row * deg_p + deg_p), x, 0.0)
+    s = nw // 2
+    while s >= deg_p:
+        x = x + pltpu.roll(x, s, 1)
+        s //= 2
+    return x[:, :deg_p]
+
+
+def _score(q, rows, metric: str, deg_p: int):
+    """Distances of a group's 8 queries ``q`` [8, d] to their own
+    candidates among ``rows`` [8·deg_p, d] (query-major) → [8, deg_p]."""
     ip = lax.dot_general(
         q, rows, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=lax.Precision.HIGHEST,
-    )                                                        # [1, deg]
+    )                                                        # [8, 8·deg_p]
     if metric == "inner_product":
         cd = -ip
     else:
         # v² via an MXU ones-contraction keeps every vector op 2-D; at
         # HIGHEST like ip: a DEFAULT-precision dot rounds the squares to
         # bf16 (one pass), which put ~4e-4 of the pair's scale into every
-        # distance on the chip and scrambled the ranking of near neighbours
+        # distance on the chip and scrambled the ranking of near
+        # neighbours
         v2 = lax.dot_general(
-            jnp.full((1, d), 1.0, jnp.float32), rows * rows,
+            jnp.full((1, q.shape[1]), 1.0, jnp.float32), rows * rows,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=lax.Precision.HIGHEST,
-        )                                                    # [1, deg]
-        q2 = jnp.sum(q * q, axis=1, keepdims=True)           # [1, 1]
+        )                                                    # [1, 8·deg_p]
+        q2 = jnp.sum(q * q, axis=1, keepdims=True)           # [8, 1]
         cd = jnp.maximum(q2 + v2 - 2.0 * ip, 0.0)
+    return _own_columns(cd, deg_p)
 
-    # ---- visited dedup: membership vs the live merged buffer (covers the
-    # original buffer AND earlier parents' survivors) + a strict-upper
-    # within-step mask for duplicate neighbors in one list
-    cand = g_blk[0]                                          # [1, deg]
-    m_i = mi_s[...]                                          # [1, itopk]
-    in_buf = jnp.any(cand[:, :, None] == m_i[:, None, :], axis=2)
-    pi = lax.broadcasted_iota(jnp.int32, (1, deg, deg), 1)
-    pj = lax.broadcasted_iota(jnp.int32, (1, deg, deg), 2)
-    dup = jnp.any(
-        (cand[:, :, None] == cand[:, None, :]) & (pi < pj), axis=1
-    )
-    bad = (cand < 0) | in_buf | dup | (pid < 0)
-    cd = jnp.where(bad, _INF, cd)
-    cand = jnp.where(bad, -1, cand)
 
-    # ---- fold into the merged buffer: residents ride the pool's first
-    # positions, so fold_topk's first-position tie-break keeps the XLA
-    # merge's resident-wins discipline
-    v, i = fold_topk(md_s[...], m_i, cd, cand, itopk)
-    # the +inf slots a short pool leaves behind must not carry ids (they
-    # would shadow later finite copies) — same fixup as the XLA hop
-    i = jnp.where(jnp.isfinite(v), i, -1)
-    md_s[...] = v
-    mi_s[...] = i
+def _decode(code):
+    """Buffer ids from the fold's codes (explored id i is held as −i−2)."""
+    return jnp.where(code <= -2, -code - 2, code)
 
-    @pl.when(w == width - 1)
-    def _finish():
-        mv = md_s[...]
-        mi = mi_s[...]
-        # explored flags transfer by membership against the input buffer
-        # (ids unique): new candidates are unexplored, +inf slots explored
-        hit = (mi[:, :, None] == bi_blk[0][:, None, :]) & (
-            be_blk[0][:, None, :] != 0
+
+def _hop_kernel(cand_ref, c_blk, q_blk, bd_blk, bi_blk, be_blk, dataset_ref,
+                od_blk, oi_blk, oe_blk, rows_s, sem, *, metric: str, qb: int,
+                deg_p: int, itopk: int, width: int, tile_p: int,
+                page_rows=None, ps_ref=None):
+    """One block of ``qb`` queries.  ``cand_ref`` is the flat prefetched
+    candidate table ([width, tile_p, deg_p], −1 = no candidate); the rows
+    scratch holds the block's candidate rows in (parent, query, slot)
+    order.
+
+    ``page_rows``/``ps_ref`` select the paged leg: ``dataset_ref`` is then
+    the HBM page pool ``[slots, page_rows, d]`` and each candidate row DMA
+    translates its global id through the prefetched ``page_slot`` table —
+    the "one more prefetched indirection" the page table costs."""
+    t0 = pl.program_id(0) * qb
+
+    def row_copy(cid, r):
+        if page_rows is None:
+            src = dataset_ref.at[pl.ds(cid, 1), :]
+        else:
+            pg = cid // page_rows
+            slot = jnp.maximum(ps_ref[pg], 0)
+            src = dataset_ref.at[slot, pl.ds(cid - pg * page_rows, 1), :]
+        return pltpu.make_async_copy(src, rows_s.at[pl.ds(r, 1), :], sem)
+
+    def each_candidate(fn):
+        # (parent, query, slot) order; ids < 0 start no copy (their
+        # scores are masked to +inf below, whatever the scratch holds)
+        for w in range(width):
+            def per_query(b, _, w=w):
+                base = (w * tile_p + t0 + b) * deg_p
+                r0 = (w * qb + b) * deg_p
+
+                def per_slot(c, _):
+                    cid = cand_ref[base + c]
+
+                    @pl.when(cid >= 0)
+                    def _():
+                        fn(row_copy(cid, r0 + c))
+
+                    return 0
+
+                lax.fori_loop(0, deg_p, per_slot, 0, unroll=True)
+                return 0
+
+            lax.fori_loop(0, qb, per_query, 0)
+
+    # all of the block's copies in flight before the first wait
+    each_candidate(lambda cp: cp.start())
+    each_candidate(lambda cp: cp.wait())
+
+    q = q_blk[...].astype(jnp.float32)                       # [qb, d]
+    pi = lax.broadcasted_iota(jnp.int32, (qb, deg_p, deg_p), 1)
+    pj = lax.broadcasted_iota(jnp.int32, (qb, deg_p, deg_p), 2)
+    # the fold carries explored flags inside the ids
+    md = bd_blk[...]
+    code = jnp.where(be_blk[...] != 0, -bi_blk[...] - 2, bi_blk[...])
+    gw = _GROUP * deg_p
+    for w in range(width):
+        # score in groups of 8 queries: the MXU product of a group is
+        # [8, 8·deg_p], so the work grows with qb, not with qb²
+        r0 = w * qb * deg_p
+        cd = jnp.concatenate([
+            _score(q[g * _GROUP:(g + 1) * _GROUP],
+                   rows_s[r0 + g * gw:r0 + (g + 1) * gw, :].astype(
+                       jnp.float32),
+                   metric, deg_p)
+            for g in range(qb // _GROUP)
+        ], axis=0)                                           # [qb, deg_p]
+
+        # ---- visited dedup: membership vs the live buffer (covers the
+        # input buffer AND earlier parents' survivors) + a strict-upper
+        # within-parent mask for duplicate neighbors in one list
+        cand = c_blk[w]                                      # [qb, deg_p]
+        live = _decode(code)
+        in_buf = jnp.any(cand[:, :, None] == live[:, None, :], axis=2)
+        dup = jnp.any(
+            (cand[:, :, None] == cand[:, None, :]) & (pi < pj), axis=1
         )
-        exp = jnp.any(hit, axis=2) | ~jnp.isfinite(mv)
-        od_blk[0] = mv
-        oi_blk[0] = mi
-        oe_blk[0] = exp.astype(jnp.int32)
+        bad = (cand < 0) | in_buf | dup
+        cd = jnp.where(bad, _INF, cd)
+        cand = jnp.where(bad, -1, cand)
+
+        # ---- fold into the buffer: residents ride the pool's first
+        # positions, so fold_topk's first-position tie-break keeps the XLA
+        # merge's resident-wins discipline
+        md, code = fold_topk(md, code, cd, cand, itopk)
+        # the +inf slots a short pool leaves behind must not carry ids (they
+        # would shadow later finite copies) — same fixup as the XLA hop
+        code = jnp.where(jnp.isfinite(md), code, -1)
+
+    od_blk[...] = md
+    oi_blk[...] = _decode(code)
+    # new candidates are unexplored, +inf slots explored
+    oe_blk[...] = ((code <= -2) | ~jnp.isfinite(md)).astype(jnp.int32)
 
 
-def _hop_kernel_paged(par_ref, cand_ref, ps_ref, g_blk, q_blk, bd_blk,
-                      bi_blk, be_blk, pool_ref, od_blk, oi_blk, oe_blk,
-                      rows_s, md_s, mi_s, sem, *, metric: str, deg: int,
-                      itopk: int, width: int, d: int, page_rows: int):
+def _hop_kernel_paged(cand_ref, ps_ref, c_blk, q_blk, bd_blk, bi_blk,
+                      be_blk, pool_ref, od_blk, oi_blk, oe_blk, rows_s, sem,
+                      **kw):
     """Paged entry point: same hop body, with the page-slot table riding
-    as a third prefetched scalar ahead of the grid operands."""
+    as a second prefetched scalar ahead of the grid operands."""
     _hop_kernel(
-        par_ref, cand_ref, g_blk, q_blk, bd_blk, bi_blk, be_blk, pool_ref,
-        od_blk, oi_blk, oe_blk, rows_s, md_s, mi_s, sem, metric=metric,
-        deg=deg, itopk=itopk, width=width, d=d, page_rows=page_rows,
-        ps_ref=ps_ref,
+        cand_ref, c_blk, q_blk, bd_blk, bi_blk, be_blk, pool_ref, od_blk,
+        oi_blk, oe_blk, rows_s, sem, ps_ref=ps_ref, **kw,
     )
 
 
@@ -218,92 +292,85 @@ def cagra_fused_hop(
     paged = isinstance(dataset, PagedRows)
     n, d = dataset.shape
     deg = graph.shape[1]
-    # candidate-id table for the DMA scalars: a [tile, width, deg] int32
-    # gather — 4 bytes/candidate next to the d·itemsize/candidate row
-    # gather the kernel eliminates
+    # a score group's product (8 · deg_p columns) fills whole 128-lane
+    # vregs
+    deg_p = _round_up(deg, 16)
+    qb = _query_block(tile, width, deg_p, d, itopk,
+                      dataset.dtype.itemsize)
+    tile_p = _round_up(tile, qb)
+    pad = tile_p - tile
+    # candidate-id table, the kernel's one view of the graph: a
+    # [tile, width, deg] int32 gather — 4 bytes/candidate next to the
+    # d·itemsize/candidate row gather the kernel eliminates — padded with
+    # −1 (no candidate) to [width, tile_p, deg_p]
     cand = graph[jnp.clip(parents, 0, n - 1)]
     cand = jnp.where(parents[:, :, None] < 0, -1, cand)
+    cand = jnp.pad(
+        cand, ((0, pad), (0, 0), (0, deg_p - deg)), constant_values=-1
+    ).transpose(1, 0, 2)
 
     c = ops_cost.cagra_traverse_cost(
         tile, width, deg, d, itopk, itemsize=dataset.dtype.itemsize
     )
     ops_cost.note("cagra_traverse", c)
 
-    # index_maps take *rest so the same lambdas serve 2 (dense) or 3
-    # (paged: + page_slot) prefetched scalar operands
-    def _nbr_map(t, w, par, *rest):
-        return jnp.maximum(par[t * width + w], 0), 0, 0
-
-    def _tile_map(t, w, *rest):
-        return t, 0, 0
+    # index_maps take *_ so the same lambdas serve 1 (dense) or 2 (paged:
+    # + page_slot) prefetched scalar operands
+    def _blk(i, *_):
+        return i, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3 if paged else 2,
-        grid=(tile, width),
+        num_scalar_prefetch=2 if paged else 1,
+        grid=(tile_p // qb,),
         in_specs=[
-            # the parent's neighbor list (dynamic)
-            pl.BlockSpec((1, 1, deg), _nbr_map),
-            pl.BlockSpec((1, 1, d), _tile_map),
-            pl.BlockSpec((1, 1, itopk), _tile_map),
-            pl.BlockSpec((1, 1, itopk), _tile_map),
-            pl.BlockSpec((1, 1, itopk), _tile_map),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # dataset/pool in HBM
+            pl.BlockSpec((width, qb, deg_p), lambda i, *_: (0, i, 0)),
+            pl.BlockSpec((qb, d), _blk),
+            pl.BlockSpec((qb, itopk), _blk),
+            pl.BlockSpec((qb, itopk), _blk),
+            pl.BlockSpec((qb, itopk), _blk),
+            pl.BlockSpec(memory_space=pl.ANY),   # dataset/pool in HBM
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, itopk), _tile_map),
-            pl.BlockSpec((1, 1, itopk), _tile_map),
-            pl.BlockSpec((1, 1, itopk), _tile_map),
-        ],
+        out_specs=[pl.BlockSpec((qb, itopk), _blk)] * 3,
         scratch_shapes=[
-            pltpu.VMEM((deg, d), dataset.dtype),    # candidate rows
-            pltpu.VMEM((1, itopk), jnp.float32),    # merged values
-            pltpu.VMEM((1, itopk), jnp.int32),      # merged ids
+            pltpu.VMEM((width * qb * deg_p, d), dataset.dtype),  # rows
             pltpu.SemaphoreType.DMA(()),
         ],
     )
+    kw = dict(metric=metric, qb=qb, deg_p=deg_p, itopk=itopk, width=width,
+              tile_p=tile_p)
     if paged:
         kern = functools.partial(
-            _hop_kernel_paged, metric=metric, deg=deg, itopk=itopk,
-            width=width, d=d, page_rows=dataset.page_rows,
+            _hop_kernel_paged, page_rows=dataset.page_rows, **kw
         )
-        scalars = (
-            parents.reshape(-1).astype(jnp.int32),
-            cand.reshape(-1).astype(jnp.int32),
-            dataset.page_slot.astype(jnp.int32),
-        )
+        scalars = (cand.reshape(-1), dataset.page_slot.astype(jnp.int32))
         ds_operand = dataset.pool
     else:
-        kern = functools.partial(
-            _hop_kernel, metric=metric, deg=deg, itopk=itopk,
-            width=width, d=d,
-        )
-        scalars = (
-            parents.reshape(-1).astype(jnp.int32),
-            cand.reshape(-1).astype(jnp.int32),
-        )
+        kern = functools.partial(_hop_kernel, **kw)
+        scalars = (cand.reshape(-1),)
         ds_operand = dataset
     hop = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((tile, 1, itopk), jnp.float32),
-            jax.ShapeDtypeStruct((tile, 1, itopk), jnp.int32),
-            jax.ShapeDtypeStruct((tile, 1, itopk), jnp.int32),
+            jax.ShapeDtypeStruct((tile_p, itopk), jnp.float32),
+            jax.ShapeDtypeStruct((tile_p, itopk), jnp.int32),
+            jax.ShapeDtypeStruct((tile_p, itopk), jnp.int32),
         ],
         cost_estimate=c.as_pallas(),
         interpret=interpret,
     )
+    rows = ((0, pad), (0, 0))
     # the scope names the custom call: a device trace shows the hop's time
     # under ``%cagra_fused_hop.<n>`` (HOP_OP), wherever the loop calling it
     # sits
     with jax.named_scope(HOP_OP):
         od, oi, oe = hop(
             *scalars,
-            graph.reshape(n, 1, deg),
-            queries[:, None, :],
-            buf_d[:, None, :],
-            buf_i[:, None, :],
-            explored[:, None, :].astype(jnp.int32),
+            cand,
+            jnp.pad(queries, rows),
+            jnp.pad(buf_d, rows, constant_values=_INF),
+            jnp.pad(buf_i, rows, constant_values=-1),
+            jnp.pad(explored.astype(jnp.int32), rows),
             ds_operand,
         )
-    return od[:, 0, :], oi[:, 0, :], oe[:, 0, :] != 0
+    return od[:tile], oi[:tile], oe[:tile] != 0

@@ -86,7 +86,7 @@ def cagra_traverse_cost(
     flops = tile * width * per_parent
     bytes_accessed = tile * width * (
         deg * d * itemsize               # dataset rows DMA'd per parent
-        + deg * 4                        # neighbor-list block
+        + deg * 4                        # its candidate ids (no graph operand)
     ) + tile * (d * itemsize + 3 * itopk * 4 * 2)  # queries + buffers in/out
     return KernelCost(int(flops), int(bytes_accessed))
 

@@ -114,18 +114,24 @@ def _work(n_rows):
 
 @pytest.mark.parametrize("metric", ["sqeuclidean", "inner_product"])
 @pytest.mark.parametrize("width", [1, 2])
-@pytest.mark.parametrize("hop", ["xla", "pallas"])
+@pytest.mark.parametrize("hop", [
+    "xla", "pallas",
+    # query tiles that leave the fused hop's last block part padding
+    "pallas_tile1", "pallas_tile5", "pallas_tile13", "pallas_tile64",
+])
 def test_walk_matches_the_beam_reference(built, metric, width, hop,
                                          monkeypatch):
-    monkeypatch.setenv("RAFT_TPU_PALLAS", "1" if hop == "pallas" else "0")
+    path, _, tile = hop.partition("_tile")
+    monkeypatch.setenv("RAFT_TPU_PALLAS", "1" if path == "pallas" else "0")
     index, x, qs = built[metric]
-    if hop == "pallas":
+    if path == "pallas":
         qs = qs[:24]  # interpret mode runs each grid step in Python
-    params = cagra.SearchParams(itopk_size=ITOPK, search_width=width)
+    params = cagra.SearchParams(itopk_size=ITOPK, search_width=width,
+                                max_queries=int(tile or 0))
     seeds = cagra.make_seed_ids(params, index, jnp.asarray(qs), K)
     kernels.consume_kernel_path()
     d, i = cagra.search(params, index, qs, K, seed_ids=seeds)
-    assert kernels.consume_kernel_path() == hop
+    assert kernels.consume_kernel_path() == path
     work = _work(qs.shape[0])
     want_d, want_i, want_e = _reference(index, qs, seeds, metric, width)
     _assert_agrees(x, qs, d, i, want_d, want_i, metric)

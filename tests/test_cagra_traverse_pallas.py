@@ -8,6 +8,8 @@ each other to (in practice the suites observe identical ids, asserted
 as distance-multiset equality to stay tie-robust).
 """
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -168,3 +170,107 @@ def test_zero_post_warmup_recompiles_with_kernels_enabled(
     assert compile_count() - c0 == 0, (
         "shuffled same-shape traffic recompiled with the fused hop on"
     )
+
+
+# --------------------------------------------------------------------------
+# the query-blocked hop: fused traverse_steps against the XLA body, state
+# for state, at tiles that are not a multiple of the query block
+# --------------------------------------------------------------------------
+
+def _paged(x, page_rows=64):
+    """``x`` as a PagedRows whose pages sit in the pool in reverse order,
+    so every row copy goes through the page-slot translation."""
+    from raft_tpu.store.paged import PagedRows
+
+    n, d = x.shape
+    n_pages = -(-n // page_rows)
+    rows = np.zeros((n_pages * page_rows, d), np.float32)
+    rows[:n] = x
+    pages = rows.reshape(n_pages, page_rows, d)
+    slot = np.arange(n_pages)[::-1].copy()
+    pool = np.zeros_like(pages)
+    pool[slot] = pages
+    return PagedRows(jnp.asarray(pool), jnp.asarray(slot, jnp.int32), n)
+
+
+@pytest.mark.parametrize("case,tile,width,metric", [
+    ("dense", 1, 1, "sqeuclidean"),
+    ("dense", 5, 2, "sqeuclidean"),
+    ("dense", 13, 1, "inner_product"),
+    ("dense", 24, 2, "inner_product"),
+    ("paged", 13, 1, "sqeuclidean"),
+    ("paged", 5, 2, "inner_product"),
+    ("halo", 13, 1, "sqeuclidean"),
+    ("halo", 5, 2, "sqeuclidean"),
+    ("dup", 13, 1, "sqeuclidean"),
+    ("dup", 24, 2, "inner_product"),
+    ("no_parent", 13, 1, "sqeuclidean"),
+    ("no_parent", 5, 2, "inner_product"),
+])
+def test_fused_steps_match_xla_steps(corpus, built, case, tile, width,
+                                     metric):
+    """``traverse_steps`` (the sharded walk's hop loop) through the fused
+    hop and through the XLA body, from the same seeded buffer: the paged
+    leg; −1 graph entries (a shard's missing halo neighbours); neighbour
+    lists that repeat ids; rows whose frontier is spent (parent −1), which
+    must come back unchanged."""
+    x, q = corpus
+    q = jnp.asarray(q[:tile])
+    graph = np.asarray(built.graph).copy()
+    rng = np.random.default_rng(tile)
+    if case == "halo":
+        graph[rng.random(graph.shape) < 0.3] = -1
+    elif case == "dup":
+        graph[:, 1::2] = graph[:, 0::2]
+    graph = jnp.asarray(graph)
+    dataset = _paged(x) if case == "paged" else jnp.asarray(x)
+    itopk = 32
+    seeds = jnp.asarray(rng.integers(0, len(x), (tile, 48)), jnp.int32)
+    buf_d, buf_i, explored = cagra.traverse_init(
+        dataset, q, seeds, itopk=itopk, metric=metric)
+    spent = np.zeros(tile, bool)
+    if case == "no_parent":
+        spent[::2] = True
+        explored = explored | jnp.asarray(spent)[:, None]
+    out = {}
+    for fused in (False, True):
+        out[fused] = [np.asarray(a) for a in cagra.traverse_steps(
+            dataset, graph, q, buf_d, buf_i, explored, steps=6,
+            width=width, metric=metric, fused=fused)]
+    (d0, i0, e0), (d1, i1, e1) = out[False], out[True]
+    np.testing.assert_allclose(d1, d0, rtol=1e-5, atol=1e-5)
+    # ids may differ only where equal distances tie at one slot
+    differ = i1 != i0
+    assert differ.sum() <= 0.01 * i0.size, np.argwhere(differ)
+    np.testing.assert_array_equal(e1[~differ], e0[~differ])
+    assert (i1[np.isfinite(d1)] >= 0).all()
+    if case == "no_parent":
+        np.testing.assert_array_equal(d1[spent], np.asarray(buf_d)[spent])
+        np.testing.assert_array_equal(i1[spent], np.asarray(buf_i)[spent])
+        assert e1[spent].all()
+    else:
+        assert (d1 < np.asarray(buf_d)).any()  # the walk moved
+
+
+@pytest.mark.parametrize("metric", ["sqeuclidean", "inner_product"])
+@pytest.mark.parametrize("width", [1, 2])
+def test_fused_row_result_ignores_its_block(corpus, built, metric, width):
+    """A query walked alone (its block padded with inert rows) and inside
+    a full batch (a block of other queries), from the same buffer, comes
+    back bit for bit the same.  (The walk starts from one buffer: the
+    XLA seeding einsum outside the hop may round differently at another
+    batch size on the CPU.)"""
+    x, q = corpus
+    q = jnp.asarray(q)
+    rng = np.random.default_rng(3)
+    seeds = jnp.asarray(rng.integers(0, len(x), (q.shape[0], 48)), jnp.int32)
+    state = cagra.traverse_init(built.dataset, q, seeds, itopk=32,
+                                metric=metric)
+    walk = functools.partial(cagra.traverse_steps, steps=8, width=width,
+                             metric=metric, fused=True)
+    full = [np.asarray(a) for a in walk(built.dataset, built.graph, q, *state)]
+    for r in (0, 5, 17):
+        one = walk(built.dataset, built.graph, q[r:r + 1],
+                   *(a[r:r + 1] for a in state))
+        for a, b in zip(full, one):
+            np.testing.assert_array_equal(np.asarray(b)[0], a[r])
