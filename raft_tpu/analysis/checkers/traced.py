@@ -69,8 +69,6 @@ SERVE_ENTRY_POINTS = {
     ("serve.overload.HedgedDispatcher", "dispatch"): "serve.hedge.dispatch",
     ("obs.autotune.Autotuner", "step"): "autotune.step",
     ("serve.effort.EffortArbiter", "apply"): "serve.effort.apply",
-    ("obs.perf.PerfLedger", "record"): "perf.record",
-    ("obs.perf.PerfLedger", "evaluate"): "perf.evaluate",
     ("store.tiered.TieredStore", "ensure_resident"): "store.pager.ensure",
     ("store.tiered.TieredStore", "prefetch"): "store.pager.prefetch",
     ("store.tiered.TieredStore", "evict"): "store.pager.evict",
@@ -87,9 +85,9 @@ SERVE_FUNCTION_ENTRY_POINTS = {
 }
 
 #: the closed ``kernel_path`` vocabulary (tabulated in docs/kernels.md) —
-#: the batcher, the perf ledger's hotspot keys, and the bench records all
-#: treat the stamp as an enum; a stray literal would silently mint a new
-#: ledger key that no dashboard or A/B gate knows to read
+#: the batcher's ``kernel_paths`` tally, its metric labels and the bench
+#: records all treat the stamp as an enum; a stray literal would silently
+#: mint a new label that no dashboard or A/B gate knows to read
 KERNEL_PATH_VOCAB = frozenset(
     {"pallas", "xla", "xla_filter_fallback", "sharded", "sharded_graph"}
 )
@@ -311,11 +309,11 @@ def _check_kernel_dispatch(project: Project, result) -> None:
 
     * every ``stamp_kernel_path(...)`` call stamps a literal from the
       closed :data:`KERNEL_PATH_VOCAB` (a non-enumerable stamp would mint
-      unreadable ledger keys at runtime);
+      unreadable ``kernel_paths`` labels at runtime);
     * every ``pallas_call`` under ``kernels.`` carries a
       ``cost_estimate=`` — without it the dispatch is an opaque custom
-      call with blank flops/bytes/roofline columns in
-      ``PerfLedger.top_hotspots()`` (ops/cost.py owns the formulas).
+      call with blank flops/bytes in its cost report (ops/cost.py owns
+      the formulas).
     """
     n_stamps = 0
     n_calls = 0
@@ -338,8 +336,8 @@ def _check_kernel_dispatch(project: Project, result) -> None:
                     f = project.finding(
                         "TRACED", mod, node, mod.name,
                         f"stamp_kernel_path({bad}) is outside the closed "
-                        f"vocabulary {sorted(KERNEL_PATH_VOCAB)} — ledger "
-                        "keys and bench A/B gates read the stamp as an "
+                        f"vocabulary {sorted(KERNEL_PATH_VOCAB)} — metric "
+                        "labels and bench A/B gates read the stamp as an "
                         "enum",
                         suppressed_sink=result.suppressed,
                     )
@@ -352,7 +350,7 @@ def _check_kernel_dispatch(project: Project, result) -> None:
                         "TRACED", mod, node, mod.name,
                         "pallas_call without cost_estimate= — the dispatch "
                         "is an opaque custom call to XLA's cost model, so "
-                        "its pallas ledger key reports blank flops/bytes/"
+                        "its cost report carries blank flops/bytes/"
                         "roofline (register a formula in ops/cost.py)",
                         suppressed_sink=result.suppressed,
                     )

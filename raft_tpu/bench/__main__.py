@@ -221,7 +221,7 @@ def main(argv=None):
     export.to_csv(results, base + ".csv")
     # one comparable headline record per run: the best-QPS operating point
     # among the runs that achieved the sweep's best recall — the shape
-    # ``compare`` diffs (schema-versioned, same envelope as bench.py legs)
+    # ``compare`` diffs (schema-versioned bench record)
     try:
         best_recall = max(r.recall for r in results)
         head = max(

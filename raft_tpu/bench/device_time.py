@@ -9,8 +9,8 @@ image, so a ~60-line protobuf *wire* parser extracts just what the
 counter needs (plane name, line events, event durations) — the schema is
 stable and public (tsl/profiler/protobuf/xplane.proto: XSpace.planes=1;
 XPlane.name=2,.lines=3; XLine.events=4; XEvent.offset_ps=2,
-.duration_ps=3 — field numbers verified against a live dump in
-tests/test_bench.py::TestDeviceTime).
+.duration_ps=3 — field numbers verified against a live dump by
+the ``TestDeviceTime`` tests).
 
 On host-only backends (CPU fallback) the profiler emits no ``/device:``
 plane and :func:`measure_device_time` returns None — callers report the

@@ -1,5 +1,5 @@
 """Result export: CSV tables, schema-versioned bench records, and the
-noise-aware record comparator behind ``bench.py compare``.
+noise-aware record comparator behind ``python -m raft_tpu.bench compare``.
 
 CSV side (ref: raft-ann-bench data_export — flattens the per-run JSON
 into build/search CSV tables for plotting) is unchanged.  The record side
@@ -275,9 +275,8 @@ def compare_records(
 
 
 def compare_main(argv: Optional[List[str]] = None) -> int:
-    """CLI body shared by ``bench.py compare`` and
-    ``python -m raft_tpu.bench compare``.  Exit 0 on pass/skip, 1 on
-    regression, 2 on usage/IO errors."""
+    """CLI body of ``python -m raft_tpu.bench compare``.  Exit 0 on
+    pass/skip, 1 on regression, 2 on usage/IO errors."""
     import argparse
 
     ap = argparse.ArgumentParser(
@@ -286,9 +285,8 @@ def compare_main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument("--baseline", required=True,
                     help="baseline record (BENCH_last.json / BENCH_r0N.json)")
-    ap.add_argument("--candidate", default="",
-                    help="candidate record; default: run the CPU bench leg "
-                    "now and compare its record")
+    ap.add_argument("--candidate", required=True,
+                    help="candidate record")
     ap.add_argument("--rtol", type=float, default=0.25,
                     help="relative tolerance for value/latency (default .25)")
     ap.add_argument("--recall-atol", type=float, default=0.02,
@@ -300,27 +298,8 @@ def compare_main(argv: Optional[List[str]] = None) -> int:
     except (OSError, ValueError) as e:
         print(f"compare: cannot load baseline: {e}")
         return 2
-    cand_path = args.candidate
-    if not cand_path:
-        import subprocess
-        import sys
-        import tempfile
-
-        cand_path = os.path.join(
-            tempfile.mkdtemp(prefix="raft_tpu_bench_"), "candidate.json"
-        )
-        env = dict(os.environ, **{RECORD_PATH_ENV: cand_path})
-        bench_py = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))), "bench.py")
-        print(f"compare: no --candidate; running {bench_py} --run-leg cpu")
-        proc = subprocess.run(
-            [sys.executable, bench_py, "--run-leg", "cpu"], env=env
-        )
-        if proc.returncode != 0 or not os.path.exists(cand_path):
-            print(f"compare: candidate leg failed (rc={proc.returncode})")
-            return 2
     try:
-        candidate = load_record(cand_path)
+        candidate = load_record(args.candidate)
     except (OSError, ValueError) as e:
         print(f"compare: cannot load candidate: {e}")
         return 2

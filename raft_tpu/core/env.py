@@ -57,8 +57,6 @@ KNOWN_VARS: Tuple[EnvVar, ...] = (
     EnvVar("RAFT_TPU_PIPELINE_DEPTH", "int", "2",
            "serving in-flight window: device batches the MicroBatcher "
            "overlaps; 1 = fully serial dispatch"),
-    EnvVar("RAFT_TPU_COST_ACCOUNTING", "bool", "1",
-           "0 skips the per-bucket XLA cost/memory gauges at warmup"),
     EnvVar("RAFT_TPU_SHARD_MERGE_DTYPE", "str", "float32",
            "bfloat16 quantizes the cross-shard merge all-gather of "
            "ShardedIndex candidate distances"),
@@ -238,26 +236,6 @@ KNOWN_VARS: Tuple[EnvVar, ...] = (
            "404"),
     EnvVar("RAFT_TPU_DISABLE_PROFILER", "bool", "unset",
            "1 disables the Perfetto capture helper"),
-    EnvVar("RAFT_TPU_PERF_LEDGER", "bool", "1",
-           "0 disables the measured perf ledger (per-executable "
-           "device-time attribution + regression detection)"),
-    EnvVar("RAFT_TPU_PERF_EWMA_ALPHA", "float", "0.25",
-           "fast-EWMA weight of the per-bucket device-time regression "
-           "detector (the slow baseline uses alpha/8)"),
-    EnvVar("RAFT_TPU_PERF_REGRESSION_X", "float", "1.5",
-           "regression trip ratio: fast device-time EWMA over this "
-           "multiple of the slow baseline publishes perf_regression"),
-    EnvVar("RAFT_TPU_PERF_MIN_SAMPLES", "int", "32",
-           "dispatches per executable key before the regression "
-           "detector arms (warm baselines only)"),
-    EnvVar("RAFT_TPU_PERF_DEBOUNCE_S", "float", "60",
-           "minimum seconds between perf_regression events (and profile "
-           "captures) per executable key"),
-    EnvVar("RAFT_TPU_PERF_CAPTURE_S", "float", "1.0",
-           "duration of the auto profile capture a perf_regression "
-           "triggers (0 disables the capture, the event still fires)"),
-    EnvVar("RAFT_TPU_PERF_CAPTURE_DIR", "str", "flight dir",
-           "where regression-triggered profiler captures are written"),
     # -- kernels / planners --------------------------------------------------
     EnvVar("RAFT_TPU_PALLAS", "str", "auto",
            "auto runs the Pallas kernels on TPU; 1 forces them "
@@ -285,22 +263,9 @@ KNOWN_VARS: Tuple[EnvVar, ...] = (
     # -- bench harness -------------------------------------------------------
     EnvVar("RAFT_TPU_BENCH_RECORD", "str", "BENCH_last.json",
            "bench record artifact path (- suppresses)"),
-    EnvVar("RAFT_TPU_BENCH_PIPELINE_DEPTHS", "str", "1,2,4",
-           "depth ladder for the bench.py serve pipeline A/B"),
-    EnvVar("RAFT_TPU_BENCH_DEVICE_MS", "float", "10",
-           "paced device interval for the serve A/B's async-device model"),
-    EnvVar("RAFT_TPU_BENCH_SLO_ROUNDS", "int", "3",
-           "interleaved off/on rounds pooled by the bench.py slo A/B"),
-    EnvVar("RAFT_TPU_BENCH_N", "int", "500000",
-           "accelerator bench corpus size"),
-    EnvVar("RAFT_TPU_BENCH_DEADLINE_S", "float", "1500",
-           "accelerator bench leg wall-clock budget"),
-    EnvVar("RAFT_TPU_BENCH_CPU_DEADLINE_S", "float", "600",
-           "CPU bench leg wall-clock budget"),
     # -- test harness --------------------------------------------------------
     EnvVar("RAFT_TPU_RUN_SLOW", "bool", "unset",
-           "1 opts into @pytest.mark.slow tests (bench smokes, scale "
-           "runs)"),
+           "1 opts into @pytest.mark.slow tests (scale runs)"),
     EnvVar("RAFT_TPU_SCALE_N", "int", "test default",
            "corpus size override for the scale test suite"),
 )

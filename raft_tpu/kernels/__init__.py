@@ -67,8 +67,8 @@ def cagra_fused_enabled() -> bool:
 # (the per-row-filter XLA leg), "sharded" (SPMD shard_map dispatch, where
 # per-leg stamps would fire at trace time only), "sharded_graph" (the
 # partitioned-graph CAGRA SPMD dispatch — separated from "sharded" so
-# ledger hotspots and bench records can tell the traversal from the
-# brute-refine control arm).
+# the kernel_paths tally and bench records can tell the traversal from
+# the brute-refine control arm).
 
 _kernel_path_tls = threading.local()
 

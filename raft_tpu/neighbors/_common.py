@@ -603,7 +603,7 @@ def paged_lists_for_search(index, queries, metric: str, n_probes: int):
         # per-dispatch coarse/residency bookkeeping entirely — nothing can
         # ever be evicted, so the page table is immutable after the pin.
         # This is what keeps the HBM-resident paged arm within a few
-        # percent of the monolithic control (bench.py paged).
+        # percent of the monolithic control.
         tiered.pin_identity()
         pool, page_slot = tiered.view()
         if explain_on:

@@ -166,7 +166,7 @@ def knn(
     select_min = canonical != "inner_product"
     n, d = dataset.shape
 
-    # perf-ledger attribution: the tiled XLA matmul path unless the fused
+    # kernel-path attribution: the tiled XLA matmul path unless the fused
     # Pallas leg below takes the call
     from raft_tpu.kernels import stamp_kernel_path
 

@@ -828,7 +828,7 @@ def search(
     per_q = 4 * n_probes * index.list_cap * (index.dim + 2)
     query_tile = int(min(max(queries.shape[0], 1), max(1, res.workspace_rows(per_q, cap=256))))
     # per-row filters land here only when the fused descriptor leg was
-    # unavailable — stamp the fallback distinctly for the perf ledger A/B
+    # unavailable — stamp the fallback distinctly in the kernel_paths tally
     _stamp_kernel_path("xla_filter_fallback" if per_row else "xla")
     return _search_jit(
         queries,

@@ -43,11 +43,6 @@ into queryable state:
 - :mod:`~raft_tpu.obs.incidents` — bus subscriber correlating bursts of
   events into incident timelines with service context at open/close,
   exported as JSON + Chrome trace alongside flight dumps.
-- :mod:`~raft_tpu.obs.perf` — measured perf ledger: per-executable
-  device-time attribution keyed ``(index, backend, bucket, kernel_path,
-  version)``, hotspot ranking with measured roofline utilization, and a
-  per-bucket EWMA regression detector that auto-triggers a profiler
-  capture and lands inside the correlated incident.
 - :mod:`~raft_tpu.obs.autotune` — closed-loop SLO autotuner: walks each
   served index's warmed effort ladder (through the serve
   ``EffortArbiter``) toward max QPS subject to recall ≥ floor and a
@@ -121,12 +116,7 @@ from raft_tpu.obs.incidents import (
     IncidentManager,
     incidents_snapshot,
 )
-from raft_tpu.obs.perf import (
-    PerfLedger,
-    default_ledger,
-    ledger_snapshot,
-)
-from raft_tpu.obs.profiler import capture_async, last_capture, profile
+from raft_tpu.obs.profiler import profile
 from raft_tpu.obs.quality import QualityAuditor
 from raft_tpu.obs.registry import (
     Counter,
@@ -157,7 +147,6 @@ from raft_tpu.obs import (
     gateway,
     health,
     incidents,
-    perf,
     profiler,
     quality,
     slo,
@@ -180,7 +169,6 @@ def install() -> None:
     reg.register_provider("spans", spans_snapshot)
     reg.register_provider("slow_queries", slowlog_snapshot)
     reg.register_provider("flight", flight_snapshot)
-    reg.register_provider("perf", ledger_snapshot)
     reg.register_provider("explain", explain_snapshot)
     events.default_bus()
 
@@ -212,7 +200,6 @@ __all__ = [
     "OPENMETRICS_CONTENT_TYPE",
     "OperationalGateway",
     "PROMETHEUS_CONTENT_TYPE",
-    "PerfLedger",
     "QualityAuditor",
     "QueryArchive",
     "SloEngine",
@@ -222,12 +209,10 @@ __all__ = [
     "analyze_callable",
     "analyze_compiled",
     "autotune",
-    "capture_async",
     "cost",
     "current_span",
     "default_archive",
     "default_bus",
-    "default_ledger",
     "default_recorder",
     "default_registry",
     "events",
@@ -241,12 +226,9 @@ __all__ = [
     "incidents",
     "incidents_snapshot",
     "install",
-    "last_capture",
-    "ledger_snapshot",
     "negotiate_content_type",
     "next_request_id",
     "open_span",
-    "perf",
     "profile",
     "profiler",
     "publish",

@@ -3,7 +3,7 @@
 Everything upstream of this module *observes* — the
 :class:`~raft_tpu.obs.quality.QualityAuditor` maintains a recall EWMA,
 the :class:`~raft_tpu.obs.slo.SloEngine` burns error budgets, the
-:class:`~raft_tpu.obs.perf.PerfLedger` attributes device seconds — but
+:class:`~raft_tpu.serve.metrics.ServingMetrics` sums device seconds — but
 until now the only *actuator* was PR 11's fixed overload hysteresis
 ladder.  This module closes the loop:
 
@@ -200,7 +200,7 @@ class _IndexState:
     floor: float
     auditor: Optional[object] = None
     slo: Optional[object] = None
-    perf: Optional[object] = None
+    metrics: Optional[object] = None
     latency_specs: Tuple[str, ...] = ()
     burn_ticks: int = 0
     calm_ticks: int = 0
@@ -257,11 +257,12 @@ class Autotuner:
     # -- index management ----------------------------------------------
 
     def watch_index(self, name: str, arbiter, *, index=None,
-                    auditor=None, slo=None, perf=None,
+                    auditor=None, slo=None, metrics=None,
                     floor: Optional[float] = None,
                     latency_specs: Optional[Tuple[str, ...]] = None) -> None:
         """Put ``name`` under closed-loop control.  ``arbiter`` is the
-        index's single effort writer; ``auditor``/``slo``/``perf`` are
+        index's single effort writer; ``auditor``/``slo``/``metrics``
+        (the index's :class:`~raft_tpu.serve.metrics.ServingMetrics`) are
         the optional measurement taps (a missing tap just removes that
         input from the policy).  ``latency_specs`` names the SloEngine
         specs whose alert/exhaustion means the p99 budget is unhealthy
@@ -276,7 +277,7 @@ class Autotuner:
         state = _IndexState(
             arbiter=arbiter, backend=backend, base_spec=base_spec,
             floor=self.recall_floor if floor is None else float(floor),
-            auditor=auditor, slo=slo, perf=perf,
+            auditor=auditor, slo=slo, metrics=metrics,
             latency_specs=tuple(latency_specs) if latency_specs is not None
             else (f"{name}-latency",),
         )
@@ -427,11 +428,8 @@ class Autotuner:
     def _report(self, name: str, state: _IndexState, level: int,
                 reason: Optional[str], ewma: Optional[float]) -> None:
         qps, _recall = state.predictions.get(level, (None, None))
-        if qps is None and state.perf is not None:
-            totals = state.perf.totals().get(name)
-            if totals and totals.get("device_s", 0.0) > 0 \
-                    and totals.get("rows", 0) > 0:
-                qps = float(totals["rows"]) / float(totals["device_s"])
+        if qps is None and state.metrics is not None:
+            qps = state.metrics.device_rows_per_s()
         self._registry.gauge(
             "raft_tpu_autotune_level",
             help="autotuner effort level (0 = full effort)",
@@ -444,8 +442,8 @@ class Autotuner:
         if qps is not None:
             self._registry.gauge(
                 "raft_tpu_autotune_predicted_qps",
-                help="frontier-predicted (or ledger-measured) QPS at the "
-                     "current effort level",
+                help="frontier-predicted (or measured rows per device "
+                     "second) QPS at the current effort level",
             ).set(float(qps), index=name)
         if reason is not None:
             _events.publish(

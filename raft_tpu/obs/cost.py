@@ -27,9 +27,10 @@ Three layers:
   (name, version) still alive on the host; versions the GC has collected
   get their series *removed*, so a stale series IS the leak report.
 
-Everything here runs at warmup or snapshot time — never on the serving
-hot path — and every extraction is exception-tolerant: a backend that
-cannot answer degrades to absent gauges, not to a crashed warmup.
+Everything here runs off the serving hot path (the live-buffer, page and
+mutation gauges at scrape time), and every extraction is
+exception-tolerant: a backend that cannot answer degrades to absent
+gauges, not to a crash.
 """
 
 from __future__ import annotations
@@ -193,8 +194,8 @@ def analyze_callable(fn, *args, time_run: bool = True) -> Optional[CostReport]:
     when lowering/compilation itself fails (e.g. a backend without AOT
     support) — callers treat that as "no gauges", not an error.
 
-    Note the compile here is a real XLA compile: callers must only do
-    this at warmup (the serve stack does), never per request.
+    Note the compile here is a real XLA compile: call it off the serving
+    path, never per request.
     """
     import jax
 

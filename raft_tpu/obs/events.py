@@ -69,7 +69,6 @@ KINDS = frozenset({
     "degraded_enter",
     "degraded_exit",
     "hedge_fired",
-    "perf_regression",
     "build_complete",
     "page_thrash",
     # closed-loop autotuner effort moves: context, not trigger — the
@@ -92,7 +91,6 @@ TRIGGER_KINDS = frozenset({
     "slo_burn",
     "admission_shed",
     "degraded_enter",
-    "perf_regression",
     "page_thrash",
 })
 
@@ -314,18 +312,15 @@ def _install_default_subscribers(bus: EventBus) -> None:
     from raft_tpu.obs import explain as _explain
     from raft_tpu.obs import flight as _flight
     from raft_tpu.obs import incidents as _incidents
-    from raft_tpu.obs import perf as _perf
 
-    # order matters: the flight dumper and the perf auto-capture run
-    # before the incident manager so the dump AND the profiler capture
-    # are fresh when the incident correlating the same event attaches
-    # its evidence; the query-archive dumper runs *after* the incident
-    # manager so its reentrant ``explain_dump`` context publish finds
-    # the incident the trigger just opened (earlier, the nested fan-out
-    # would reach the incident manager before the trigger itself and
-    # the artifact link would be dropped)
+    # order matters: the flight dumper runs before the incident manager
+    # so the dump is fresh when the incident correlating the same event
+    # attaches its evidence; the query-archive dumper runs *after* the
+    # incident manager so its reentrant ``explain_dump`` context publish
+    # finds the incident the trigger just opened (earlier, the nested
+    # fan-out would reach the incident manager before the trigger itself
+    # and the artifact link would be dropped)
     _flight.install_bus_subscriber(bus)
-    _perf.install_bus_subscriber(bus)
     _incidents.install(bus)
     _explain.install_bus_subscriber(bus)
     default_registry().register_provider("events", bus.snapshot)
@@ -388,9 +383,6 @@ def reset() -> None:
     flight = sys.modules.get("raft_tpu.obs.flight")
     if flight is not None:
         flight._on_bus_reset()
-    perf = sys.modules.get("raft_tpu.obs.perf")
-    if perf is not None:
-        perf._on_bus_reset()
     explain = sys.modules.get("raft_tpu.obs.explain")
     if explain is not None:
         explain._on_bus_reset()

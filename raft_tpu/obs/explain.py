@@ -1,7 +1,7 @@
 """Per-query EXPLAIN plans + the tail-sampled query archive.
 
-The rest of the obs stack explains *aggregate* behaviour (PerfLedger
-hotspots, SLO burn, incident timelines); this module explains a *single
+The rest of the obs stack explains *aggregate* behaviour (stage
+breakdowns, SLO burn, incident timelines); this module explains a *single
 request*: which admission decision it hit, what effort level was in
 force and who set it, which capacity bucket and kernel path served it,
 which coarse lists were probed, how the page cache treated its lists,

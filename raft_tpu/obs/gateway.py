@@ -2,7 +2,7 @@
 
 Every scrape/probe/debug answer in this package is a lock-light pull
 API — ``to_prometheus()``, ``SearchService.healthz()/readyz()``,
-``obs.snapshot()``, the incident/flight/explain/perf exports — but until
+``obs.snapshot()``, the incident/flight/explain exports — but until
 this module none of it was reachable from outside the process:
 ``docs/observability.md`` said "wire it to any HTTP handler" and
 stopped.  :class:`OperationalGateway` is that handler, stdlib-only
@@ -21,8 +21,8 @@ Read plane (GET):
   bucket ladder is warm (and always 503 with no service attached);
 - ``/snapshot`` — ``SearchService.metrics()`` (or the bare registry
   snapshot standalone);
-- ``/slo`` ``/autotune`` ``/perf/hotspots`` ``/incidents[/<id>]``
-  ``/flight`` — the corresponding subsystem snapshots;
+- ``/slo`` ``/autotune`` ``/incidents[/<id>]`` ``/flight`` — the
+  corresponding subsystem snapshots;
 - ``/explain?name=<index>&q=<v0,v1,...>`` — a deep-mode EXPLAIN replay
   through the live batched path (needs an attached service).
 
@@ -61,7 +61,6 @@ from raft_tpu.obs import export as _export
 from raft_tpu.obs import flight as _flight
 from raft_tpu.obs import health as _health
 from raft_tpu.obs import incidents as _incidents
-from raft_tpu.obs import perf as _perf
 from raft_tpu.obs.registry import MetricsRegistry, default_registry
 
 JSON_CONTENT_TYPE = "application/json; charset=utf-8"
@@ -217,7 +216,6 @@ class OperationalGateway:
             "/readyz": ("GET", self._r_readyz),
             "/snapshot": ("GET", self._r_snapshot),
             "/slo": ("GET", self._r_slo),
-            "/perf/hotspots": ("GET", self._r_hotspots),
             "/incidents": ("GET", self._r_incidents),
             "/incidents/<id>": ("GET", self._r_incident),
             "/flight": ("GET", self._r_flight),
@@ -393,15 +391,6 @@ class OperationalGateway:
         if engine is None:
             return _json_error(404, "no SLO engine configured")
         return _json_answer(200, engine.snapshot())
-
-    def _r_hotspots(self, query, arg, headers) -> _Answer:
-        try:
-            n = max(1, min(int(query.get("n", "8")), 64))
-        except ValueError:
-            return _json_error(400, "n must be an integer")
-        return _json_answer(
-            200, {"hotspots": _perf.default_ledger().top_hotspots(n)}
-        )
 
     def _r_incidents(self, query, arg, headers) -> _Answer:
         return _json_answer(200, _incidents.default_manager().snapshot())

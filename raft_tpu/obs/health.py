@@ -277,22 +277,6 @@ def slo_check(slo_health: Optional[Dict[str, object]]) -> Dict[str, object]:
     return _check(OK, "budgets healthy")
 
 
-def perf_check(perf: Optional[Dict[str, object]]) -> Dict[str, object]:
-    """Fold a :meth:`~raft_tpu.obs.perf.PerfLedger.health_slice` into a
-    health check: a device-time regression still inside its debounce
-    window is DEGRADED — the executable answers, but slower than its own
-    baseline, and the auto-captured profile is waiting to be read."""
-    if not perf:
-        return _check(OK, "perf ledger off or no dispatches yet")
-    active = list(perf.get("active_regressions") or ())
-    if active:
-        return _check(
-            DEGRADED,
-            "device-time regression on: " + ", ".join(sorted(active)),
-        )
-    return _check(OK, "no active device-time regressions")
-
-
 def budget_check(snapshot: Dict[str, object]) -> Dict[str, object]:
     """Fold a :meth:`raft_tpu.store.budget.MemoryBudget.snapshot` into a
     health check: a near-fully-reserved page budget is DEGRADED — the
@@ -315,7 +299,6 @@ def build_report(
     probes: Dict[str, IndexProbe],
     registry: Optional[MetricsRegistry] = None,
     slo: Optional[Dict[str, object]] = None,
-    perf: Optional[Dict[str, object]] = None,
     budget: Optional[Dict[str, object]] = None,
 ) -> Dict[str, object]:
     """Assemble the service-wide report and publish ``raft_tpu_health``.
@@ -347,9 +330,6 @@ def build_report(
     slo_c = slo_check(slo) if slo is not None else None
     if slo_c is not None:
         statuses.append(slo_c["status"])
-    perf_c = perf_check(perf) if perf is not None else None
-    if perf_c is not None:
-        statuses.append(perf_c["status"])
     budget_c = budget_check(budget) if budget is not None else None
     if budget_c is not None:
         statuses.append(budget_c["status"])
@@ -378,8 +358,6 @@ def build_report(
     }
     if slo_c is not None:
         report["slo"] = slo_c
-    if perf_c is not None:
-        report["perf"] = perf_c
     if budget_c is not None:
         report["budget"] = budget_c
     return report

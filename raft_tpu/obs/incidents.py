@@ -45,7 +45,6 @@ from typing import Callable, Dict, List, Optional
 from raft_tpu.core import env as _env
 from raft_tpu.core.trace import traced
 from raft_tpu.obs import flight as _flight
-from raft_tpu.obs import profiler as _profiler
 from raft_tpu.obs import spans as _spans
 from raft_tpu.obs.events import Event, EventBus, TRIGGER_KINDS
 from raft_tpu.obs.registry import default_registry
@@ -114,7 +113,6 @@ class Incident:
         self.context_open = context
         self.context_close: Optional[Dict[str, object]] = None
         self.flight: Optional[Dict[str, object]] = None
-        self.capture: Optional[Dict[str, object]] = None
         self.archive: Optional[Dict[str, object]] = None
         self.last_event_mono = time.monotonic()
         self.last_event_t = trigger.t
@@ -135,7 +133,6 @@ class Incident:
             "context_open": self.context_open,
             "context_close": self.context_close,
             "flight": self.flight,
-            "capture": self.capture,
             "archive": self.archive,
         }
 
@@ -149,7 +146,6 @@ class Incident:
             "resolution": self.resolution,
             "events": len(self.timeline),
             "flight": (self.flight or {}).get("path"),
-            "capture": (self.capture or {}).get("path"),
             "archive": (self.archive or {}).get("path"),
         }
 
@@ -245,14 +241,13 @@ class IncidentManager:
         is_trigger = event.kind in TRIGGER_KINDS and not event.recovered
         context = self._capture_context() if is_trigger else None
         dump = _flight.last_dump()
-        capture = _profiler.last_capture()
         opened = None
         dropped = False
         with self._lock:
             to_close = self._sweep_locked(now)
             target = self._match_locked(now)
             if target is not None:
-                self._append_locked(target, event, dump, capture, now)
+                self._append_locked(target, event, dump, now)
             elif is_trigger:
                 if len(self._open) >= self._max_open:
                     self._dropped += 1
@@ -260,7 +255,6 @@ class IncidentManager:
                 else:
                     opened = Incident(next(self._iid), event, context)
                     self._attach_flight_locked(opened, event, dump)
-                    self._attach_capture_locked(opened, event, capture)
                     self._open.append(opened)
                     self._opened_total += 1
             # a context/recovery event with no fresh incident: not a story
@@ -289,7 +283,6 @@ class IncidentManager:
 
     def _append_locked(self, inc: Incident, event: Event,
                        dump: Optional[Dict[str, object]],
-                       capture: Optional[Dict[str, object]],
                        now: float) -> None:
         inc.timeline.append(event.to_dict())
         inc.last_event_mono = now
@@ -306,7 +299,6 @@ class IncidentManager:
                 "unix_time": event.unix_time,
             }
         self._attach_flight_locked(inc, event, dump)
-        self._attach_capture_locked(inc, event, capture)
 
     def _attach_flight_locked(self, inc: Incident, event: Event,
                               dump: Optional[Dict[str, object]]) -> None:
@@ -328,29 +320,6 @@ class IncidentManager:
             "unix_time": dump.get("unix_time"),
             "path": dump.get("path"),
             "trace_path": dump.get("trace_path"),
-        })
-
-    def _attach_capture_locked(self, inc: Incident, event: Event,
-                               capture: Optional[Dict[str, object]]) -> None:
-        # Same contract as flight dumps: the perf auto-capture subscriber
-        # runs before us in bus order, so a capture this event triggered
-        # already started; attach only a fresh one, once.
-        if capture is None:
-            return
-        if abs(event.unix_time - float(capture["unix_time"])) > \
-                max(self._window_s, 1.0):
-            return
-        if inc.capture is not None and \
-                inc.capture.get("path") == capture["path"]:
-            return
-        inc.capture = capture
-        inc.timeline.append({
-            "kind": "profile_capture",
-            "reason": capture.get("reason"),
-            "t": event.t,
-            "unix_time": capture.get("unix_time"),
-            "path": capture.get("path"),
-            "duration_s": capture.get("duration_s"),
         })
 
     # -- closing -------------------------------------------------------------

@@ -6,26 +6,12 @@ in a span so registry snapshots record that (and how long) a profiling
 session ran, and the ``RAFT_TPU_DISABLE_PROFILER`` escape hatch from
 ``core.trace`` still applies — CI boxes without a writable trace dir can
 no-op the capture without touching call sites.
-
-:func:`capture_async` is the unattended variant the perf ledger's
-``perf_regression`` subscriber fires: ``jax.profiler.start_trace`` plus
-a timer-driven stop, so a regression detected on the serving path gets
-a bounded profile of the *next* few dispatches without blocking the
-publisher.  One capture runs at a time (the jax profiler is a process
-singleton); overlapping requests are counted and skipped.
-:func:`last_capture` exposes the newest capture's info the same way
-``flight.last_dump()`` does, which is what lets the incident manager
-attach captures into timelines exactly like flight dumps.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
-import re
-import threading
-import time
-from typing import Dict, Iterator, Optional
+from typing import Iterator
 
 from raft_tpu.core import env as _env
 from raft_tpu.obs import spans as _spans
@@ -53,94 +39,3 @@ def profile(log_dir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
     with _spans.span("obs.profile"):
         with jax.profiler.trace(log_dir):
             yield
-
-
-# ---------------------------------------------------------------------------
-# unattended captures (perf-regression auto-profile)
-
-_state_lock = threading.Lock()
-_active = False
-_last_capture: Optional[Dict[str, object]] = None
-
-
-def last_capture() -> Optional[Dict[str, object]]:
-    """``{"path", "reason", "duration_s", "t", "unix_time"}`` of the most
-    recent :func:`capture_async`, or None.  Recorded at capture *start*
-    so the incident correlating the triggering event can attach the
-    capture immediately (the trace file lands ``duration_s`` later)."""
-    with _state_lock:
-        return dict(_last_capture) if _last_capture is not None else None
-
-
-def capture_async(
-    log_dir: str, *, duration_s: float, reason: str = "manual",
-) -> Optional[Dict[str, object]]:
-    """Start a bounded profiler capture without blocking the caller.
-
-    Returns the capture info dict (also exposed by :func:`last_capture`)
-    or None when profiling is disabled, a capture is already running, or
-    the profiler refuses to start.  The stop runs on a daemon timer
-    thread after ``duration_s``.
-    """
-    global _active, _last_capture
-    if _env.env_bool("RAFT_TPU_DISABLE_PROFILER") or duration_s <= 0:
-        return None
-    import jax
-
-    with _state_lock:
-        if _active:
-            default_registry().counter(
-                "raft_tpu_profile_captures_skipped_total",
-                help="async capture requests skipped because one was "
-                     "already running",
-            ).inc()
-            return None
-        _active = True
-    stem = re.sub(r"[^A-Za-z0-9_.-]", "_", reason)
-    path = os.path.join(log_dir, f"profile_{stem}_{os.getpid()}")
-    try:
-        jax.profiler.start_trace(path)
-    except Exception:  # already tracing elsewhere — never fail the caller
-        with _state_lock:
-            _active = False
-        return None
-    info = {
-        "path": path,
-        "reason": reason,
-        "duration_s": float(duration_s),
-        "t": time.perf_counter(),
-        "unix_time": time.time(),
-    }
-    with _state_lock:
-        _last_capture = dict(info)
-    default_registry().counter(
-        "raft_tpu_profile_captures_total",
-        help="jax.profiler trace sessions started via obs.profile",
-    ).inc()
-    timer = threading.Timer(duration_s, _finish_capture)
-    timer.daemon = True
-    timer.start()
-    return info
-
-
-def _finish_capture() -> None:
-    global _active
-    with _state_lock:
-        if not _active:
-            return
-        _active = False
-    try:
-        import jax
-
-        jax.profiler.stop_trace()
-    except Exception:  # stop raced a reset — the capture is gone anyway
-        pass
-
-
-def reset() -> None:
-    """Stop any active capture and forget the last one (test hygiene,
-    reached through ``events.reset`` → ``perf._on_bus_reset``)."""
-    global _last_capture
-    _finish_capture()
-    with _state_lock:
-        _last_capture = None

@@ -26,8 +26,8 @@ multi-burn-rate policy: the **fast** pair (5 m short / 1 h long, burn
 when *both* windows of a pair burn, and re-arms when the short window
 recovers — the alarm-fatigue fix a single EWMA threshold lacks.  All
 windows (and the evaluation period) scale by
-``RAFT_TPU_SLO_WINDOW_SCALE`` so tests and ``bench.py slo`` run the
-same policy in seconds.
+``RAFT_TPU_SLO_WINDOW_SCALE`` so tests run the same policy in
+seconds.
 
 Alert edges publish ``slo_burn`` events on the obs bus (opening
 incidents, dumping flight artifacts); budget state exports as

@@ -1,10 +1,9 @@
 """Analytical cost model for the hand-written Pallas kernels.
 
-The PerfLedger's roofline attribution (obs/perf.py, obs/cost.py) reads
-flops / bytes from XLA's compiled cost analysis.  That works for the XLA
-legs, but a Pallas kernel is an opaque custom call on TPU — XLA reports
-nothing for it, so every ``kernel_path=pallas`` key used to show up in
-``top_hotspots()`` with blank flops/s / bytes/s / roofline columns.
+The roofline attribution in obs/cost.py reads flops / bytes from XLA's
+compiled cost analysis.  That works for the XLA legs, but a Pallas
+kernel is an opaque custom call on TPU — XLA reports nothing for it, so
+its cost report would carry blank flops / bytes / roofline columns.
 
 This module is the one owner of the per-kernel analytical cost formulas:
 

@@ -42,8 +42,7 @@ indexes that change while being served.  Five pieces:
 ``SearchService`` (:mod:`~raft_tpu.serve.service`) assembles them, and
 carries the obs v2 hooks: attach a :class:`raft_tpu.obs.QualityAuditor`
 for online recall auditing off the hot path, read ``healthz()`` /
-``readyz()`` for OK / DEGRADED / UNHEALTHY verdicts, and every warmup
-books XLA cost/memory figures per bucket into the registry.  See
+``readyz()`` for OK / DEGRADED / UNHEALTHY verdicts.  See
 ``docs/serving.md`` for the guided tour.
 """
 

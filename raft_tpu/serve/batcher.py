@@ -29,7 +29,7 @@ out, resolves futures in submission order, and runs the observer /
 metrics / slow-log off the dispatch path.  ``pipeline_depth=1`` keeps
 the original fully-serial dispatch, byte for byte.  Steady-state QPS at
 depth > 1 is bounded by the *max* of the host and device stage times
-rather than their sum (``bench.py serve`` measures the A/B).
+rather than their sum.
 
 Request identity: every :meth:`MicroBatcher.submit` assigns a
 process-wide monotonically increasing request id (returned on the future
@@ -81,7 +81,6 @@ from raft_tpu.kernels.toolkit import next_pow2
 from raft_tpu.obs import events as obs_events
 from raft_tpu.obs import explain as obs_explain
 from raft_tpu.obs import flight, slowlog, spans
-from raft_tpu.obs import perf as obs_perf
 from raft_tpu.serve.metrics import ServingMetrics, compile_count
 from raft_tpu.serve.overload import expire_deadlines, validate_priority
 
@@ -184,12 +183,6 @@ class MicroBatcher:
         auditor's shadow-sampling entry.  Exceptions are swallowed and
         the call sits after future resolution, so a misbehaving observer
         can delay the *next* batch but never fail or block a result.
-    cost_accounting:
-        When True (default; env ``RAFT_TPU_COST_ACCOUNTING=0`` disables)
-        :meth:`warmup` additionally AOT-compiles each bucket's executable
-        for XLA cost/memory analysis and publishes ``raft_tpu_xla_*``
-        gauges.  Purely best-effort: backends that cannot answer leave
-        the gauges absent.
     pipeline_depth:
         Bound on device batches in flight (default from
         ``RAFT_TPU_PIPELINE_DEPTH``, else 2).  ``1`` reproduces the
@@ -221,15 +214,6 @@ class MicroBatcher:
         ``hedger`` (a :class:`~raft_tpu.serve.overload.
         HedgedDispatcher`) reroutes batches carrying priority-0 traffic
         through a raced two-member dispatch; warmup warms every member.
-    perf_meta:
-        Optional zero-argument callable returning ``(backend, version)``
-        strings for the perf-ledger executable key — the service points
-        this at its registry so every dispatch is attributed to the
-        index *kind and version* actually serving it.  Standalone
-        batchers default to ``("unknown", "0")``.  The ledger itself
-        (:mod:`raft_tpu.obs.perf`) rides the stage stamps this class
-        already takes — ``RAFT_TPU_PERF_LEDGER=0`` disables it, sampled
-        once at construction so the hot path never re-reads env.
     """
 
     def __init__(
@@ -243,14 +227,12 @@ class MicroBatcher:
         metrics: Optional[ServingMetrics] = None,
         start: bool = True,
         observer: Optional[Observer] = None,
-        cost_accounting: Optional[bool] = None,
         pipeline_depth: Optional[int] = None,
         ragged=None,
         admission=None,
         degraded=None,
         effort=None,
         hedger=None,
-        perf_meta: Optional[Callable[[], Tuple[str, str]]] = None,
     ):
         if dim <= 0:
             raise ValueError(f"dim must be positive, got {dim}")
@@ -269,9 +251,6 @@ class MicroBatcher:
         self.max_delay_s = float(max_delay_ms) * 1e-3
         self.metrics = metrics if metrics is not None else ServingMetrics()
         self.observer = observer
-        if cost_accounting is None:
-            cost_accounting = _env.env_bool("RAFT_TPU_COST_ACCOUNTING", True)
-        self.cost_accounting = bool(cost_accounting)
         if pipeline_depth is None:
             pipeline_depth = _env.env_int("RAFT_TPU_PIPELINE_DEPTH", 2)
         if pipeline_depth < 1:
@@ -307,13 +286,6 @@ class MicroBatcher:
             # mirrored hedge members report their device windows here so
             # device_busy_s() merges the pair instead of double-counting
             hedger.on_interval = self._note_device_interval
-        # -- measured perf ledger (obs.perf) ---------------------------------
-        # enabled() is sampled ONCE: the hot path holds either a ledger
-        # reference or None, never an env read
-        self._perf = obs_perf.default_ledger() if obs_perf.enabled() else None
-        self._perf_meta = (
-            perf_meta if perf_meta is not None else (lambda: ("unknown", "0"))
-        )
         # attribution fallback when the search fn did not stamp a routing
         # choice this dispatch (e.g. hedged members run on pool threads,
         # whose thread-local stamps this thread cannot see)
@@ -385,13 +357,6 @@ class MicroBatcher:
         blocks on the result.  Compiles spent here are booked as
         ``warmup_compiles`` and the hot-path recompile counter is reset, so
         any later non-zero ``recompiles`` is a genuine shape leak.
-
-        With ``cost_accounting`` each bucket's executable is additionally
-        AOT-compiled for :mod:`raft_tpu.obs.cost` analysis — FLOPs, bytes
-        accessed, peak memory and roofline utilization land as
-        ``raft_tpu_xla_*`` gauges labeled ``index=<name>,bucket=<b>``.
-        The extra compiles happen here, inside warmup, so the hot-path
-        zero-recompile contract is untouched.
         """
         total = 0
         # degraded mode changes search params (host Python values the
@@ -416,8 +381,6 @@ class MicroBatcher:
                         if self.hedger is not None:
                             self.hedger.warm(*self._invoke_args(dummy, []))
                         total += compile_count(thread=True) - c0
-                        if self.cost_accounting and not level:
-                            self._account_bucket_cost(b, dummy)
         self.metrics.record_warmup(total)
         self.metrics.reset_hot_path()
         self._warm = True
@@ -504,33 +467,6 @@ class MicroBatcher:
         if self.ragged is not None and req.k < d.shape[1]:
             d, i = d[:, : req.k], i[:, : req.k]
         return d, i
-
-    def _account_bucket_cost(self, bucket: int, dummy: np.ndarray) -> None:
-        """Best-effort XLA cost/memory gauges for one bucket's executable."""
-        try:
-            from raft_tpu.obs import cost as obs_cost
-
-            args = self._invoke_args(dummy, [])
-            report = obs_cost.analyze_callable(self._search_fn, *args)
-            obs_cost.record_cost(
-                report,
-                index=self.metrics.name or "default",
-                bucket=str(bucket),
-            )
-            if (
-                self._perf is not None
-                and report.flops is not None
-                and report.bytes_accessed is not None
-            ):
-                # analytical per-dispatch cost for the ledger's measured
-                # roofline: keyed (index, bucket) — shapes are identical
-                # across kernel paths and versions
-                self._perf.register_cost(
-                    self.metrics.name or "default", int(bucket),
-                    report.flops, report.bytes_accessed,
-                )
-        except Exception:  # noqa: BLE001 — accounting must not fail warmup
-            pass
 
     @property
     def warm(self) -> bool:
@@ -1057,16 +993,6 @@ class MicroBatcher:
             )
             if work is not None:
                 self.metrics.record_search_work(n, *work)
-            if self._perf is not None:
-                # ledger entry rides the t1/t2 stamps already taken
-                # above — zero new clock calls on the hot path
-                backend, ver = self._perf_meta()
-                self._perf.record(
-                    index=self.metrics.name or "default", backend=backend,
-                    bucket=bucket, kernel_path=self._last_kernel_path,
-                    version=ver, device_s=t2 - t1, rows=n,
-                    padded_rows=bucket,
-                )
             self._record_flight(
                 seq=seq, batch=batch, n=n, bucket=bucket, compiles=compiles,
                 t_pickup=t_start, t_done=done,
@@ -1394,17 +1320,6 @@ class MicroBatcher:
             )
             if work is not None:
                 self.metrics.record_search_work(rec.n, *work)
-            if self._perf is not None:
-                # same t3/t4 stamps the "device" stage above is built from,
-                # so per-key ledger totals reconcile with
-                # stage_totals()["device"]
-                backend, ver = self._perf_meta()
-                self._perf.record(
-                    index=self.metrics.name or "default", backend=backend,
-                    bucket=rec.bucket, kernel_path=rec.kernel_path,
-                    version=ver, device_s=t_device, rows=rec.n,
-                    padded_rows=rec.bucket,
-                )
             self._record_flight(
                 seq=rec.seq, batch=batch, n=rec.n, bucket=rec.bucket,
                 compiles=rec.compiles,

@@ -31,8 +31,8 @@ scale:
   batcher's zero-recompile contract holds.
 
 The brute-refine path stays the default (``RAFT_TPU_SHARD_CAGRA=brute``)
-and the correctness control arm; ``bench.py shard_cagra`` freezes the
-graph-vs-brute A/B (matched recall, modeled per-device work ratio).
+and the correctness control arm; ``tests/test_graph_shard.py`` holds
+graph mode to the single-host walk's recall.
 """
 
 from __future__ import annotations
@@ -331,8 +331,7 @@ class GraphShardedIndex(ShardedIndex):
         traversal plan ``_traverse_config(kk)`` resolves: seed scoring at
         init plus ``width·deg`` candidate scores per hop.  The brute-refine
         control arm scores every resident row (``rows_len``), so
-        ``rows_len / total`` is the modeled per-device work ratio the
-        ``bench.py shard_cagra`` A/B freezes."""
+        ``rows_len / total`` is the modeled per-device work ratio."""
         cfg = self._traverse_config(kk)
         rl = int(self._parts["rows"].shape[1])
         deg = int(self._parts["graph"].shape[2])

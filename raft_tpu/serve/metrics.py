@@ -44,8 +44,8 @@ _RESERVOIR = 4096
 #: ``inflight_wait`` only appears at pipeline_depth > 1: it is the time a
 #: formed batch waited for an in-flight window slot (device backpressure),
 #: measured before the dispatch stage.  ``record`` is the per-batch hook
-#: cost after the futures resolved (observer, metrics, perf ledger, flight
-#: recorder, slow-log).
+#: cost after the futures resolved (observer, metrics, flight recorder,
+#: slow-log).
 STAGES = ("queue", "coalesce", "pad", "inflight_wait", "dispatch", "device",
           "record")
 
@@ -414,6 +414,16 @@ class ServingMetrics:
             if a.size
         }
         return out
+
+    def device_rows_per_s(self) -> Optional[float]:
+        """Real rows answered per second of the ``device`` stage, over
+        every batch recorded so far; None before any device time."""
+        with self._lock:
+            device_s = self._stage_sum.get("device", 0.0)
+            rows = self._fill_real
+        if device_s <= 0 or rows <= 0:
+            return None
+        return rows / device_s
 
     def stage_totals(self) -> Dict[str, float]:
         """Exact cumulative sum of each recorded stage in seconds.

@@ -246,7 +246,7 @@ class RaggedSearcher:
             # and replicates to every shard (ShardedIndex.search rebases
             # it per shard; one extra cached executable per k, never a
             # per-(k × filter) lattice)
-            # perf-ledger attribution: the SPMD body traces once, so the
+            # kernel-path attribution: the SPMD body traces once, so the
             # routing stamp happens here on the host, not inside search
             # (graph-mode CAGRA serves filtered traffic through its exact
             # brute-refine core, so a filtered dispatch stamps "sharded")

@@ -45,7 +45,6 @@ from raft_tpu.obs import explain as obs_explain
 from raft_tpu.obs import gateway as obs_gateway
 from raft_tpu.obs import health as obs_health
 from raft_tpu.obs import incidents as obs_incidents
-from raft_tpu.obs import perf as obs_perf
 from raft_tpu.obs import slo as obs_slo
 from raft_tpu.obs import spans as obs_spans
 from raft_tpu.obs.quality import QualityAuditor
@@ -109,6 +108,14 @@ class SearchService:
             obs_gateway.OperationalGateway,
         ] = None,
     ):
+        # accepted only as None/False, for callers that still pass it:
+        # warmup runs no cost analysis
+        if cost_accounting:
+            raise ValueError(
+                "cost_accounting=True is no longer supported: serving warmup "
+                "runs no cost analysis (call raft_tpu.obs.cost."
+                "analyze_callable directly)"
+            )
         install_compile_listener()
         install_gc_hook()
         # full pipeline: XLA event attribution + span/slowlog snapshot
@@ -122,7 +129,6 @@ class SearchService:
         self.max_delay_ms = max_delay_ms
         self.replicas = replicas
         self.auditor = auditor
-        self.cost_accounting = cost_accounting
         # None defers to the batcher's RAFT_TPU_PIPELINE_DEPTH / default;
         # 1 forces the serial dispatch path for every served index
         self.pipeline_depth = pipeline_depth
@@ -343,14 +349,12 @@ class SearchService:
                 metrics=ServingMetrics(name=name),
                 start=self._start,
                 observer=self._make_observer(name),
-                cost_accounting=self.cost_accounting,
                 pipeline_depth=self.pipeline_depth,
                 ragged=self.ragged,
                 admission=admission,
                 degraded=degraded,
                 hedger=hedger,
                 effort=effort,
-                perf_meta=self._make_perf_meta(name),
             )
             self._batchers[name] = batcher
         if old is not None:
@@ -364,7 +368,7 @@ class SearchService:
                 name, effort, index=index,
                 auditor=_AuditorTap(self),
                 slo=self.slo_engine,
-                perf=obs_perf.default_ledger(),
+                metrics=batcher.metrics,
             )
         if warmup:
             batcher.warmup()
@@ -394,22 +398,6 @@ class SearchService:
             return index.search(queries, k)
 
         return search_fn
-
-    def _make_perf_meta(self, name: str):
-        """``(backend, version)`` supplier for the perf ledger's
-        executable key.  Resolved per dispatch, so a hot-swap
-        re-attributes device time to the successor kind/version from its
-        first batch — the ledger's A/B story survives swaps."""
-
-        def perf_meta():
-            try:
-                index, version = self.registry.get_versioned(name)
-            except KeyError:  # removed mid-flight
-                return ("unknown", "0")
-            return (getattr(index, "kind", "unknown") or "unknown",
-                    str(version))
-
-        return perf_meta
 
     def _make_observer(self, name: str):
         """Batcher observer feeding the quality auditor, if any.
@@ -874,12 +862,6 @@ class SearchService:
             obs_cost.refresh_page_gauges(self.registry)
         except Exception:  # page-residency gauges likewise
             pass
-        try:
-            # wasted-time fraction + measured roofline utilization per
-            # executable key — pull-refreshed on the same scrape path
-            obs_perf.default_ledger().refresh_gauges()
-        except Exception:  # perf accounting must never break serving
-            pass
 
     def _incident_context(self) -> Dict[str, object]:
         """Snapshot attached to incident timelines at open/close.
@@ -929,10 +911,6 @@ class SearchService:
         With an SLO engine attached (``slo=`` knob) the report also folds
         in the error-budget check: an exhausted budget is DEGRADED, and
         the detail names the offending objectives under ``slo``.
-
-        The measured perf ledger folds in the same way: an executable key
-        inside its regression-debounce window (a live ``perf_regression``)
-        reports DEGRADED under the report's ``perf`` key.
         """
         self._refresh_capacity_gauges()
         auditor = self.auditor
@@ -996,7 +974,6 @@ class SearchService:
                 self.slo_engine.health()
                 if self.slo_engine is not None else None
             ),
-            perf=obs_perf.default_ledger().health_slice(),
             budget=(
                 page_budget.snapshot() if page_budget is not None else None
             ),
@@ -1026,9 +1003,6 @@ class SearchService:
             "indexes": {n: self.stats(n) for n in self.names()},
             "health": self.healthz(),
             "registry": obs.snapshot(),
-            # measured perf ledger, surfaced at the top level too (it also
-            # rides registry["perf"]): hotspot ranking + regression state
-            "perf": obs_perf.default_ledger().snapshot(),
         }
         if self.slo_engine is not None:
             out["slo"] = self.slo_engine.snapshot()

@@ -391,7 +391,7 @@ class ShardedIndex:
                 # dispatch: tracing/enqueue of the sharded executable (the
                 # device wait lands in the caller's block_until_ready)
                 sp.add_stage("dispatch", dt)
-        # perf-ledger attribution (consumed by the batcher on this same
+        # kernel-path attribution (consumed by the batcher on this same
         # thread): stamped AFTER the dispatch so a first-call trace of the
         # per-shard core cannot overwrite it with its inner leg's stamp.
         # Graph-mode CAGRA serves filtered traffic through its exact

@@ -14,7 +14,7 @@ import sys
 
 import jax
 
-# the chip runs chip_smoke.py, never this suite: tests that need the TPU's
+# the chip runs benchmark/run.py, never this suite: tests that need the TPU's
 # compiler describe a chip (tests/test_tpu_compile.py)
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)

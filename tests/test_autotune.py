@@ -422,6 +422,36 @@ def test_service_wires_autotuner_and_healthz_folds_it():
         svc.stop()
 
 
+def test_service_autotuner_reports_measured_rows_per_device_second():
+    # with no frontier loaded the predicted_qps gauge falls back to the
+    # index's own ServingMetrics: real rows over device-stage seconds
+    from raft_tpu import serve
+
+    rng = np.random.default_rng(4)
+    x = rng.random((200, 8), dtype=np.float32)
+    q = rng.random((12, 8), dtype=np.float32)
+    idx = ivf_flat.build(ivf_flat.IndexParams(n_lists=4), x)
+    mi = serve.MutableIndex(
+        idx, search_params=ivf_flat.SearchParams(n_probes=4))
+    reg = MetricsRegistry()
+    tuner = _tuner(registry=reg, recall_floor=0.9)
+    svc = serve.SearchService(k=3, min_bucket=1, max_batch=4, start=False,
+                              autotune=tuner)
+    try:
+        svc.add_index("t", mi, warmup=True)
+        futs = [svc.submit("t", q[i]) for i in range(len(q))]
+        svc.flush("t")
+        for f in futs:
+            f.result(timeout=60)
+        tuner.step("t", now=1.0)
+        device_s = svc.stats("t")["stage_sum_s"]["device"]
+        assert device_s > 0
+        qps = reg.gauge("raft_tpu_autotune_predicted_qps").collect()
+        assert qps[(("index", "t"),)] == pytest.approx(len(q) / device_s)
+    finally:
+        svc.stop()
+
+
 # ---------------------------------------------------------------------------
 # event taxonomy: the new kind exists, annotates, never triggers
 

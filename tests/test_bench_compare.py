@@ -1,8 +1,7 @@
 """Bench record round-trip + the ``compare`` regression gate: identical
 records pass (exit 0), a synthetic 2x latency regression fails (exit
 nonzero), historical BENCH_r0N.json driver wrappers load, and the CLI
-surfaces (``bench.py compare``, ``python -m raft_tpu.bench compare``)
-agree with the library."""
+(``python -m raft_tpu.bench compare``) agrees with the library."""
 
 import json
 import os
@@ -86,7 +85,7 @@ def test_load_bare_payload(tmp_path):
 
 def test_load_driver_wrapper(tmp_path):
     path = str(tmp_path / "BENCH_r99.json")
-    json.dump({"n": 99, "cmd": "python bench.py", "rc": 0,
+    json.dump({"n": 99, "cmd": "python3 benchmark/run.py", "rc": 0,
                "tail": "...", "parsed": PAYLOAD}, open(path, "w"))
     assert export.load_record(path) == PAYLOAD
 
@@ -216,11 +215,8 @@ def _run_cli(cmd, cwd=REPO):
 
 @pytest.mark.parametrize(
     "entry",
-    [
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare"],
-        [sys.executable, "-m", "raft_tpu.bench", "compare"],
-    ],
-    ids=["bench.py", "raft_tpu.bench"],
+    [[sys.executable, "-m", "raft_tpu.bench", "compare"]],
+    ids=["raft_tpu.bench"],
 )
 def test_cli_exit_codes(entry, tmp_path):
     base = _write(tmp_path, "base.json", PAYLOAD)
@@ -239,694 +235,3 @@ def test_cli_exit_codes(entry, tmp_path):
     missing = _run_cli(entry + ["--baseline", str(tmp_path / "nope.json"),
                                 "--candidate", same])
     assert missing.returncode == 2
-
-
-@pytest.mark.slow
-def test_compare_against_frozen_cpu_baseline_smoke():
-    """CI smoke for the full gate: run the frozen CPU bench leg and diff
-    it against the last driver record — the exact invocation a CI job
-    uses (``bench.py compare --baseline BENCH_r05.json``)."""
-    baseline = os.path.join(REPO, "BENCH_r05.json")
-    cmd = [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-           "--baseline", baseline]
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               RAFT_TPU_BENCH_CPU_DEADLINE_S="300")
-    out = subprocess.run(
-        cmd, cwd=REPO, env=env, capture_output=True, text=True, timeout=600
-    )
-    # pass or honest skip (a platform/metric drift) — never a crash
-    assert out.returncode in (0, 1), out.stdout + out.stderr
-    assert "PASS" in out.stdout or "FAIL" in out.stdout \
-        or "SKIP" in out.stdout, out.stdout
-
-
-@pytest.mark.slow
-def test_serve_pipeline_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the pipelined-dispatch A/B: run ``bench.py serve`` at
-    depths 1,2 and gate it with ``bench.py compare`` against the frozen
-    serve-pipeline record.  The run itself must show the pipeline win
-    (depth=2 QPS strictly above depth=1, recompiles 0 at every depth) and
-    the compare must not trip the recompile or latency thresholds."""
-    candidate = str(tmp_path / "serve_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_PIPELINE_DEPTHS="1,2",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "serve"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    depths = line["depths"]
-    assert line["qps_vs_depth1"] > 1.0, (
-        f"pipeline showed no win: {line['qps_vs_depth1']}"
-    )
-    assert depths["2"]["qps"] > depths["1"]["qps"]
-    assert depths["2"]["p99_ms"] <= 1.2 * depths["1"]["p99_ms"]
-    for d, row in depths.items():
-        assert row["recompiles"] == 0, f"depth {d} recompiled on the hot path"
-    assert depths["2"]["inflight_peak"] <= 2
-
-    baseline = os.path.join(
-        REPO, "benchmarks", "BENCH_serve_pipeline_r06.json"
-    )
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_shard_index_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the index-sharding A/B: run ``bench.py shard`` (single
-    vs query-replicated vs index-sharded over 8 forced host devices) and
-    gate it with ``bench.py compare`` against the frozen record.  The run
-    must show the capacity win (per-device bytes shrinking ~Nx), identical
-    ids across arms at exhaustive probing, and zero hot-path recompiles."""
-    candidate = str(tmp_path / "shard_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "shard"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["devices"] == 8
-    assert line["bytes_shrink_x"] >= line["devices"] / 2, (
-        f"per-device memory only shrank {line['bytes_shrink_x']}x"
-    )
-    assert line["recall"] >= 0.999
-    assert line["recompiles"] == 0, "shard leg recompiled on the hot path"
-    arms = line["arms"]
-    assert arms["sharded"]["per_device_bytes"] < (
-        arms["replicated"]["per_device_bytes"] / 4
-    )
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_shard_r08.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_shard_cagra_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the partitioned-graph CAGRA A/B: run
-    ``bench.py shard_cagra`` (single-host vs graph-sharded vs
-    brute-refine over 8 forced host devices) and gate it with
-    ``bench.py compare`` against the frozen record.  The run must show
-    the sharded walk holding >= 0.95 of the single-host recall at
-    matched itopk, modeled per-shard device work measurably below the
-    brute arm's, and zero hot-path recompiles in every arm."""
-    candidate = str(tmp_path / "shard_cagra_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "shard_cagra"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["devices"] == 8
-    assert line["recall_ratio_vs_single"] >= 0.95, (
-        "graph-sharded walk lost recall vs the single-host walk"
-    )
-    assert line["work_ratio_vs_brute"] >= 1.5, (
-        "graph walk's modeled per-shard work is not sublinear vs brute"
-    )
-    assert line["recompiles"] == 0, "shard_cagra leg recompiled hot"
-    arms = line["arms"]
-    assert arms["brute"]["recall"] >= 0.999  # the exact control arm
-    assert arms["graph"]["modeled_distances_per_query"] < (
-        arms["brute"]["modeled_distances_per_query"]
-    )
-
-    baseline = os.path.join(
-        REPO, "benchmarks", "BENCH_shard_cagra_r20.json"
-    )
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_flight_recorder_overhead_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the flight-recorder A/B: run ``bench.py flight``
-    (recorder on vs ``obs.set_enabled(False)``) and gate it with
-    ``bench.py compare`` against the frozen record.  The run must show the
-    recorder is effectively free on the serve hot path (the tentpole's
-    "always-on" claim): every dispatched batch recorded when on, zero
-    records when off, zero recompiles, and QPS within tolerance of the
-    recorder-off arm."""
-    candidate = str(tmp_path / "flight_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "flight"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "flight leg recompiled on the hot path"
-    on, off = line["recorder_on"], line["recorder_off"]
-    assert on["recorded_batches"] >= on["batches"] > 0
-    assert off["recorded_batches"] == 0
-    # the acceptance bound is 3%; allow CI scheduling noise on top of it
-    assert line["qps_ratio"] >= 0.90, (
-        f"recorder overhead out of tolerance: {line['overhead_pct']}%"
-    )
-
-    baseline = os.path.join(
-        REPO, "benchmarks", "BENCH_flight_recorder_r07.json"
-    )
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_explain_sampling_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the explain tail-sampling A/B: run ``bench.py
-    explain`` (always-on sampling under ``RAFT_TPU_EXPLAIN=1`` vs the
-    default off) and gate it with ``bench.py compare`` against the
-    frozen record.  The run must show sampling is effectively free on
-    the serve hot path: plans archived when on, zero when off, zero
-    post-warmup recompiles on both arms, and QPS within tolerance of
-    the sampling-off arm — the leg asserts the archive/recompile
-    invariants itself before emitting."""
-    candidate = str(tmp_path / "explain_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    env.pop("RAFT_TPU_EXPLAIN", None)  # the leg owns the gate
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "explain"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "explain leg recompiled on the hot path"
-    on, off = line["sampling_on"], line["sampling_off"]
-    assert on["archived_plans"] > 0
-    assert off["archived_plans"] == 0
-    # the acceptance bound is 2%; allow CI scheduling noise on top of it
-    assert line["qps_ratio"] >= 0.90, (
-        f"sampling overhead out of tolerance: {line['overhead_pct']}%"
-    )
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_explain_r19.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_compact_churn_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the online-compaction A/B: run ``bench.py compact``
-    (compactor on vs off under identical churn) and gate it with
-    ``bench.py compare`` against the frozen record.  The run must show
-    bounded side rows and live bytes with the compactor on, monotone
-    side-buffer growth with it off, recall no worse than the off arm,
-    every promoted pass inside its memory budget, and zero post-warmup
-    hot-path recompiles — the leg asserts all of that itself before
-    emitting, so a zero exit plus a PASS compare is the whole story."""
-    candidate = str(tmp_path / "compact_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compact"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "compact leg recompiled on the hot path"
-    assert line["compactions"] >= 3
-    on, off = line["arms"]["on"], line["arms"]["off"]
-    assert on["max_side_rows"] <= 2 * line["trigger_side_rows"]
-    assert off["final_side_rows"] > 4 * line["trigger_side_rows"], (
-        "off arm failed to demonstrate unbounded growth"
-    )
-    assert on["recall"] >= off["recall"]
-    assert on["peak_rebuild_bytes"] <= on["budget_bytes"]
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_compact_r09.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_ragged_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the ragged-batching A/B: run ``bench.py ragged``
-    (one ragged batcher vs the classic per-(k, filter) variant ladder
-    under identical mixed-k/mixed-filter closed-loop traffic) and gate
-    it with ``bench.py compare`` against the frozen record.  The run
-    must clear the acceptance bars: ragged QPS ≥ 1.3x the ladder arm
-    with equal or lower p99, zero post-warmup recompiles on both arms,
-    and the warmup executable-variant count reduced ≥ 4x."""
-    candidate = str(tmp_path / "ragged_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "ragged"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "ragged leg recompiled on the hot path"
-    ladder, ragged = line["arms"]["ladder"], line["arms"]["ragged"]
-    assert line["qps_vs_ladder"] >= 1.3, (
-        f"ragged arm showed no win: {line['qps_vs_ladder']}x"
-    )
-    assert ragged["p99_ms"] <= ladder["p99_ms"], (
-        "ragged arm worsened tail latency"
-    )
-    assert line["warmup_variant_reduction"] >= 4, (
-        f"executable lattice only shrank {line['warmup_variant_reduction']}x"
-    )
-    assert ragged["pad_waste_rows"] < ladder["pad_waste_rows"]
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_ragged_r11.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_overload_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the overload-control A/B: run ``bench.py overload``
-    (admission control + degraded-mode ladder vs the same batcher with
-    neither, both under the same open-loop Poisson stream at 2x measured
-    capacity) and gate it with ``bench.py compare`` against the frozen
-    record.  The leg itself asserts the non-negotiables (priority 0 never
-    shed, zero errors, zero post-warmup recompiles on both arms, every
-    shed on the bus and inside a correlated incident, uncontrolled-arm
-    queue collapse); here we re-check the headline numbers from the
-    emitted line.  Steady-state is the post-onset window (scheduled
-    arrival >= 1.5 s): the 0->2x step has an honest transient while the
-    effort ladder's hysteresis engages, so the full-stream ratio is
-    looser and the p1 shed bound is a small fraction, not zero — a stray
-    container hiccup can brush the top pressure level for one cut."""
-    candidate = str(tmp_path / "overload_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "overload"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "overload leg recompiled on the hot path"
-    on = line["arms"]["controlled"]
-    off = line["arms"]["uncontrolled"]
-    assert "0" not in on["shed_by_priority"], "interactive traffic was shed"
-    assert on["errors"] == 0 and off["errors"] == 0
-    # controlled arm holds the interactive tail and keeps goodput
-    assert line["p0_steady_p99_vs_uncontended"] <= 2.0, (
-        "controller failed to hold steady-state p0 p99: "
-        f"{line['p0_steady_p99_vs_uncontended']}x uncontended"
-    )
-    assert on["goodput_vs_capacity"] >= 0.9, (
-        f"controlled-arm goodput collapsed: {on['goodput_vs_capacity']}"
-    )
-    steady = on["steady_shed_by_priority"]
-    assert "0" not in steady
-    total_steady = sum(steady.values())
-    assert total_steady > 0, "2x overload produced no steady-state shedding"
-    assert steady.get("1", 0) <= 0.05 * total_steady, (
-        f"steady-state shedding was not lowest-priority-first: {steady}"
-    )
-    # uncontrolled arm collapses: unbounded queue, p0 tail gone
-    assert off["queue_rows_at_submit_end"] > 4 * max(
-        1, on["queue_rows_at_submit_end"]
-    )
-    assert line["off_p0_p99_vs_on"] > 4.0
-    # observability: decisions visible on the bus and in an incident
-    assert line["shed_event_on_bus"] and line["degraded_event_on_bus"]
-    assert line["shed_in_incident"]
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_overload_r12.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_slo_engine_overhead_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the SLO-engine A/B: run ``bench.py slo`` (pooled
-    interleaved rounds, background evaluator on a 200 ms tick vs no
-    engine) and gate it with ``bench.py compare`` against the frozen
-    record.  The run must show the evaluator actually ticked, burned no
-    budget on an error-free workload, added no recompiles, and cost <2%
-    QPS on average (the acceptance bar; the assert allows single-core
-    CI scheduling noise on top — each evaluator wake preempts the only
-    serving core there, so one pooled run still swings a few percent)."""
-    candidate = str(tmp_path / "slo_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "slo"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "slo leg recompiled on the hot path"
-    on = line["slo_on"]
-    assert on["evals"] > 0
-    assert on["budget_remaining"] > 0.0
-    assert line["qps_ratio"] >= 0.90, (
-        f"SLO engine overhead out of tolerance: {line['overhead_pct']}%"
-    )
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_slo_r10.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_perf_ledger_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the measured-perf-ledger A/B: run ``bench.py perf``
-    (pacing-dominated ledger-off/on rounds, then live attribution on a
-    real served index, then a forced ~8x device slowdown) and gate it
-    with ``bench.py compare`` against the frozen record.  The run must
-    show zero hot-path recompiles in both overhead arms, the ledger
-    within tolerance of free (the <2% acceptance bar plus single-core CI
-    scheduling noise), the served executable attributed as a hotspot
-    with a measured roofline in (0, 1], and the full regression evidence
-    chain: exactly one debounced ``perf_regression`` that triggered one
-    profiler capture and landed in one correlated incident."""
-    candidate = str(tmp_path / "perf_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "perf"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "perf leg recompiled on the hot path"
-    assert line["qps_ratio"] >= 0.90, (
-        f"perf ledger overhead out of tolerance: {line['overhead_pct']}%"
-    )
-    hot = line["hotspot"]
-    assert hot["index"] == "perf_bench" and hot["backend"] == "brute_force"
-    assert hot["kernel_path"] == "xla"
-    assert 0.0 < line["roofline_utilization"] <= 1.0
-    chain = line["regression_chain"]
-    assert chain["events"] == 1 and chain["capture"] and chain["incident"]
-    assert chain["ratio"] > 1.5 and chain["regressions_on_key"] == 1
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_perf_r13.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_kernels_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the Pallas-kernel A/B: run ``bench.py kernels``
-    (select_k stable-merge and wide-beam CAGRA XLA-vs-Pallas arms in
-    interpret mode, then serving-path PerfLedger attribution) and gate
-    it with ``bench.py compare`` against the frozen record.  The leg
-    self-asserts bitwise select_k parity, CAGRA recall/distance
-    equivalence, zero post-warmup recompiles in every arm, and a
-    ``kernel_path="pallas"`` hotspot with a measured roofline — here we
-    re-check the emitted line's contract: both speedups above 1.0 (the
-    Pallas arms beat their XLA twins on the benched shapes), the
-    per-arm kernel_path stamps, and the serving record stamping
-    ``kernel_path: pallas: true``."""
-    candidate = str(tmp_path / "kernels_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "kernels"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0
-    assert line["kernel_path"] == {"pallas": True}
-    sk = line["select_k"]
-    assert sk["speedup"] > 1.0 and sk["parity"] == "bitwise"
-    assert sk["xla"]["kernel_path"] == "xla"
-    assert sk["pallas"]["kernel_path"] == "pallas"
-    cg = line["cagra_traverse"]
-    assert cg["speedup"] > 1.0
-    assert cg["xla"]["kernel_path"] == "xla"
-    assert cg["pallas"]["kernel_path"] == "pallas"
-    assert abs(cg["xla"]["recall"] - cg["pallas"]["recall"]) <= 0.02
-    srv = line["serving"]
-    assert srv["backend"] == "cagra" and srv["pallas_hotspot_device_s"] > 0
-    assert 0.0 < srv["roofline_utilization"] <= 1.0
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_kernels_r15.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_paged_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the paged-storage A/B: run ``bench.py paged`` (mono
-    vs HBM-resident paged vs over-budget paged over the same ivf_flat
-    build) and gate it with ``bench.py compare`` against the frozen
-    record.  The leg self-asserts identical ids across all three arms
-    and the per-arm recompile bounds; here we re-check the emitted
-    line's contract: the resident arm within the ≤10% acceptance
-    overhead (plus CI scheduling noise), the over-budget arm actually
-    over budget (slots < pages) yet serving, with its demand paging
-    visible in the eviction counters."""
-    candidate = str(tmp_path / "paged_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "paged"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["ids_identical"] is True
-    assert line["recompiles"] <= 4, "paged leg recompiled on the hot path"
-    arms = line["arms"]
-    assert arms["mono"]["recompiles"] == 0
-    assert arms["paged_resident"]["recompiles"] == 0
-    # acceptance bar ≤10%; single-core CI scheduling noise rides on top
-    assert line["resident_overhead_pct"] <= 15.0, (
-        f"HBM-resident paged overhead out of tolerance: "
-        f"{line['resident_overhead_pct']}%"
-    )
-    over = arms["paged_overbudget"]
-    assert over["slots"] < over["pages"], "over-budget arm was not over budget"
-    assert over["qps"] > 0
-    assert over["evictions"] > 0 and over["misses"] > 0, (
-        "over-budget arm never paged — the pool silently fit everything"
-    )
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_paged_r17.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_distributed_build_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the distributed-build A/B: run ``bench.py build``
-    (single-host ivf_flat.build vs build_sharded over 8 forced host
-    devices, f32 vs bf16-quantized training collectives) and gate it
-    with ``bench.py compare`` against the frozen record.  The leg
-    self-asserts a >= 4x modeled 8-device speedup and bf16 build-quality
-    parity; here we also pin recall at exhaustive probing and zero
-    recompiles on the warmed build path."""
-    candidate = str(tmp_path / "build_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "build"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["devices"] == 8
-    assert line["speedup_modeled_x"] >= 4.0, (
-        f"modeled 8-device build speedup {line['speedup_modeled_x']}x < 4x"
-    )
-    assert line["recall"] >= 0.999
-    assert line["recompiles"] == 0, "warmed build path recompiled"
-    arms = line["arms"]
-    # the quantized arm halves the per-iteration psum payload and must
-    # not trade away build quality
-    assert arms["sharded_bf16"]["psum_bytes_per_iter"] == (
-        arms["sharded_f32"]["psum_bytes_per_iter"] // 2
-    )
-    assert arms["sharded_bf16"]["recall"] >= arms["single"]["recall"] - 0.02
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_build_r16.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_autotune_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the closed-loop autotune A/B: run ``bench.py
-    autotune`` (paced ivf_flat serving, SLO burn injected mid-run, one
-    arm with the Autotuner attached and one without) and gate it with
-    ``bench.py compare`` against the frozen record.  The leg
-    self-asserts the control-loop story; here we re-pin the load-bearing
-    facts: the tuner sheds effort and restores p99 within its window,
-    recall never dips below the floor, effort actuation never
-    recompiles, and the slo_burn -> autotune_step chain landed in one
-    incident."""
-    candidate = str(tmp_path / "autotune_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "autotune"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recall"] >= 0.9, "recall dipped below the floor"
-    assert line["recompiles"] == 0, "effort actuation recompiled"
-    assert line["restored_within_ticks"] <= 4, (
-        "p99 not restored within the controller window"
-    )
-    on = line["autotune_on"]
-    assert on["max_level"] > 0, "autotuner never shed effort under burn"
-    assert on["final_level"] == 0, "autotuner never climbed back to full effort"
-    assert on["recompiles"] == 0 and line["autotune_off"]["recompiles"] == 0
-    chain = line["incident_chain"]
-    assert chain["trigger"] == "slo_burn"
-    assert chain["autotune_steps"] >= 1, (
-        "no autotune_step correlated into the burn incident"
-    )
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_autotune_r18.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout
-
-
-@pytest.mark.slow
-def test_gateway_smoke_against_frozen_record(tmp_path):
-    """CI smoke for the gateway scrape-under-load A/B: run ``bench.py
-    gateway`` (a 1 Hz /metrics + /healthz poller against a paced
-    serving stream vs the identical stream unpolled) and gate it with
-    ``bench.py compare`` against the frozen record.  The leg
-    self-asserts scrape liveness and zero recompiles; here we re-pin
-    the load-bearing facts: the poller actually exercised the gateway,
-    every scrape completed (transport-level), neither arm recompiled,
-    and being scraped cost QPS within tolerance of the unpolled arm."""
-    candidate = str(tmp_path / "gateway_candidate.json")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        RAFT_TPU_BENCH_RECORD=candidate,
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "gateway"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["recompiles"] == 0, "gateway scraping recompiled serve"
-    polled, unpolled = line["polled"], line["unpolled"]
-    assert polled["scrapes"] >= 2, "poller never completed a scrape cycle"
-    assert polled["scrape_errors"] == 0, "scrape transport failures"
-    assert sum(polled["scrape_codes"].values()) >= 2 * polled["scrapes"]
-    assert unpolled["scrapes"] == 0 and not unpolled["scrape_codes"]
-    # the acceptance bar is "within noise"; allow CI scheduling slack
-    assert line["qps_ratio"] >= 0.90, (
-        f"scrape overhead out of tolerance: {line['overhead_pct']}%"
-    )
-
-    baseline = os.path.join(REPO, "benchmarks", "BENCH_gateway_r21.json")
-    cmp_out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "compare",
-         "--baseline", baseline, "--candidate", candidate],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert cmp_out.returncode == 0, cmp_out.stdout + cmp_out.stderr
-    assert "PASS" in cmp_out.stdout, cmp_out.stdout

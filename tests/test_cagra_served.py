@@ -186,7 +186,7 @@ def test_service_walk_matches_the_beam_reference(built, metric):
     mi = serve.MutableIndex(index, search_params=params)
     bucket = 64
     svc = serve.SearchService(k=K, min_bucket=bucket, max_batch=bucket,
-                              max_delay_ms=1.0, cost_accounting=False)
+                              max_delay_ms=1.0)
     try:
         svc.add_index("graph", mi, warmup=True)
         b0 = svc.stats("graph")["batches"]
@@ -222,7 +222,7 @@ def test_benchmark_algo_serves_what_cagra_search_returns():
     assert isinstance(mi, serve.MutableIndex) and mi.kind == "cagra"
     assert shapes["graph_degree"] == 16 and shapes["itopk"] == 32
     svc = serve.SearchService(k=K, min_bucket=64, max_batch=64,
-                              max_delay_ms=1.0, cost_accounting=False)
+                              max_delay_ms=1.0)
     try:
         svc.add_index("algo", mi, warmup=True)
         d, i = svc.submit("algo", qs).result(timeout=120)
@@ -245,7 +245,7 @@ def test_ivf_paths_keep_no_search_work():
                             search_params=ivf_flat.SearchParams(n_probes=2))
     kernels.consume_search_work()
     svc = serve.SearchService(k=K, min_bucket=32, max_batch=32,
-                              max_delay_ms=1.0, cost_accounting=False)
+                              max_delay_ms=1.0)
     try:
         svc.add_index("ivf", mi, warmup=True)
         b0 = svc.stats("ivf")["batches"]

@@ -70,7 +70,7 @@ def _svc(index) -> serve.SearchService:
     # cut must happen without an explicit flush
     svc = serve.SearchService(
         k=5, max_batch=16, start=True,
-        ragged=serve.RaggedSpec(k_max=K_MAX), cost_accounting=False,
+        ragged=serve.RaggedSpec(k_max=K_MAX),
     )
     svc.add_index("t", index)
     return svc
@@ -242,7 +242,7 @@ def test_explain_sharded_contributions(corpus):
     sharded = serve.ShardedIndex.from_index(brute_force.build(x))
     svc = serve.SearchService(
         k=5, max_batch=16, start=True,
-        ragged=serve.RaggedSpec(k_max=K_MAX), cost_accounting=False,
+        ragged=serve.RaggedSpec(k_max=K_MAX),
     )
     svc.add_index("t", sharded)
     try:

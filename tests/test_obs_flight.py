@@ -50,7 +50,7 @@ def _toy_search_fn(k=3):
 def _run_batcher(pipeline_depth, n_requests=6, **kw):
     mb = MicroBatcher(
         _toy_search_fn(), dim=D, max_batch=8, start=False,
-        pipeline_depth=pipeline_depth, cost_accounting=False, **kw
+        pipeline_depth=pipeline_depth, **kw
     )
     mb.warmup()
     futs = [
@@ -77,7 +77,7 @@ class TestRequestIds:
     def test_squeezed_and_batched_futures_share_id_semantics(self):
         mb = MicroBatcher(
             _toy_search_fn(), dim=D, max_batch=8, start=False,
-            pipeline_depth=1, cost_accounting=False,
+            pipeline_depth=1,
         )
         f1 = mb.submit(np.zeros(D, dtype=np.float32))        # 1-D: squeezed
         f2 = mb.submit(np.zeros((2, D), dtype=np.float32))   # 2-D: as-is
@@ -202,7 +202,7 @@ class TestIncidentTriggers:
 
         mb = MicroBatcher(
             bad_fn, dim=D, max_batch=8, start=False,
-            pipeline_depth=depth, cost_accounting=False,
+            pipeline_depth=depth,
         )
         fut = mb.submit(np.zeros(D, dtype=np.float32))
         mb.flush()
@@ -230,7 +230,7 @@ class TestIncidentTriggers:
         )
         mb = MicroBatcher(
             _toy_search_fn(), dim=D, max_batch=8, start=False,
-            pipeline_depth=1, cost_accounting=False,
+            pipeline_depth=1,
         )
         mb._warm = True  # pretend warmup ran; next compile is "hot"
         fut = mb.submit(np.zeros(D, dtype=np.float32))

@@ -86,9 +86,6 @@ def test_endpoint_matrix(service, dataset):
     assert set(snap["indexes"]) == {"wiki", "code"}
     assert "registry" in snap and "health" in snap
 
-    status, hot = _jget(url, "/perf/hotspots?n=3")
-    assert status == 200 and isinstance(hot["hotspots"], list)
-
     status, incidents = _jget(url, "/incidents")
     assert status == 200 and "open" in json.dumps(incidents)
 
@@ -155,7 +152,6 @@ def test_error_paths_and_request_counter(service):
     assert _jget(url, "/explain?name=wiki")[0] == 400
     assert _jget(url, "/explain?name=ghost&q=1,2")[0] == 404
     assert _jget(url, "/explain?name=wiki&q=a,b")[0] == 400
-    assert _jget(url, "/perf/hotspots?n=zap")[0] == 400
 
     # the gateway's own traffic is in its own scrape, by matched route —
     # the raw (unbounded) path never becomes a label value

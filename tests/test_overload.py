@@ -384,7 +384,7 @@ class TestHedgedDispatcher:
 
 def _overload_service(mi, *, cfg=None, start=False, max_batch=8, **kw):
     svc = serve.SearchService(
-        k=4, max_batch=max_batch, start=start, cost_accounting=False,
+        k=4, max_batch=max_batch, start=start,
         overload=cfg if cfg is not None else OverloadConfig(), **kw)
     svc.add_index("t", mi)
     return svc
@@ -413,7 +413,7 @@ class TestServiceOverload:
         # whether an admission controller is installed
         x, q = corpus
         svc = serve.SearchService(k=4, max_batch=8, start=False,
-                                  cost_accounting=False, overload=False)
+                                  overload=False)
         try:
             svc.add_index("t", serve.MutableIndex(brute_force.build(x)))
             fut = svc.submit("t", q[0], deadline_s=1e-9)
@@ -542,15 +542,12 @@ class TestServiceOverload:
 
 def test_overload_event_taxonomy():
     for kind in ("admission_shed", "degraded_enter", "degraded_exit",
-                 "hedge_fired", "perf_regression"):
+                 "hedge_fired"):
         assert kind in events.KINDS
     # shed + degrade decisions open incidents; exits/hedges annotate
     assert "admission_shed" in events.TRIGGER_KINDS
     assert "degraded_enter" in events.TRIGGER_KINDS
     assert "degraded_exit" not in events.TRIGGER_KINDS
     assert "hedge_fired" not in events.TRIGGER_KINDS
-    # a measured device-time regression opens an incident (and triggers
-    # the debounced profiler capture on the way)
-    assert "perf_regression" in events.TRIGGER_KINDS
     with pytest.raises(ValueError):
         events.publish("admission_shedd")  # typos fail loudly, not vanish

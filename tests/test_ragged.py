@@ -58,7 +58,7 @@ def _masks(n: int):
 def _ragged_service(mi, *, depth: int) -> serve.SearchService:
     svc = serve.SearchService(
         k=5, max_batch=16, start=False, pipeline_depth=depth,
-        ragged=serve.RaggedSpec(k_max=K_MAX), cost_accounting=False,
+        ragged=serve.RaggedSpec(k_max=K_MAX),
     )
     svc.add_index("t", mi)
     return svc
@@ -328,13 +328,44 @@ def test_ragged_argument_validation(corpus):
         svc.stop()
 
     # classic services must reject the ragged-only kwargs loudly
-    svc = serve.SearchService(k=3, max_batch=8, start=False,
-                              cost_accounting=False)
+    svc = serve.SearchService(k=3, max_batch=8, start=False)
     try:
         svc.add_index("c", _build("brute_force", x))
         with pytest.raises(ValueError):
             svc.submit("c", q[0], k=2)
         with pytest.raises(RuntimeError):
             svc.register_filter("c", _masks(len(x))[0])
+    finally:
+        svc.stop()
+
+
+def test_ledger_reconciles_pipelined_ragged_traffic():
+    # pipelined (depth 2) ragged traffic with per-request k: zero
+    # post-warmup recompiles, and the stamped kernel path flows from the
+    # neighbors routing code into stats() and the Prometheus export
+    rng = np.random.default_rng(0)
+    data = rng.standard_normal((256, 16), dtype=np.float32)
+    q = rng.standard_normal((40, 16), dtype=np.float32)
+    svc = serve.SearchService(k=4, max_batch=8, pipeline_depth=2,
+                              ragged=serve.RaggedSpec(k_max=8, filters=False))
+    svc.add_index("t", brute_force.build(data), warmup=True)
+    try:
+        c0 = compile_count()
+        futs = [
+            svc.submit("t", q[i : i + 2], k=int(1 + i % 5))
+            for i in range(0, 40, 2)
+        ]
+        svc.flush("t")
+        for f in futs:
+            f.result(timeout=60)
+        assert compile_count() - c0 == 0
+        st = svc.stats("t")
+        assert st["recompiles"] == 0
+        assert st["requests"] == 20 and st["batches"] > 0
+        # every dispatch is attributed: brute force has no pallas leg
+        assert st["kernel_paths"] == {"xla": st["batches"]}
+        # one device-stage value per dispatched batch
+        assert st["stage_n"]["device"] == st["batches"]
+        assert 'kernel_path="xla"' in svc.prometheus()
     finally:
         svc.stop()

@@ -84,6 +84,45 @@ def test_batcher_zero_recompiles_after_warmup(corpus):
         svc.stop()
 
 
+def test_default_warmup_runs_no_cost_analysis(corpus, monkeypatch):
+    # warmup compiles the bucket ladder and nothing else: no AOT cost
+    # analysis per bucket, so no raft_tpu_xla_* cost gauges for the index
+    from raft_tpu.obs import cost as obs_cost
+
+    x, _q = corpus
+    calls = []
+    real = obs_cost.analyze_callable
+
+    def counting(*args, **kwargs):
+        calls.append(args)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(obs_cost, "analyze_callable", counting)
+    svc = serve.SearchService(k=5, min_bucket=1, max_batch=8)
+    try:
+        svc.add_index("nocost", _build("brute_force", x), warmup=True)
+        assert calls == [], "warmup ran XLA cost analysis"
+        cost_lines = [
+            ln for ln in svc.prometheus().splitlines()
+            if 'index="nocost"' in ln and ln.split("{")[0] in {
+                name for name, _attr, _help in obs_cost._GAUGES
+            }
+        ]
+        assert cost_lines == [], cost_lines
+    finally:
+        svc.stop()
+
+
+def test_cost_accounting_true_raises():
+    # the keyword remains for callers that pass False; asking for the
+    # removed warmup cost analysis fails loudly instead of being ignored
+    serve.SearchService(k=3, max_batch=4, start=False,
+                        cost_accounting=False).stop()
+    with pytest.raises(ValueError, match="cost_accounting"):
+        serve.SearchService(k=3, max_batch=4, start=False,
+                            cost_accounting=True)
+
+
 def test_mutable_index_exact_refine(corpus, tmp_path):
     """refine_dataset re-ranks k·REFINE_RATIO PQ candidates exactly: served
     ids are the direct search + refine ids, a misshapen dataset is refused,

@@ -313,3 +313,66 @@ def test_pipelined_batches_report_spans_and_stage_metrics():
     ]
     assert recorded, "pipelined dispatch recorded no serve.batch spans"
     assert any("inflight_wait" in sp.get("stages_ms", {}) for sp in recorded)
+
+
+# ---------------------------------------------------------------------------
+# hedge device-interval dedupe: device_busy_s under hedging
+
+
+class _MirrorHedger:
+    """Stands in for HedgedDispatcher: runs the search once but reports
+    the two mirrored members' (almost fully overlapping) device windows
+    through the batcher's interval sink — the double-count scenario."""
+
+    def __init__(self, fn, window_s=0.03):
+        self.metrics = None
+        self.on_interval = None
+        self._fn = fn
+        self.window_s = window_s
+        self.windows = []
+
+    def warm(self, *args):
+        self._fn(*args)
+
+    def dispatch(self, *args):
+        t0 = time.perf_counter()
+        out = self._fn(*args)
+        time.sleep(self.window_s)
+        t1 = time.perf_counter()
+        sink = self.on_interval
+        if sink is not None:
+            # mirrored pair: same device window, reported twice
+            sink(t0, t1)
+            sink(t0 + self.window_s / 10.0, t1)
+        self.windows.append((t0, t1))
+        return out
+
+
+def test_hedged_device_busy_stays_union_not_sum():
+    rng = np.random.default_rng(7)
+    data = rng.standard_normal((64, DIM), dtype=np.float32)
+
+    def fn(q):
+        return brute_force.knn(data, q, 4)
+
+    hedger = _MirrorHedger(fn)
+    mb = MicroBatcher(fn, DIM, max_batch=4, start=False, pipeline_depth=2,
+                      hedger=hedger)
+    # the batcher wired its union sink into the hedger at construction
+    assert hedger.on_interval is not None
+    mb.warmup()
+    futs = [
+        mb.submit(rng.standard_normal(DIM, dtype=np.float32), priority=0)
+        for _ in range(3)
+    ]
+    mb.flush()
+    for f in futs:
+        f.result(timeout=60)
+    mb.stop()
+    assert hedger.windows, "hedged dispatch never ran"
+    union = sum(t1 - t0 for t0, t1 in hedger.windows)
+    busy = mb.device_busy_s()
+    # the overlapping mirrored windows must merge: busy ≈ one window per
+    # dispatch, bounded well below the double-counted sum (2x union)
+    assert busy == pytest.approx(union, rel=0.35)
+    assert busy < 1.5 * union

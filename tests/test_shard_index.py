@@ -267,3 +267,19 @@ def test_replicated_search_skips_device_put_when_pre_sharded(corpus):
         jax.device_put = real_put
     np.testing.assert_array_equal(np.asarray(i), np.asarray(iref))
     np.testing.assert_array_equal(np.asarray(i2), np.asarray(iref))
+
+
+def test_shard_skew_probe_publishes_gauges():
+    if len(jax.devices()) < 2:
+        pytest.skip("needs the multi-device CPU mesh")
+    rng = np.random.default_rng(5)
+    x = rng.standard_normal((512, 16), dtype=np.float32)
+    q = rng.standard_normal((8, 16), dtype=np.float32)
+    sh = ShardedIndex.from_index(brute_force.build(x))
+    out = sh.measure_shard_skew(q, k=4)
+    assert len(out["per_shard_s"]) == sh.n_shards
+    assert all(t > 0.0 for t in out["per_shard_s"])
+    assert out["skew"] >= 1.0
+    prom = obs.to_prometheus()
+    assert "raft_tpu_shard_device_seconds" in prom
+    assert "raft_tpu_shard_device_skew" in prom

@@ -51,11 +51,11 @@ def test_entry_point_discovery_is_not_vacuous(project):
 def test_serve_surface_discovery_is_not_vacuous(result):
     # all online entry points (service/mutation/ragged/compactor plus
     # the SLO evaluator, incident ingest, the overload trio, the
-    # perf-ledger pair, the sharded rebuild, the two module-level build
+    # sharded rebuild, the two module-level build
     # entry points, the page-store pager trio, the deep-explain entry
     # point, the query-archive record/dump pair, and the gateway's
     # request dispatch) checked, against exactly one MicroBatcher
-    assert result.stats["traced_serve_entries_checked"] == 29, result.stats
+    assert result.stats["traced_serve_entries_checked"] == 27, result.stats
     assert result.stats["traced_batcher_classes"] == 1, result.stats
     assert result.stats["traced_labels"] >= 23, result.stats
 
