@@ -8,7 +8,10 @@ bucket the list's decoded rows are DMA'd into VMEM via a *dynamic block
 index* (scalar-prefetched ``bucket_list`` drives the BlockSpec index_map —
 the Pallas answer to data-dependent gathers, SURVEY §7 hard part 2), the
 [G, cap] score tile is computed on the MXU, and the per-query top-k is
-extracted in VMEM (toolkit.fold_topk) — scores never reach HBM.
+extracted in VMEM (toolkit.select_topk) — scores never reach HBM.  A grid
+step takes a stack of buckets (:func:`stack_height`) and extracts once
+over their stacked tiles: the extraction's rounds are bound by latency,
+not by rows.
 
 Role parity: the reference's per-list ``compute_similarity`` scan kernel
 (cpp/include/raft/neighbors/detail/ivf_pq_compute_similarity-inl.cuh) with
@@ -153,24 +156,36 @@ def _score_against_list(dec, qg, q2, y2_row, ids_row, filt_row, scale,
     return scores, cand_i
 
 
-def _scan_kernel(bucket_list_ref, dec_ref, y2_ref, ids_ref, filt_ref, qg_ref,
-                 q2_ref, scale_ref, vals_ref, out_ids_ref, *, kk: int,
-                 metric: str, filtered: bool, scan_dtype: str):
-    """One bucket: score its list's rows against its G queries, keep the
-    per-query top-kk.  dec/y2/ids/filt blocks were selected by the
-    prefetched bucket_list (dynamic index_map); qg/q2 are the bucket's
-    pre-gathered rotated queries (+inf q2 marks padding slots).  An int8
-    dec block takes the quantized-query path: per-query symmetric
+def _scan_kernel(bucket_list_ref, *refs, kk: int, metric: str,
+                 filtered: bool, scan_dtype: str, stack: int):
+    """One step of ``stack`` (S) consecutive buckets: score each bucket's
+    list rows against its G queries, then keep every query's top-kk with
+    ONE extraction over the S score tiles stacked into [S·G, cap].  The
+    extraction's kk rounds are bound by latency, not by rows, so a step
+    pays them once for S·G queries instead of once per bucket.
+
+    ``refs`` holds S dec, S y2, S ids and S filt blocks (bucket j's blocks
+    were selected by the prefetched ``bucket_list[S·b + j]``, a dynamic
+    index_map each), then qg/q2 (the step's pre-gathered rotated queries,
+    +inf q2 marks padding slots), scale (SMEM) and the two outputs.  An
+    int8 dec block takes the quantized-query path: per-query symmetric
     quantization in VMEM, int8×int8 MXU dot with int32 accumulation,
-    rescale by the per-query scale × the cache's frozen scan_scale
-    (scale_ref, SMEM) — the memory-lean DEEP-100M mode's scoring, fused.
-    ``metric`` picks the score: L2 (y² − 2ip + q²) or inner product
-    (−ip); ``filtered`` expands the list's packed filter words to a lane
-    mask and demotes failing slots.  A padding bucket (list id < 0: the
-    grid runs the static bucket bound, and a batch fills only part of it)
-    writes empty partials without scoring or folding."""
+    rescale by the per-query scale × the cache's frozen scan_scale — the
+    memory-lean DEEP-100M mode's scoring, fused.  ``metric`` picks the
+    score: L2 (y² − 2ip + q²) or inner product (−ip); ``filtered`` expands
+    the list's packed filter words to a lane mask and demotes failing
+    slots.  A step of padding buckets only (list id < 0: the grid runs the
+    static bucket bound, and a batch fills only part of it) writes empty
+    partials without scoring or folding; a padding bucket in a live step
+    scores to +inf on its q2 and comes out empty too."""
+    S = stack
+    dec, y2, ids, filt = (refs[j * S:(j + 1) * S] for j in range(4))
+    qg_ref, q2_ref, scale_ref, vals_ref, out_ids_ref = refs[4 * S:]
     G = qg_ref.shape[1]
-    live = bucket_list_ref[pl.program_id(0)] >= 0
+    first = S * pl.program_id(0)
+    live = bucket_list_ref[first] >= 0
+    for j in range(1, S):
+        live = live | (bucket_list_ref[first + j] >= 0)
 
     @pl.when(live)
     def _scan():
@@ -178,19 +193,78 @@ def _scan_kernel(bucket_list_ref, dec_ref, y2_ref, ids_ref, filt_ref, qg_ref,
         # [G, 1] column block and y2/ids as [1, cap] rows, so the masks
         # build from plain 2-D broadcasts (1-D reshapes/transposes crash
         # tpu_compile)
-        scores, cand_i = _score_against_list(
-            dec_ref[0], qg_ref[0], q2_ref[0], y2_ref[0], ids_ref[0],
-            filt_ref[0], scale_ref[0, 0],
-            metric=metric, filtered=filtered, scan_dtype=scan_dtype,
-        )
-        v, i = select_topk(scores, cand_i, kk)
-        vals_ref[0] = v
-        out_ids_ref[0] = jnp.where(jnp.isfinite(v), i, -1)
+        tiles = [
+            _score_against_list(
+                dec[j][0], qg_ref[j], q2_ref[j], y2[j][0], ids[j][0],
+                filt[j][0], scale_ref[0, 0],
+                metric=metric, filtered=filtered, scan_dtype=scan_dtype,
+            )
+            for j in range(S)
+        ]
+        scores = jnp.concatenate([t[0] for t in tiles], axis=0)
+        cand_i = jnp.concatenate([t[1] for t in tiles], axis=0)
+        v, i = select_topk(scores, cand_i, kk)            # [S·G, kk]
+        i = jnp.where(jnp.isfinite(v), i, -1)
+        for j in range(S):
+            vals_ref[j] = v[j * G:(j + 1) * G]
+            out_ids_ref[j] = i[j * G:(j + 1) * G]
 
     @pl.when(jnp.logical_not(live))
     def _empty():
-        vals_ref[0] = jnp.full((G, kk), _WORST, jnp.float32)
-        out_ids_ref[0] = jnp.full((G, kk), -1, jnp.int32)
+        vals_ref[...] = jnp.full((S, G, kk), _WORST, jnp.float32)
+        out_ids_ref[...] = jnp.full((S, G, kk), -1, jnp.int32)
+
+
+#: query rows one probe-major step folds together: the extraction's
+#: rounds cost about the same from 8 to 128 rows, and 128 is the height
+#: the CAGRA hop's block settled on (kernels/cagra_traverse.py)
+_STACK_ROWS = 128
+
+#: VMEM a probe-major step may hold: the default scoped limit of a v5e
+_STACK_VMEM = 16 * 1024 * 1024
+
+
+def _vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a [rows, cols] VMEM buffer padded to the native tiling
+    (8 sublanes of 32 bits, so 16 rows of bf16 and 32 of int8; 128 lanes)."""
+    sub = 8 * (4 // itemsize)
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * itemsize
+
+
+def _stack_vmem_bytes(S: int, G: int, cap: int, rot: int, itemsize: int,
+                      kk: int, cap_w: int) -> int:
+    """VMEM of a step of S buckets: the double-buffered list blocks (dec,
+    y2, ids, filt) of each bucket, the double-buffered query and output
+    blocks, and the [S·G, cap] value, id and position temporaries of the
+    extraction."""
+    per_list = (
+        _vmem_tile_bytes(cap, rot, itemsize)
+        + 2 * _vmem_tile_bytes(1, cap, 4)
+        + _vmem_tile_bytes(1, cap_w, 4)
+    )
+    rows = S * G
+    io = (
+        _vmem_tile_bytes(rows, rot, 4) + _vmem_tile_bytes(rows, 1, 4)
+        + 2 * _vmem_tile_bytes(rows, kk, 4)
+    )
+    return 2 * (S * per_list + io) + 3 * _vmem_tile_bytes(rows, cap, 4)
+
+
+def stack_height(n_buckets: int, G: int, cap: int, rot: int, itemsize: int,
+                 kk: int, cap_w: int = 1) -> int:
+    """Buckets the probe-major scan folds per grid step, read from the
+    shapes: the largest power of two S with S·G ≤ 128 rows, S ≤ the
+    bucket count (so a small call stacks no padding), and the step's
+    VMEM (:func:`_stack_vmem_bytes`) inside the default scoped limit."""
+    S = 1
+    while (
+        2 * S * G <= _STACK_ROWS
+        and 2 * S <= n_buckets
+        and _stack_vmem_bytes(2 * S, G, cap, rot, itemsize, kk, cap_w)
+        <= _STACK_VMEM
+    ):
+        S *= 2
+    return S
 
 
 def _scan_paged_kernel(bucket_list_ref, page_slot_ref, dec_ref, y2_ref,
@@ -344,6 +418,21 @@ def ivf_scan_probe_major(
             list_index, kk, metric=metric, scan_dtype=scan_dtype,
             scan_scale=scan_scale, interpret=interpret,
         )
+    return _ivf_scan_probe_major_dense(
+        bucket_list, q_gathered, q2_gathered, list_data, list_y2,
+        list_index, kk, metric=metric, scan_dtype=scan_dtype,
+        list_filter=list_filter, scan_scale=scan_scale, interpret=interpret,
+    )
+
+
+def _ivf_scan_probe_major_dense(
+    bucket_list, q_gathered, q2_gathered, list_data, list_y2, list_index,
+    kk, *, metric, scan_dtype, list_filter, scan_scale, interpret,
+    stack=None,
+):
+    """Dense body of :func:`ivf_scan_probe_major` (same contract).
+    ``stack`` overrides :func:`stack_height` (tests and the on-chip
+    microbench compare heights; the served path reads it from shapes)."""
     B, G, rot = q_gathered.shape
     L, cap, _ = list_data.shape
     # a bucket whose first slot holds no query is padding: mark it -1 so
@@ -355,62 +444,76 @@ def ivf_scan_probe_major(
         # single-word dummy rides the same BlockSpec; the kernel skips it
         list_filter = jnp.zeros((L, 1), jnp.uint32)
     cap_w = list_filter.shape[1]
+    S = stack or stack_height(
+        B, G, cap, rot, list_data.dtype.itemsize, kk, cap_w
+    )
+    # pad to whole steps with padding buckets (list -1, q2 = +inf)
+    B_pad = -(-B // S) * S
+    if B_pad > B:
+        pad = B_pad - B
+        bucket_list = jnp.pad(bucket_list, (0, pad), constant_values=-1)
+        q_gathered = jnp.pad(q_gathered, ((0, pad), (0, 0), (0, 0)))
+        q2_gathered = jnp.pad(
+            q2_gathered, ((0, pad), (0, 0)), constant_values=_WORST
+        )
 
     # 2-D operands indexed by the dynamic list id carry a singleton middle
     # axis: Mosaic requires each block's last two dims to be (8, 128)-
     # divisible OR equal to the array dims, and a (1, cap) block over an
     # [L, cap] array satisfies neither when L is dynamic-selected.  As
     # [L, 1, cap] the block (1, 1, cap) matches the trailing (1, cap)
-    # exactly (first real Mosaic compile, round 4).
+    # exactly (first real Mosaic compile, round 4).  Each of a step's S
+    # buckets has its own block of every list operand, so the pipeline
+    # has all S lists' copies in flight.
+    def list_spec(block, j):
+        return pl.BlockSpec(
+            block, lambda b, bl: (jnp.maximum(bl[S * b + j], 0), 0, 0)
+        )
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B,),
+        grid=(B_pad // S,),
         in_specs=[
-            pl.BlockSpec(       # dec: the bucket's list rows (dynamic)
-                (1, cap, rot), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)
-            ),
-            pl.BlockSpec(                                             # y2
-                (1, 1, cap), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)),
-            pl.BlockSpec(                                             # ids
-                (1, 1, cap), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)),
-            pl.BlockSpec(                                             # filt
-                (1, 1, cap_w), lambda b, bl: (jnp.maximum(bl[b], 0), 0, 0)),
-            pl.BlockSpec((1, G, rot), lambda b, bl: (b, 0, 0)),  # queries
-            pl.BlockSpec((1, G, 1), lambda b, bl: (b, 0, 0)),    # q2 column
+            *(list_spec((1, cap, rot), j) for j in range(S)),      # dec
+            *(list_spec((1, 1, cap), j) for j in range(S)),        # y2
+            *(list_spec((1, 1, cap), j) for j in range(S)),        # ids
+            *(list_spec((1, 1, cap_w), j) for j in range(S)),      # filt
+            pl.BlockSpec((S, G, rot), lambda b, bl: (b, 0, 0)),  # queries
+            pl.BlockSpec((S, G, 1), lambda b, bl: (b, 0, 0)),    # q2 column
             pl.BlockSpec(memory_space=pltpu.SMEM),               # scan_scale
         ],
         out_specs=[
-            pl.BlockSpec((1, G, kk), lambda b, bl: (b, 0, 0)),
-            pl.BlockSpec((1, G, kk), lambda b, bl: (b, 0, 0)),
+            pl.BlockSpec((S, G, kk), lambda b, bl: (b, 0, 0)),
+            pl.BlockSpec((S, G, kk), lambda b, bl: (b, 0, 0)),
         ],
     )
     c = ops_cost.ivf_scan_cost(
         B, G, cap, rot, kk, itemsize=list_data.dtype.itemsize
     )
-    ops_cost.note("ivf_scan_probe_major", c)
+    ops_cost.note(f"ivf_scan_probe_major[stack={S}]", c)
     vals, ids = pl.pallas_call(
         functools.partial(
             _scan_kernel, kk=kk, metric=metric, filtered=filtered,
-            scan_dtype=scan_dtype,
+            scan_dtype=scan_dtype, stack=S,
         ),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, G, kk), jnp.float32),
-            jax.ShapeDtypeStruct((B, G, kk), jnp.int32),
+            jax.ShapeDtypeStruct((B_pad, G, kk), jnp.float32),
+            jax.ShapeDtypeStruct((B_pad, G, kk), jnp.int32),
         ],
         cost_estimate=c.as_pallas(),
         interpret=interpret,
     )(
         bucket_list,
-        list_data,
-        list_y2[:, None, :],
-        list_index[:, None, :],
-        list_filter[:, None, :],
+        *[list_data] * S,
+        *[list_y2[:, None, :]] * S,
+        *[list_index[:, None, :]] * S,
+        *[list_filter[:, None, :]] * S,
         q_gathered,
         q2_gathered[:, :, None],
         jnp.asarray(scan_scale, jnp.float32).reshape(1, 1),
     )
-    return vals, ids
+    return vals[:B], ids[:B]
 
 
 def _scan_qm_kernel(probes_ref, dec_ref, y2_ref, ids_ref, filt_ref, q_ref,

@@ -197,6 +197,77 @@ class TestIvfScanKernel:
                 v[b][live], np.take_along_axis(d, want, 1), rtol=1e-4,
                 atol=1e-3)
 
+    @pytest.mark.parametrize("case", [
+        dict(metric="sqeuclidean", dtype="float32", B=12, live=12),
+        dict(metric="inner_product", dtype="bfloat16", B=12, live=9),
+        dict(metric="cosine", dtype="float32", B=12, live=10),
+        dict(metric="sqeuclidean", dtype="float32", B=12, live=11,
+             filtered=True),
+        dict(metric="inner_product", dtype="int8", B=12, live=12),
+        dict(metric="sqeuclidean", dtype="bfloat16", B=16, live=12,
+             scan_dtype="bfloat16"),
+    ], ids=["l2", "ip-bf16", "cosine", "filtered", "int8",
+            "last-step-half-padding"])
+    def test_stacked_buckets_equal_one_bucket_per_step(self, case):
+        """A step folds a stack of S buckets' score tiles with one
+        extraction; each row's top-kk is its own, so the partials equal
+        the one-bucket-per-step kernel's bit for bit: across part-filled
+        buckets, padding buckets, a bucket count that is not a multiple of
+        S and a last live step that is half padding."""
+        from raft_tpu.kernels.ivf_scan import (
+            _ivf_scan_probe_major_dense, ivf_scan_probe_major,
+            pack_list_filter, stack_height,
+        )
+        from raft_tpu.ops import cost as ops_cost
+
+        rng = np.random.default_rng(len(case["metric"]) + case["B"])
+        L, cap, rot, G, kk = 5, 256, 128, 16, 7
+        B, live, dtype = case["B"], case["live"], case["dtype"]
+        if dtype == "int8":
+            data = rng.integers(-127, 128, (L, cap, rot)).astype(np.int8)
+        else:
+            data = rng.standard_normal((L, cap, rot)).astype(np.float32)
+        data = jnp.asarray(data, dtype)
+        y2 = jnp.sum(data.astype(jnp.float32) ** 2, axis=-1)
+        ids = np.arange(L * cap, dtype=np.int32).reshape(L, cap)
+        ids[:, 200:] = -1                  # part-filled lists
+        ids[3, 17] = -1
+        qg = rng.standard_normal((B, G, rot)).astype(np.float32)
+        q2 = (qg.astype(np.float64) ** 2).sum(-1)
+        q2[live:] = np.inf                 # padding buckets
+        q2[1, 5:] = np.inf                 # part-filled live buckets
+        q2[live - 1, 9:] = np.inf
+        bl = np.where(np.arange(B) < live, rng.integers(0, L, B), 0)
+        lf = None
+        if case.get("filtered"):
+            words = rng.integers(0, 2**32, -(-L * cap // 32), dtype=np.uint32)
+            lf = pack_list_filter(jnp.asarray(ids), jnp.asarray(words))
+        args = (
+            jnp.asarray(bl, jnp.int32), jnp.asarray(qg),
+            jnp.asarray(q2, jnp.float32), data, y2, jnp.asarray(ids), kk,
+        )
+        kw = dict(metric=case["metric"],
+                  scan_dtype=case.get("scan_dtype", "float32"),
+                  list_filter=lf, scan_scale=0.01, interpret=True)
+        S = stack_height(B, G, cap, rot, data.dtype.itemsize, kk,
+                         1 if lf is None else lf.shape[1])
+        assert S == 8                  # stacked; steps padded to whole
+        with ops_cost.capture() as notes:   # the cost note names the height
+            jax.eval_shape(
+                lambda *a: _ivf_scan_probe_major_dense(*a, kk, **kw),
+                *args[:6])
+        assert [name for name, _ in notes] == [
+            "ivf_scan_probe_major[stack=8]"]
+        v, i = ivf_scan_probe_major(*args, **kw)
+        v1, i1 = _ivf_scan_probe_major_dense(*args, **kw, stack=1)
+        v, i, v1, i1 = map(np.asarray, (v, i, v1, i1))
+        assert v.shape == (B, G, kk) and i.shape == (B, G, kk)
+        np.testing.assert_array_equal(v.view(np.int32), v1.view(np.int32))
+        np.testing.assert_array_equal(i, i1)
+        assert np.isinf(v[live:]).all() and (i[live:] == -1).all()
+        assert np.isinf(v[1, 5:]).all() and (i[1, 5:] == -1).all()
+        assert (i[:live][np.isfinite(q2[:live])] >= 0).any()
+
     def test_matches_xla_probe_major(self, monkeypatch):
         from raft_tpu.neighbors import ivf_pq
 

@@ -105,6 +105,53 @@ def test_ivf_scan_probe_major_compiles(one_chip):
     )
 
 
+@pytest.mark.parametrize("cell,dtype,n_lists,cap,n_probes,kk,metric,stack", [
+    ("deep1m-ivfpq", jnp.bfloat16, 2118, 1000, 32, 40, "inner_product", 8),
+    ("sift1m-ivfflat", jnp.float32, 1129, 1960, 5, 10, "sqeuclidean", 4),
+])
+def test_ivf_scan_probe_major_stacks_buckets(
+    one_chip, cell, dtype, n_lists, cap, n_probes, kk, metric, stack
+):
+    """The bulk cells' probe-major scan (1,024 queries, buckets of 16,
+    the lists 128 lanes wide) folds ``stack`` buckets per grid step: the
+    height the shape rule reads from the shapes, and one that compiles
+    for the chip under the kernel's own name, which the trace reads.
+    SIFT's 1,960-row f32 lists stop at 4: eight of them double-buffered
+    overflow the default scoped VMEM, as the compiler confirms."""
+    from raft_tpu.kernels.ivf_scan import (
+        _ivf_scan_probe_major_dense, stack_height,
+    )
+
+    G, rot = 16, 128
+    B = 1024 * n_probes // G + n_lists
+    assert stack_height(B, G, cap, rot, jnp.dtype(dtype).itemsize, kk) == stack
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B,), jnp.int32),
+        ((B, G, rot), jnp.float32),
+        ((B, G), jnp.float32),
+        ((n_lists, cap, rot), dtype),
+        ((n_lists, cap), jnp.float32),
+        ((n_lists, cap), jnp.int32),
+    )]
+    text = jax.jit(
+        lambda *a: ivf_scan_probe_major(
+            *a, kk, metric=metric, scan_dtype="float32")
+    ).lower(*args).compile().as_text()
+    b_pad = -(-B // stack) * stack
+    assert re.search(
+        rf"%ivf_scan_probe_major\.\d+ = \(f32\[{b_pad},{G},{kk}\]", text
+    ), "the scan's custom call lost its name or its stack height"
+    if cell == "sift1m-ivfflat":
+        def twice(*a):
+            return _ivf_scan_probe_major_dense(
+                *a, kk, metric=metric, scan_dtype="float32",
+                list_filter=None, scan_scale=1.0, interpret=False,
+                stack=2 * stack)
+
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(twice).lower(*args).compile()
+
+
 def test_ivf_scan_query_major_compiles(one_chip):
     Q, P = 1024, 32
     _compile(
